@@ -293,7 +293,9 @@ def main(argv=None) -> None:
     # Chipless lane: must pick the CPU backend, and only can before
     # JAX initializes (imports below are deliberately lazy).
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-  import jax
+  from tensor2robot_tpu.utils import compile_cache
+  from tensor2robot_tpu.utils.device_info import device_summary
+  compile_cache.configure()
 
   if args.fleet:
     clients = [int(c) for c in args.clients.split(",") if c]
@@ -305,7 +307,7 @@ def main(argv=None) -> None:
     print(json.dumps({
         "metric": "QT-Opt fleet serving: deadline micro-batch + "
                   "bucketed CEM",
-        "device_kind": jax.devices()[0].device_kind,
+        **device_summary(),
         **fleet,
         "reference_note": "the reference ran robot fleets at 10-30 Hz "
                           "through one batched session.run per CEM "
@@ -317,7 +319,7 @@ def main(argv=None) -> None:
              bench_policy(uint8_images=True)]
   print(json.dumps({
       "metric": "QT-Opt fused CEM control rate (64 samples x 3 iters)",
-      "device_kind": jax.devices()[0].device_kind,
+      **device_summary(),
       "results": results,
       "reference_note": "the reference's robot fleets ran 10-30 Hz "
                         "with a batched session.run per CEM iteration "

@@ -25,6 +25,7 @@ from tensor2robot_tpu.train.train_eval import (
     continuous_eval_model,
     train_eval_model,
 )
+from tensor2robot_tpu.utils import compile_cache
 
 
 def main(argv=None) -> int:
@@ -47,6 +48,7 @@ def main(argv=None) -> int:
                            "evaluator polling model_dir's checkpoints "
                            "(configure continuous_eval_model.* bindings)")
   args = parser.parse_args(argv)
+  compile_cache.configure()
 
   logging.basicConfig(
       level=logging.INFO,

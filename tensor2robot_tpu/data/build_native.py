@@ -15,18 +15,25 @@ import sys
 _THIS_DIR = os.path.dirname(os.path.abspath(__file__))
 SOURCE = os.path.join(_THIS_DIR, "_native", "native_data.cc")
 LIBRARY = os.path.join(_THIS_DIR, "_native", "libt2rnative.so")
-# Sidecar recording the sha256 of the source the .so was built from.
-# Staleness is decided by content hash, NOT mtime ordering: a copied or
-# touched .so artifact can carry an mtime newer than an updated source
-# while holding pre-update code (ADVICE r3) — with the old mtime rule it
-# would be trusted and could violate newer ABI contracts (e.g. return
-# uninitialized memory for failure modes the update started zeroing).
+# No -march=native: the tree (ignored build products included) is copied
+# between hosts, and a binary tuned to the build host's CPU dies with
+# SIGILL on another (seen on the v5e host, which lacks this sandbox's
+# AVX-512 extensions). The source has no intrinsics to lose.
+_BUILD_CMD = ("g++", "-O3", "-shared", "-fPIC", "-pthread")
+_LINK_LIBS = ("-ljpeg",)
+# Sidecar recording the sha256 of the source AND the build command the
+# .so came from. Staleness is decided by content hash, NOT mtime
+# ordering: a copied or touched .so can carry an mtime newer than an
+# updated source while holding pre-update code. Hashing the command too
+# retires any binary built under other flags.
 HASH_SIDECAR = LIBRARY + ".srchash"
 
 
 def source_hash() -> str:
+  digest = hashlib.sha256(" ".join(_BUILD_CMD + _LINK_LIBS).encode())
   with open(SOURCE, "rb") as f:
-    return hashlib.sha256(f.read()).hexdigest()
+    digest.update(f.read())
+  return digest.hexdigest()
 
 
 def library_is_current() -> bool:
@@ -43,10 +50,7 @@ def library_is_current() -> bool:
 
 def build(verbose: bool = True) -> str:
   """Compiles the shared library; returns its path."""
-  cmd = [
-      "g++", "-O3", "-march=native", "-shared", "-fPIC", "-pthread",
-      SOURCE, "-o", LIBRARY, "-ljpeg",
-  ]
+  cmd = [*_BUILD_CMD, SOURCE, "-o", LIBRARY, *_LINK_LIBS]
   result = subprocess.run(cmd, capture_output=True, text=True)
   if result.returncode != 0:
     raise RuntimeError(

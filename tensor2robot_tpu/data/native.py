@@ -44,25 +44,23 @@ class NativeData:
     lib.t2r_jpeg_decode.argtypes = [
         ctypes.c_char_p, ctypes.c_uint64,
         ctypes.POINTER(ctypes.c_uint8), ctypes.c_int32]
-    if hasattr(lib, "t2r_jpeg_decode_batch"):  # older .so may predate it
-      lib.t2r_jpeg_decode_batch.restype = ctypes.c_int32
-      lib.t2r_jpeg_decode_batch.argtypes = [
-          ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
-          ctypes.POINTER(ctypes.c_uint8), ctypes.c_int32, ctypes.c_int32,
-          ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
-          ctypes.POINTER(ctypes.c_int32)]
-    if hasattr(lib, "t2r_example_batch_dense"):
-      lib.t2r_example_batch_dense.restype = ctypes.c_int32
-      lib.t2r_example_batch_dense.argtypes = [
-          ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
-          ctypes.c_int32, ctypes.c_char_p, ctypes.c_int32, ctypes.c_int32,
-          ctypes.c_int64, ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64)]
-      lib.t2r_example_batch_bytes.restype = ctypes.c_int32
-      lib.t2r_example_batch_bytes.argtypes = [
-          ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
-          ctypes.c_int32, ctypes.c_char_p, ctypes.c_int32,
-          ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_uint64),
-          ctypes.POINTER(ctypes.c_int64)]
+    lib.t2r_jpeg_decode_batch.restype = ctypes.c_int32
+    lib.t2r_jpeg_decode_batch.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_uint8), ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_int32, ctypes.c_int32, ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_int32)]
+    lib.t2r_example_batch_dense.restype = ctypes.c_int32
+    lib.t2r_example_batch_dense.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_int32, ctypes.c_char_p, ctypes.c_int32, ctypes.c_int32,
+        ctypes.c_int64, ctypes.c_void_p, ctypes.POINTER(ctypes.c_int64)]
+    lib.t2r_example_batch_bytes.restype = ctypes.c_int32
+    lib.t2r_example_batch_bytes.argtypes = [
+        ctypes.POINTER(ctypes.c_char_p), ctypes.POINTER(ctypes.c_uint64),
+        ctypes.c_int32, ctypes.c_char_p, ctypes.c_int32,
+        ctypes.POINTER(ctypes.c_void_p), ctypes.POINTER(ctypes.c_uint64),
+        ctypes.POINTER(ctypes.c_int64)]
 
   def masked_crc32c(self, data: bytes) -> int:
     return self._lib.t2r_masked_crc32c(data, len(data))
@@ -111,10 +109,6 @@ class NativeData:
       raise ValueError("JPEG decode failed")
     return out
 
-  @property
-  def has_batch_decode(self) -> bool:
-    return hasattr(self._lib, "t2r_jpeg_decode_batch")
-
   def jpeg_decode_batch(
       self,
       images: "list[bytes]",
@@ -159,10 +153,6 @@ class NativeData:
 
 
   # --- tf.Example parsing ---------------------------------------------------
-
-  @property
-  def has_example_parse(self) -> bool:
-    return hasattr(self._lib, "t2r_example_batch_dense")
 
   def example_batch_dense(self, records: "list[bytes]", name: str,
                           kind: int, elems: int) -> Optional[np.ndarray]:
@@ -233,7 +223,7 @@ def reset_cache() -> None:
     _load_attempted = False
 
 
-def get_native(auto_build: bool = True) -> Optional[NativeData]:
+def get_native() -> Optional[NativeData]:
   """The loaded native library, building it on first use; None if
   unavailable."""
   global _native, _load_attempted
@@ -245,9 +235,9 @@ def get_native(auto_build: bool = True) -> Optional[NativeData]:
       return None
     from tensor2robot_tpu.data import build_native
     try:
-      # Content-hash staleness (ADVICE r3): the .so is trusted only if
-      # its recorded source sha256 matches the source on disk.
-      if not build_native.library_is_current() and auto_build:
+      # The .so is loaded only if its recorded hash matches the source
+      # and build command on disk; anything else is rebuilt here first.
+      if not build_native.library_is_current():
         build_native.build(verbose=False)
       _native = NativeData(ctypes.CDLL(build_native.LIBRARY))
     except Exception as e:  # missing toolchain/libjpeg → Python path

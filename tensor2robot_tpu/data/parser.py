@@ -134,7 +134,7 @@ class ExampleParser:
     from tensor2robot_tpu.data import native
     lib = native.get_native()
     stats: Dict = {"trials": 0}
-    if lib is None or not (lib.has_example_parse and lib.has_batch_decode):
+    if lib is None:
       self._native_enabled = False
       stats.update(decision="python", reason="native library unavailable")
       return stats
@@ -253,8 +253,7 @@ class ExampleParser:
     serialized_records = list(serialized_records)
     from tensor2robot_tpu.data import native
     lib = None if self._native_enabled is False else native.get_native()
-    if (lib is not None and lib.has_example_parse
-        and lib.has_batch_decode):
+    if lib is not None:
       result = self._parse_batch_native(serialized_records, lib)
       if result is not None:
         return result

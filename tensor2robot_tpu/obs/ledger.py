@@ -50,13 +50,20 @@ CHIP_PEAKS = {
 
 
 def peak_flops_for(device_kind: Optional[str]) -> Optional[float]:
-  """Peak FLOP/s for a device kind; None when unknown (e.g. cpu)."""
+  """Peak FLOP/s for a device kind. None for a host device (cpu): there
+  is no peak model for it and MFU stays null. A TPU kind that matches
+  no CHIP_PEAKS key raises — a chip missing from the table is an
+  error, not a quietly null MFU."""
   if not device_kind:
     return None
   kind = device_kind.lower()
   for key, peak in CHIP_PEAKS.items():
     if key in kind:
       return peak
+  if "tpu" in kind:
+    raise KeyError(
+        f"no peak FLOP/s for TPU device kind {device_kind!r}; add it to "
+        f"obs.ledger.CHIP_PEAKS (known: {sorted(CHIP_PEAKS)})")
   return None
 
 
@@ -84,8 +91,6 @@ def _cost_analysis(compiled):
   the backend doesn't report them."""
   try:
     analysis = compiled.cost_analysis()
-    if isinstance(analysis, (list, tuple)):
-      analysis = analysis[0]
     flops = float(analysis.get("flops", 0.0)) or None
     nbytes = float(analysis.get("bytes accessed", 0.0)) or None
     return flops, nbytes

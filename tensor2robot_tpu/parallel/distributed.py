@@ -66,19 +66,14 @@ def initialize(
     # is documented as the process's first JAX call, so this is the
     # one place the flag can still take effect). Real TPU/GPU pods
     # never enter this branch: their collectives are ICI/NCCL-native.
-    try:
-      jax.config.update("jax_cpu_collectives_implementation", "gloo")
-      # Gloo pairs assume one in-flight collective per context; the CPU
-      # client's async dispatch can issue two differently-sized
-      # collectives back-to-back and cross their wire frames
-      # ("op.preamble.length <= op.nbytes" aborts). Synchronous
-      # dispatch serializes issue order — correctness over overlap on
-      # this emulation tier.
-      jax.config.update("jax_cpu_enable_async_dispatch", False)
-    except Exception:  # older jaxlib without the gloo tier
-      _log.warning("CPU gloo collectives unavailable; cross-process "
-                   "programs will not compile on this backend.",
-                   exc_info=True)
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    # Gloo pairs assume one in-flight collective per context; the CPU
+    # client's async dispatch can issue two differently-sized
+    # collectives back-to-back and cross their wire frames
+    # ("op.preamble.length <= op.nbytes" aborts). Synchronous
+    # dispatch serializes issue order — correctness over overlap on
+    # this emulation tier.
+    jax.config.update("jax_cpu_enable_async_dispatch", False)
   try:
     jax.distributed.initialize(
         coordinator_address=coordinator_address,

@@ -22,12 +22,8 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
-
-try:  # promoted to jax.shard_map in newer releases
-  from jax import shard_map
-except ImportError:
-  from jax.experimental.shard_map import shard_map
 
 
 class MoEParams(NamedTuple):

@@ -661,6 +661,8 @@ def main(argv=None) -> None:
                       help="also write the JSON line to this file")
   args = parser.parse_args(argv)
   if args.worker is not None:
+    from tensor2robot_tpu.utils import compile_cache
+    compile_cache.configure()
     _run_worker(json.loads(args.worker))
     return
   if args.smoke or args.ci:

@@ -24,12 +24,8 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
-
-try:  # promoted to jax.shard_map in newer releases
-  from jax import shard_map
-except ImportError:
-  from jax.experimental.shard_map import shard_map
 
 
 def stack_stage_params(params_per_stage: Sequence[Any]) -> Any:
@@ -90,10 +86,8 @@ def _pipeline_local(stacked_params, microbatches, *, stage_fn,
 
   # Mark the carried buffers device-varying up front (they depend on
   # axis_index from the first tick) for shard_map's VMA type check.
-  _pcast = getattr(jax.lax, "pcast",
-                   lambda x, axes, to: x)  # pre-VMA jax: no-op
   varying = lambda tree: jax.tree_util.tree_map(
-      lambda x: _pcast(x, (axis_name,), to="varying"), tree)
+      lambda x: jax.lax.pcast(x, (axis_name,), to="varying"), tree)
   init = (varying(zeros_like_out), varying(outputs))
   _, outputs = jax.lax.fori_loop(
       0, num_microbatches + num_stages - 1, tick, init)

@@ -21,12 +21,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
-
-try:  # promoted to jax.shard_map in newer releases
-  from jax import shard_map
-except ImportError:
-  from jax.experimental.shard_map import shard_map
 
 
 def _ring_attention_local(
@@ -86,9 +82,7 @@ def _ring_attention_local(
   # axis_index — and on the batch shard when batch-sharded — from the
   # first iteration) for shard_map's VMA type check.
   vary_axes = (axis_name,) + ((batch_axis,) if batch_axis else ())
-  _pcast = getattr(jax.lax, "pcast",
-                   lambda x, axes, to: x)  # pre-VMA jax: no-op
-  varying = lambda x: _pcast(x, vary_axes, to="varying")
+  varying = lambda x: jax.lax.pcast(x, vary_axes, to="varying")
   init = (
       k, v,
       varying(jnp.full((b, h, t_local), -jnp.inf, jnp.float32)),

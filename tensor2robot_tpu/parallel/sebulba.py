@@ -68,6 +68,7 @@ ledger stays exactly-once across the whole outage.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
 import os
 import subprocess
@@ -367,8 +368,10 @@ def _cem_actor(spec: Dict, writer: ChunkWriter):
         state["reloads"] += 1
 
   def summary() -> Dict:
+    from tensor2robot_tpu.utils.device_info import device_summary
     return {
         "mode": "cem",
+        **device_summary(),  # the device THIS actor process acted on
         "env_steps": actor.env_steps,
         "episodes": actor.episodes,
         "successes": actor.successes,
@@ -467,7 +470,7 @@ class ActorSupervisor:
   """
 
   def __init__(self, spool_dir: str, specs: List[Dict],
-               env: Optional[Dict[str, str]] = None,
+               envs: Dict[int, Dict[str, str]],
                watchdog=None, recorder=None, registry=None,
                deadline_s: float = 1.0, quarantine_s: float = 0.75,
                max_respawns: int = 2):
@@ -477,7 +480,9 @@ class ActorSupervisor:
     from tensor2robot_tpu.serving.slo import CircuitBreaker
     self.spool_dir = spool_dir
     self._specs = {spec["actor_id"]: dict(spec) for spec in specs}
-    self._env = env
+    # One environment PER actor, fixed before spawn: it decides which
+    # device the child's runtime may open (a respawn gets the same one).
+    self._envs = envs
     self._recorder = recorder or flight_lib.get_recorder()
     self._registry = registry or registry_lib.get_registry()
     self._watchdog = watchdog or watchdog_lib.Watchdog(
@@ -513,7 +518,7 @@ class ActorSupervisor:
     self._procs[actor_id] = subprocess.Popen(
         [sys.executable, "-m", "tensor2robot_tpu.parallel.sebulba",
          _WORKER_FLAG, json.dumps(spec)],
-        env=self._env, stdout=subprocess.PIPE,
+        env=self._envs[actor_id], stdout=subprocess.PIPE,
         stderr=subprocess.STDOUT, text=True)
 
   def start(self) -> None:
@@ -889,15 +894,48 @@ def _actor_specs(config: SebulbaConfig, spool_dir: str,
   return specs
 
 
+def _actor_envs(config: SebulbaConfig) -> Dict[int, Dict[str, str]]:
+  """Each actor owns its own single-device runtime — its acting
+  executable is pinned to ITS device, never the learner mesh. Which
+  device follows from what the (already initialized) learner runs on:
+  cpu -> a 1-device CPU runtime per actor; tpu -> one chip per actor,
+  outside the chips the learner holds. A learner that was not started
+  under tpu_chip_env holds every chip of the host, and an actor that
+  needs one would fail or hang — refuse before spawning any."""
+  import jax
+
+  from tensor2robot_tpu.utils import tpu_chip_env as chip_lib
+  from tensor2robot_tpu.utils.cpu_mesh_env import cpu_mesh_env
+
+  actor_ids = range(config.num_actors)
+  if jax.devices()[0].platform != "tpu":
+    envs = {actor_id: cpu_mesh_env(1) for actor_id in actor_ids}
+  else:
+    held = chip_lib.visible_chips()
+    if held is None or len(jax.devices()) != config.mesh_devices:
+      raise RuntimeError(
+          f"the Sebulba learner holds {len(jax.devices())} TPU chip(s) "
+          f"(visibility {held}) for a {config.mesh_devices}-device mesh; "
+          "start the learner process under utils.tpu_chip_env.tpu_chip_env"
+          f"(<its {config.mesh_devices} chips>) so the actor processes "
+          "can open theirs")
+    free = (chip for chip in itertools.count() if chip not in held)
+    envs = {actor_id: chip_lib.tpu_chip_env([next(free)])
+            for actor_id in actor_ids}
+  for env in envs.values():
+    env["PYTHONPATH"] = (_repo_root() + os.pathsep
+                         + env.get("PYTHONPATH", ""))
+  return envs
+
+
 def run_live(config: SebulbaConfig, workdir: str,
              die_after: Optional[Dict[int, int]] = None,
-             actor_env: Optional[Dict[str, str]] = None,
              timeout_s: float = 600.0) -> Dict:
   """The live Sebulba window: THIS process is the learner; N actor
   processes stream chunks through the spool. Returns the result block
   (manifest, overlap instruments, supervisor timeline, actor results,
   compile ledger) plus the final params path for the parity check."""
-  from tensor2robot_tpu.utils.cpu_mesh_env import cpu_mesh_env
+  from tensor2robot_tpu.utils.device_info import device_summary
   os.makedirs(workdir, exist_ok=True)
   spool_dir = os.path.join(workdir, "spool")
   os.makedirs(spool_dir, exist_ok=True)
@@ -906,15 +944,10 @@ def run_live(config: SebulbaConfig, workdir: str,
   learner = SebulbaLearner(config, workdir)
   specs = _actor_specs(config, spool_dir, learner.params_dir,
                        die_after=die_after, obs_logdir=obs_logdir)
-  if actor_env is None:
-    # Each actor owns its own single-device CPU runtime — its acting
-    # executable is pinned to ITS device slice, not the learner mesh.
-    actor_env = cpu_mesh_env(1)
-    actor_env["PYTHONPATH"] = (_repo_root() + os.pathsep
-                               + actor_env.get("PYTHONPATH", ""))
   reader = SpoolReader(spool_dir, config.num_actors)
   supervisor = ActorSupervisor(
-      spool_dir, specs, env=actor_env, recorder=learner.recorder,
+      spool_dir, specs, envs=_actor_envs(config),
+      recorder=learner.recorder,
       registry=learner.registry, deadline_s=config.actor_deadline_s,
       quarantine_s=config.quarantine_s)
   arrivals: List[dict] = []
@@ -1000,6 +1033,7 @@ def run_live(config: SebulbaConfig, workdir: str,
   return {
       "config": config.to_json(),
       "learner_pid": os.getpid(),
+      "learner_device": device_summary(),
       "mesh_shape": {"data": config.mesh_devices},
       "drive": drive,
       "manifest": arrivals[:needed],
@@ -1143,6 +1177,8 @@ def main(argv=None) -> None:
     parser.error("this module's CLI is the worker entry point; the "
                  "user-facing protocol lives in "
                  "tensor2robot_tpu.bin.bench_sebulba")
+  from tensor2robot_tpu.utils import compile_cache
+  compile_cache.configure()
   spec = json.loads(args.worker)
   if spec.get("role") == "oracle":
     _run_oracle(spec)

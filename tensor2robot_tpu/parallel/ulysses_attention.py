@@ -28,18 +28,13 @@ ops.flash_attention applies.
 from __future__ import annotations
 
 import functools
-import inspect
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec
-
-try:  # promoted to jax.shard_map in newer releases
-  from jax import shard_map
-except ImportError:
-  from jax.experimental.shard_map import shard_map
 
 
 def _local_attention(q, k, v, causal: bool, scale: float, attn_impl: str):
@@ -112,15 +107,13 @@ def ulysses_attention(
   # pallas_call's out_shape carries no varying-mesh-axes annotation,
   # which the replication/VMA type check rejects inside shard_map; the
   # explicit in/out_specs above already pin the layout, so the check
-  # adds nothing here. The kwarg was renamed check_rep -> check_vma.
-  check_kw = ("check_vma" if "check_vma"
-              in inspect.signature(shard_map).parameters else "check_rep")
+  # adds nothing here.
   fn = shard_map(
       functools.partial(_ulysses_local, axis_name=axis, causal=causal,
                         scale=scale, attn_impl=attn_impl),
       mesh=mesh,
       in_specs=(spec, spec, spec),
       out_specs=spec,
-      **{check_kw: attn_impl != "pallas"},
+      check_vma=attn_impl != "pallas",
   )
   return fn(q, k, v)

@@ -14,6 +14,7 @@ import os
 from typing import Dict, Optional
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from tensor2robot_tpu.export import export_utils, variables_io
@@ -26,6 +27,39 @@ from tensor2robot_tpu.predictors.abstract_predictor import AbstractPredictor
 from tensor2robot_tpu.specs import tensorspec_utils as ts
 
 
+def _row_batched(call):
+  """`exported.call` plus the vmap rule jax.export does not provide.
+
+  The fleet CEM step vmaps a per-robot search over the predictor's
+  device fn (cem.fleet_cem_optimize); `call_exported` has no batching
+  rule, so an artifact could serve one robot but not a bucket of them.
+  The artifact's leading dim is the symbolic batch "b" and PREDICT is
+  row-independent, so a vmapped (B, n, ...) call IS the flat (B*n, ...)
+  call: fold the mapped axis into the rows, call, unfold.
+  """
+
+  @jax.custom_batching.custom_vmap
+  def serve(variables, *arrays):
+    return dict(call(variables, *arrays))
+
+  @serve.def_vmap
+  def _fold_into_rows(axis_size, in_batched, variables, *arrays):
+    if any(jax.tree_util.tree_leaves(in_batched[0])):
+      raise NotImplementedError(
+          "vmap over the served variables of an exported artifact")
+    rows = []
+    for array, batched in zip(arrays, in_batched[1:]):
+      if not batched:
+        array = jnp.broadcast_to(array[None], (axis_size,) + array.shape)
+      rows.append(array.reshape((-1,) + array.shape[2:]))
+    outputs = jax.tree_util.tree_map(
+        lambda out: out.reshape((axis_size, -1) + out.shape[1:]),
+        serve(variables, *rows))
+    return outputs, jax.tree_util.tree_map(lambda _: True, outputs)
+
+  return serve
+
+
 class ExportedModelPredictor(AbstractPredictor):
   """Polls export_root and serves the newest native artifact."""
 
@@ -33,7 +67,7 @@ class ExportedModelPredictor(AbstractPredictor):
     self._export_root = export_root
     self._version = -1
     self._call = None
-    self._exported_call = None
+    self._device_serve = None
     self._variables = None
     self._feature_spec: Optional[ts.TensorSpecStruct] = None
     self._feature_keys = None
@@ -59,9 +93,9 @@ class ExportedModelPredictor(AbstractPredictor):
       variables = ocp.StandardCheckpointer().restore(
           os.path.abspath(os.path.join(export_dir, VARIABLES_DIR)))
     feature_spec, _, extra = export_utils.read_spec_assets(export_dir)
-    self._exported_call = exported.call
+    self._device_serve = _row_batched(exported.call)
     self._call = jax.jit(exported.call)
-    self._variables = jax.tree_util.tree_map(jax.numpy.asarray, variables)
+    self._variables = jax.tree_util.tree_map(jnp.asarray, variables)
     self._feature_spec = feature_spec
     self._feature_keys = extra["feature_keys"]
     self._example_parser = None  # rebuilt on demand for the new spec
@@ -108,11 +142,11 @@ class ExportedModelPredictor(AbstractPredictor):
     """See AbstractPredictor.device_fn: the deserialized StableHLO call
     is traceable under an outer jit (it inlines as a call op)."""
     self.assert_is_loaded()
-    call = self._exported_call
+    serve = self._device_serve
     keys = tuple(self._feature_keys)
 
     def fn(variables, features):
-      return dict(call(variables, *[features[key] for key in keys]))
+      return serve(variables, *[features[key] for key in keys])
 
     return fn, self._variables
 
@@ -126,7 +160,7 @@ class ExportedModelPredictor(AbstractPredictor):
 
   def close(self) -> None:
     self._call = None
-    self._exported_call = None
+    self._device_serve = None
     self._variables = None
     self._example_parser = None
     self._version = -1  # assert_is_loaded fails cleanly after close()

@@ -21,8 +21,7 @@ bench proves chiplessly is structural: the SAME one-executable ledger
 (`anakin_step` == 1 at every scale), host-blocked ~0, per-shard env
 fleets, capacity-sharded ring, and a learn body whose metrics match
 the 1-device oracle (the parity suite's claim) — the scaling NUMBERS
-become meaningful when the TPU pool returns and the driver re-runs
-this on real chips.
+become meaningful only when this runs on real chips.
 
 Emitted block (every citable field carries the repo's
 {median,min,max,trials} spread shape):

@@ -520,8 +520,7 @@ def measure_precision(
           "virtual_mesh=true means bf16 is CPU-emulated: rates and "
           "cem_bf16_speedup are not chip claims (the null is "
           "deliberate); agreement/TD parity and every structural "
-          "claim stand. Real-chip speedups land via bench.py's "
-          "precision block on a pool window."),
+          "claim stand. Real-chip speedups are not measured."),
   }
 
   if enforce_bars:

@@ -42,7 +42,7 @@ HONESTY CAVEAT (carried as `virtual_mesh`): chipless, every timing
 figure here is a virtual-CPU-mesh diagnostic. int8 agreement, ledger
 structure, sharding evidence, and byte counts are device-independent
 claims and stand; `tp_scaling_efficiency` (a chip claim) is null by
-rule until a TPU pool window re-runs this bench.
+rule until this bench runs on real chips.
 """
 
 from __future__ import annotations

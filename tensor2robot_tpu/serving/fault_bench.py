@@ -47,7 +47,7 @@ chipless artifact proves is STRUCTURE and ORDERING — the breaker state
 machine against real dispatch failures, typed-not-hung futures, shed
 ordering, checkpoint/restore fidelity. Recovery LATENCY on real chips
 (how fast p99 re-converges after a real device fault) is a chip claim
-that lands via bench.py's ``faults`` block on a pool window.
+that is not measured here.
 """
 
 from __future__ import annotations

@@ -31,8 +31,7 @@ are XLA virtual CPU devices sharing this host's cores — replication
 buys no real parallelism, and absolute rates say nothing about chips.
 What the chipless artifact proves is structural: the ledger, the
 per-class EDF/shedding behavior, budgets held at the offered load, and
-the rollout cycle. Real-chip rates land when the driver re-runs this
-on a pool window (bench.py's `fleet` block, same schema).
+the rollout cycle. Real-chip rates are not measured here.
 """
 
 from __future__ import annotations

@@ -301,6 +301,14 @@ class TestDeviceResidentSmoke:
   learner-throughput block reports the device-vs-host speedup at the
   same batch shape."""
 
+  def test_output_line_names_the_device(self, device_smoke_results):
+    """--smoke is a declared chipless lane; its line must say so in the
+    same three keys every entry point's output carries."""
+    results, _ = device_smoke_results
+    assert results["platform"] == "cpu"
+    assert results["device_kind"] == "cpu"
+    assert results["device_count"] == 1
+
   def test_td_reduction_still_meets_bar(self, device_smoke_results):
     results, _ = device_smoke_results
     assert results["device_resident"] is True

@@ -147,7 +147,7 @@ class TestBatchJpegDecode:
 
   def test_batch_matches_single(self):
     lib = native.get_native()
-    if lib is None or not lib.has_batch_decode:
+    if lib is None:
       pytest.skip("native library unavailable")
     images, _ = self._jpegs(n=8)
     out, statuses = lib.jpeg_decode_batch(images, 32, 32, 3)
@@ -157,7 +157,7 @@ class TestBatchJpegDecode:
 
   def test_per_image_failures_isolated(self):
     lib = native.get_native()
-    if lib is None or not lib.has_batch_decode:
+    if lib is None:
       pytest.skip("native library unavailable")
     images, _ = self._jpegs(n=3)
     bad = [images[0], b"corrupt bytes", images[2]]
@@ -171,7 +171,7 @@ class TestBatchJpegDecode:
     # Valid header + cut-off entropy data: libjpeg aborts mid-scanline
     # after writing partial rows; the slot must still come back zeroed.
     lib = native.get_native()
-    if lib is None or not lib.has_batch_decode:
+    if lib is None:
       pytest.skip("native library unavailable")
     images, _ = self._jpegs(n=1, size=64)
     truncated = images[0][: len(images[0]) // 2]
@@ -181,7 +181,7 @@ class TestBatchJpegDecode:
 
   def test_dimension_mismatch_status(self):
     lib = native.get_native()
-    if lib is None or not lib.has_batch_decode:
+    if lib is None:
       pytest.skip("native library unavailable")
     images, _ = self._jpegs(n=2, size=32)
     out, statuses = lib.jpeg_decode_batch(images, 64, 64, 3)
@@ -193,14 +193,14 @@ class TestBatchJpegDecode:
 
   def test_empty_batch(self):
     lib = native.get_native()
-    if lib is None or not lib.has_batch_decode:
+    if lib is None:
       pytest.skip("native library unavailable")
     out, statuses = lib.jpeg_decode_batch([], 32, 32, 3)
     assert out.shape == (0, 32, 32, 3) and statuses.shape == (0,)
 
   def test_grayscale_batch(self):
     lib = native.get_native()
-    if lib is None or not lib.has_batch_decode:
+    if lib is None:
       pytest.skip("native library unavailable")
     images, _ = self._jpegs(n=4)
     out, statuses = lib.jpeg_decode_batch(images, 32, 32, channels=1)
@@ -406,6 +406,46 @@ class TestBuildCache:
     if not os.path.exists(build_native.LIBRARY):
       pytest.skip("native library not built")
     assert build_native.library_is_current()
+
+  def test_binary_is_portable_across_hosts(self):
+    """The tree (ignored build products included) is copied between
+    hosts: a -march=native binary died with SIGILL on the v5e host."""
+    from tensor2robot_tpu.data import build_native
+    assert not any("march" in flag or "mtune" in flag
+                   for flag in build_native._BUILD_CMD)
+
+  def test_hash_covers_the_build_command(self, monkeypatch):
+    """A .so built from the same source under other flags is stale."""
+    from tensor2robot_tpu.data import build_native
+    before = build_native.source_hash()
+    monkeypatch.setattr(build_native, "_BUILD_CMD",
+                        build_native._BUILD_CMD + ("-march=native",))
+    assert build_native.source_hash() != before
+
+  def test_stale_library_is_rebuilt_never_loaded(self, monkeypatch,
+                                                 tmp_path):
+    """get_native() trusts the .so only after library_is_current();
+    with a stale sidecar and a failing build the answer is None — the
+    stale binary is not loaded as a consolation."""
+    from tensor2robot_tpu.data import build_native, native
+    fake_lib = tmp_path / "lib.so"
+    fake_lib.write_bytes(b"built elsewhere, for another CPU")
+    monkeypatch.setattr(build_native, "LIBRARY", str(fake_lib))
+    monkeypatch.setattr(build_native, "HASH_SIDECAR",
+                        str(fake_lib) + ".srchash")
+
+    def failing_build(verbose=True):
+      raise RuntimeError("no compiler here")
+    monkeypatch.setattr(build_native, "build", failing_build)
+    loaded = []
+    monkeypatch.setattr(native.ctypes, "CDLL",
+                        lambda path: loaded.append(path))
+    native.reset_cache()
+    try:
+      assert native.get_native() is None
+      assert loaded == []
+    finally:
+      native.reset_cache()
 
   def test_missing_sidecar_means_stale(self, monkeypatch, tmp_path):
     from tensor2robot_tpu.data import build_native

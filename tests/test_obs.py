@@ -214,6 +214,14 @@ class TestExecutableLedger:
     row = ledger.attribution()["executables"][0]
     assert row["name"] == "ghost" and row["compiles"] == 0
 
+  def test_tpu_kind_missing_from_the_peak_table_is_an_error(self):
+    """cpu -> None (no peak model, MFU null). A TPU the table does not
+    know is an error, not a quietly null MFU on a chip run."""
+    assert peak_flops_for("cpu") is None
+    assert peak_flops_for(None) is None
+    with pytest.raises(KeyError, match="CHIP_PEAKS"):
+      peak_flops_for("TPU v9 unheard-of")
+
   def test_mfu_needs_a_known_peak(self):
     assert peak_flops_for("cpu") is None
     assert peak_flops_for("TPU v5 lite") == 197e12
@@ -416,7 +424,7 @@ class TestGuardedProfiler:
 def obs_bench_results(tmp_path_factory):
   """ONE obs_bench --ci run shared by the acceptance assertions — the
   CLI in a subprocess under the ARTIFACT environment (the re-exec
-  bootstrap path under test, exactly as measure_round.sh runs it)."""
+  bootstrap path under test)."""
   import subprocess
   import sys
   tmp = tmp_path_factory.mktemp("obs_bench")

@@ -201,6 +201,20 @@ class TestDispatch:
     with pytest.raises(ValueError, match="implementation"):
       flash_attention(q, q, q, implementation="Pallas")
 
+  def test_spatial_softmax_vmem_guard_counts_padded_lanes(self):
+    """VMEM pads the channel tile to 128 lanes whatever C is, so the
+    guard counts every block at 128: a 16-channel map takes the room
+    of a 128-channel one. (On v5e Mosaic refused 118x118x64 under the
+    old min(C, 128) count and the default scoped-VMEM budget.)"""
+    import importlib
+    module = importlib.import_module("tensor2robot_tpu.ops.spatial_softmax")
+    shape = lambda h, w, c: jax.ShapeDtypeStruct((2, h, w, c), jnp.float32)
+    assert module._supported(shape(128, 128, 16))
+    assert module._supported(shape(128, 128, 256))
+    assert module._supported(shape(118, 118, 64))
+    assert not module._supported(shape(129, 128, 16))
+    assert not module._supported(shape(236, 236, 64))
+
   def test_flash_attention_vmem_guard(self):
     # Huge T that is 128-divisible must fall back in auto mode and
     # raise (not compile-crash) when pallas is forced.

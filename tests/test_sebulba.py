@@ -232,6 +232,60 @@ class TestExtendDeviceChunk:
       buffer.extend_device_chunk(jax.device_put(_chunk(n=8)))
 
 
+class TestActorDevicePlacement:
+  """One process per chip: each actor's environment decides, before it
+  spawns, which device its runtime may open — never the learner's."""
+
+  def test_cpu_learner_gives_each_actor_a_one_device_cpu_runtime(self):
+    from tensor2robot_tpu.utils.cpu_mesh_env import is_cpu_mesh_env
+    envs = sebulba._actor_envs(sebulba.SebulbaConfig(num_actors=3))
+    assert sorted(envs) == [0, 1, 2]
+    for env in envs.values():
+      assert is_cpu_mesh_env(1, env) and not is_cpu_mesh_env(2, env)
+      assert env["PYTHONPATH"].split(os.pathsep)[0] == sebulba._repo_root()
+
+  def _fake_tpu(self, monkeypatch, count):
+    import types
+
+    import jax
+    chip = types.SimpleNamespace(platform="tpu")
+    monkeypatch.setattr(jax, "devices", lambda: [chip] * count)
+
+  def test_tpu_learner_hands_out_chips_outside_its_own(self, monkeypatch):
+    from tensor2robot_tpu.utils.tpu_chip_env import tpu_chip_env
+    self._fake_tpu(monkeypatch, 2)
+    for key, value in tpu_chip_env([0, 1], base={}).items():
+      monkeypatch.setenv(key, value)
+    envs = sebulba._actor_envs(
+        sebulba.SebulbaConfig(num_actors=2, mesh_devices=2))
+    assert [envs[i]["TPU_VISIBLE_CHIPS"] for i in (0, 1)] == ["2", "3"]
+    for env in envs.values():
+      assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+      assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+  def test_learner_holding_the_whole_host_refuses_to_spawn(
+      self, monkeypatch):
+    """A learner that initialized JAX with every chip visible holds
+    them all; an actor needing one would fail or hang."""
+    self._fake_tpu(monkeypatch, 4)
+    monkeypatch.delenv("TPU_VISIBLE_CHIPS", raising=False)
+    with pytest.raises(RuntimeError, match="tpu_chip_env"):
+      sebulba._actor_envs(
+          sebulba.SebulbaConfig(num_actors=2, mesh_devices=2))
+
+  def test_tpu_chip_env_sets_visibility_without_touching_its_base(self):
+    from tensor2robot_tpu.utils.tpu_chip_env import (tpu_chip_env,
+                                                     visible_chips)
+    base = {"TPU_VISIBLE_CHIPS": "0,1", "OTHER": "kept"}
+    env = tpu_chip_env([3], base=base)
+    assert env["TPU_VISIBLE_CHIPS"] == "3" and env["OTHER"] == "kept"
+    assert base["TPU_VISIBLE_CHIPS"] == "0,1"
+    assert visible_chips(env) == [3] and visible_chips(base) == [0, 1]
+    assert visible_chips({}) is None
+    with pytest.raises(ValueError):
+      tpu_chip_env([0, 1, 2])
+
+
 @pytest.mark.slow
 class TestSebulbaLiveOracleParity:
   """The tentpole end-to-end: 2 real actor processes + this learner

@@ -741,6 +741,8 @@ class TestFleetSmokeCLI:
   def test_fleet_smoke_contract_and_amortization(self):
     obj = self._run_smoke()
     assert obj["mode"] == "smoke"
+    assert (obj["platform"], obj["device_kind"]) == ("cpu", "cpu")
+    assert obj["device_count"] >= 1
     assert obj["bucket_ladder"] == [1, 2, 4, 8, 16]
     # Exactly one compiled executable per ladder bucket over the whole
     # run — warmup, partial deadline flushes, and full batches included.
