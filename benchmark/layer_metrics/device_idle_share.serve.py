@@ -1,0 +1,4 @@
+"""The device's idle share of the traced window in the serve cells
+(moves serve_actions_per_s); one reader for both kinds of cell."""
+
+from benchmark.trace.reduce import idle_share_percent as read  # noqa: F401
