@@ -22,6 +22,8 @@ from typing import Any, Iterator, Optional
 import jax
 import numpy as np
 
+from tensor2robot_tpu.obs import trace as trace_lib
+
 
 class PrefetchExhausted(Exception):
   """The upstream host iterator ended and every in-flight transfer has
@@ -89,7 +91,8 @@ def prefetch_to_device(
 
   def push(batch: Any) -> None:
     in_flight_bytes.append(_host_nbytes(batch))
-    buffer.append(transfer(batch))
+    with trace_lib.span("input/put", bytes=in_flight_bytes[-1]):
+      buffer.append(transfer(batch))
     depth_gauge.set(len(buffer))
     bytes_gauge.set(sum(in_flight_bytes))
 
@@ -101,7 +104,15 @@ def prefetch_to_device(
     batches_counter.inc()
     return batch
 
-  for batch in iterator:
+  iterator = iter(iterator)
+  exhausted = object()
+  while True:
+    # What the consumer waits for when the buffer is not ahead of it:
+    # the host pipeline's next batch.
+    with trace_lib.span("input/wait"):
+      batch = next(iterator, exhausted)
+    if batch is exhausted:
+      break
     push(batch)
     if len(buffer) >= depth:
       yielded += 1

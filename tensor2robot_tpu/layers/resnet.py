@@ -16,6 +16,7 @@ from __future__ import annotations
 from typing import Any, Callable, Optional, Sequence, Tuple
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from tensor2robot_tpu.layers.vision_layers import make_norm, normalize_image
@@ -126,12 +127,14 @@ class ResNet(nn.Module):
       raise ValueError("FiLM ResNet requires a context embedding.")
     block_sizes, bottleneck = _CONFIGS[self.depth]
 
-    x = normalize_image(images, self.dtype)  # uint8 wire → [0,1] on-chip
+    with jax.named_scope("normalize_image"):
+      x = normalize_image(images, self.dtype)  # uint8 wire → [0,1] on-chip
     x = nn.Conv(self.width, (7, 7), strides=(2, 2), use_bias=False,
                 dtype=self.dtype, name="stem_conv")(x)
     x = make_norm(self.norm, train, self.dtype)("stem_bn")(x)
     x = nn.relu(x)
-    x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+    with jax.named_scope("stem_pool"):
+      x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
 
     # remat=True drops each block's activations after the forward pass and
     # recomputes them during backprop (jax.checkpoint): activation memory

@@ -192,7 +192,8 @@ class AbstractT2RModel(abc.ABC):
     """
     outputs, new_state = self.inference_network_fn(
         variables, features, modes.TRAIN, rngs=rngs)
-    loss, metrics = self.loss_fn(outputs, features, labels)
+    with jax.named_scope("loss"):
+      loss, metrics = self.loss_fn(outputs, features, labels)
     metrics = dict(metrics)
     metrics.setdefault("loss", loss)
     return loss, (metrics, new_state)

@@ -3,8 +3,9 @@
 Four layers, each usable alone, designed to compose:
 
 - ``trace``: host-side structured spans (thread-safe, nestable) that
-  double as ``jax.profiler.TraceAnnotation``s while a device trace is
-  active, exportable as one Chrome-trace/Perfetto JSON per run.
+  double as ``jax.profiler.TraceAnnotation``s (seen by whatever
+  profiler session is open), exportable as one Chrome-trace/Perfetto
+  JSON per run.
 - ``registry``: a process-wide typed metric registry (counters, gauges,
   bounded histograms with p50/p99 snapshots) with one bridge flushing
   snapshots through the existing ``utils.metric_writer.MetricWriter``
@@ -60,8 +61,7 @@ from tensor2robot_tpu.obs.ledger import (ExecutableLedger,
                                          check_compile_ledger,
                                          peak_flops_for)
 from tensor2robot_tpu.obs.registry import MetricRegistry, get_registry
-from tensor2robot_tpu.obs.trace import (Tracer, get_tracer,
-                                        set_device_annotations, span)
+from tensor2robot_tpu.obs.trace import Tracer, get_tracer, span
 from tensor2robot_tpu.obs.watchdog import (Watchdog, find_stragglers,
                                            get_watchdog)
 
@@ -87,6 +87,5 @@ __all__ = [
     "new_request_id",
     "peak_flops_for",
     "q_drift_report",
-    "set_device_annotations",
     "span",
 ]

@@ -10,12 +10,13 @@ Span names are ``stage/detail`` — the first path segment is the loop
 stage (``act``, ``extend``, ``learn``, ``serve``, ``replay``), which
 ``stage_counts()`` aggregates and the obs bench asserts coverage over.
 
-While a device trace is active (the guarded window in
-``utils.profiling``), every span ALSO enters a
-``jax.profiler.TraceAnnotation`` with the same name, so host spans line
-up against XLA device lanes in the same Perfetto view. Outside a trace
-window the annotation is skipped entirely — the hot-path cost of a span
-is two ``perf_counter`` reads and one deque append.
+Every span ALSO enters a ``jax.profiler.TraceAnnotation`` of the same
+name, with its scalar attrs as the annotation's stats: whoever opens a
+profiler session (``utils.profiling``'s guarded window or a plain
+``jax.profiler.start_trace``) finds the program's spans on ``/host:CPU``
+beside the device lanes, on the profiler's clock. With no session open
+the annotation is one inactive TraceMe (about half a microsecond
+against the span's four).
 
 Listeners (``add_listener``) receive every completed span dict — the
 flight recorder subscribes so the last N spans are always available for
@@ -41,6 +42,8 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from tensor2robot_tpu.obs import context as context_lib
 
 _log = logging.getLogger(__name__)
@@ -56,9 +59,6 @@ class Tracer:
     self._lock = threading.Lock()
     self._local = threading.local()
     self._listeners: List[Callable[[dict], None]] = []
-    # Toggled by utils.profiling's guarded start/stop_trace: spans only
-    # pay the TraceAnnotation cost while a device trace can see them.
-    self.annotate_devices = False
 
   # -- recording -----------------------------------------------------------
 
@@ -70,38 +70,36 @@ class Tracer:
 
   @contextlib.contextmanager
   def span(self, name: str, **attrs):
-    """One nestable span; attrs must be JSON-serializable scalars."""
+    """One nestable span; attrs must be JSON-serializable scalars.
+    Yields the span's record, which holds its times (``ts_s``,
+    ``dur_s``) once the block has ended."""
     stack = self._stack()
     parent = stack[-1] if stack else None
     depth = len(stack)
     stack.append(name)
-    annotation = None
-    if self.annotate_devices:
-      import jax
-      annotation = jax.profiler.TraceAnnotation(name)
-      annotation.__enter__()
+    # Explicit attrs win over inherited context attrs. Read at entry: a
+    # bind() inside the span is undone before it ends, so the context
+    # is the same at both ends.
+    attrs = {**context_lib.context_attrs(), **attrs}
+    annotation = TraceAnnotation(
+        name, **{key: _annotation_stat(value)
+                 for key, value in attrs.items()
+                 if isinstance(value, (str, int, float))})
+    annotation.__enter__()
+    record = {"name": name}
     start = time.perf_counter()
     try:
-      yield
+      yield record
     finally:
       duration = time.perf_counter() - start
-      if annotation is not None:
-        annotation.__exit__(None, None, None)
+      annotation.__exit__(None, None, None)
       stack.pop()
-      record = {
-          "name": name,
-          "ts_s": round(start - self._epoch, 6),
-          "dur_s": round(duration, 6),
-          "tid": threading.get_ident(),
-          "depth": depth,
-      }
+      record.update(ts_s=round(start - self._epoch, 6),
+                    dur_s=round(duration, 6),
+                    tid=threading.get_ident(), depth=depth)
       if parent is not None:
         record["parent"] = parent
-      context_attrs = context_lib.context_attrs()
-      if context_attrs:
-        record.update(context_attrs)
-      if attrs:  # explicit attrs win over inherited context attrs
-        record.update(attrs)
+      record.update(attrs)
       with self._lock:
         self._spans.append(record)
         self._total += 1
@@ -204,6 +202,14 @@ class Tracer:
     return path
 
 
+def _annotation_stat(value):
+  """TraceMe's ``name#k=v,k=v#`` encoding cuts a value at a bare comma
+  and keeps one inside brackets: the comma-joined ``request_ids``."""
+  if isinstance(value, str) and "," in value:
+    return f"[{value}]"
+  return value
+
+
 def request_flow_events(by_request: Dict[str, list], pid: int,
                         flow_ids: Optional[Dict[str, int]] = None) -> list:
   """Perfetto flow events linking each request's spans in time order.
@@ -264,8 +270,3 @@ def span(name: str, **attrs):
   """``with obs.trace.span("learn/megastep", k=10): ...``"""
   return get_tracer().span(name, **attrs)
 
-
-def set_device_annotations(enabled: bool) -> None:
-  """Flip TraceAnnotation emission on the process tracer (the guarded
-  profiler window in utils.profiling owns this flag)."""
-  get_tracer().annotate_devices = bool(enabled)

@@ -23,6 +23,7 @@ from __future__ import annotations
 from typing import Any, Optional
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -86,7 +87,10 @@ class _GraspingQModule(nn.Module):
     else:
       raise ValueError(f"Unknown norm_kind {self.norm_kind!r}")
 
-    x = normalize_image(features["image"], dtype)
+    # Scopes for what is no flax module (those have their own): every
+    # device op of the step then has a stable path in the compiled HLO.
+    with jax.named_scope("normalize_image"):
+      x = normalize_image(features["image"], dtype)
     # Stem: 472 -> 118 -> 59.
     if self.stem_kind == "conv":
       x = nn.Conv(64, (6, 6), strides=(4, 4), dtype=dtype, name="stem")(x)
@@ -101,23 +105,27 @@ class _GraspingQModule(nn.Module):
     else:
       raise ValueError(f"Unknown stem_kind {self.stem_kind!r}")
     x = nn.relu(norm("stem_bn")(x))
-    if self.impl == "fast" and x.shape[1] % 2 == 0 and x.shape[2] % 2 == 0:
-      x = max_pool_reshape(x)
-    else:
-      x = nn.max_pool(x, (2, 2), strides=(2, 2))
+    with jax.named_scope("stem_pool"):
+      if (self.impl == "fast" and x.shape[1] % 2 == 0
+          and x.shape[2] % 2 == 0):
+        x = max_pool_reshape(x)
+      else:
+        x = nn.max_pool(x, (2, 2), strides=(2, 2))
     for i in range(3):
       x = nn.relu(norm(f"pre_bn{i}")(nn.Conv(
           64, (3, 3), dtype=dtype, name=f"pre_conv{i}")(x)))
 
     # Action (and optional state vector) merge.
-    action = features["action"].astype(dtype)
+    with jax.named_scope("wire_cast"):
+      action = features["action"].astype(dtype)
     if action.shape[-1] != self.action_size:
       raise ValueError(
           f"Expected action dim {self.action_size}, got "
           f"{action.shape[-1]}.")
     merge_inputs = [action]
     if "state" in features:
-      merge_inputs.append(features["state"].astype(dtype))
+      with jax.named_scope("wire_cast"):
+        merge_inputs.append(features["state"].astype(dtype))
     embedding = jnp.concatenate(merge_inputs, axis=-1)
     embedding = nn.relu(nn.Dense(64, dtype=dtype, name="action_fc1")(
         embedding))

@@ -572,7 +572,12 @@ class MicroBatcher:
       # death path (_resolve_failed handles the RUNNING futures).
       if self._faults is not None:
         self._faults.perturb("batcher_flush", site=self._site)
-      with trace_lib.span("serve/flush", batch=len(batch)):
+      # A request's latency is its wait up to here plus this span.
+      flush_start = time.perf_counter()
+      waits_ms = [(flush_start - r.enqueued_at) * 1e3 for r in batch]
+      with trace_lib.span("serve/flush", batch=len(batch),
+                          queue_wait_ms_sum=round(sum(waits_ms), 3),
+                          queue_wait_ms_max=round(max(waits_ms), 3)):
         try:
           results = self._batch_fn([r.item for r in batch])
         except Exception as e:  # fail the flush's requests, not the loop
@@ -583,11 +588,12 @@ class MicroBatcher:
             request.future.set_exception(e)
           return
     done = time.perf_counter()
-    for request, result in zip(batch, results):
+    for request, result, wait_ms in zip(batch, results, waits_ms):
       request.future.set_result(result)
       if self._stats is not None:
         self._stats.record_latency_ms(
             (done - request.enqueued_at) * 1e3, request.slo.name)
+        self._stats.record_queue_wait_ms(wait_ms, request.slo.name)
     if self._stats is not None:
       with self._cond:
         depth_after = self._live

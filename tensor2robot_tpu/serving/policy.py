@@ -18,7 +18,6 @@ batch position, and bucket padding (see cem.fleet_cem_optimize).
 from __future__ import annotations
 
 import threading
-import time
 from typing import Optional, Sequence
 
 import jax
@@ -26,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tensor2robot_tpu.obs import ledger as ledger_lib
+from tensor2robot_tpu.obs import trace as trace_lib
 from tensor2robot_tpu.research.qtopt import cem
 from tensor2robot_tpu.serving import bucketing
 from tensor2robot_tpu.serving.bucketing import BucketLadder
@@ -150,12 +150,15 @@ class CEMFleetPolicy:
     already computes them (CEM's final elite-mean score), so the fleet
     Q-drift sketches cost zero extra device work. The host fallback
     has no per-call score readout and returns ``(actions, None)``."""
-    batch = np.stack([np.asarray(image) for image in images])
-    n = batch.shape[0]
-    seeds = (self.assign_seeds(n) if seeds is None
-             else np.asarray(seeds, np.uint32))
-    if seeds.shape != (n,):
-      raise ValueError(f"need {n} seeds, got shape {seeds.shape}")
+    n = len(images)
+    bucket = self.ladder.bucket_for(n)
+    with trace_lib.span("serve/stack", rows=n,
+                        bytes=n * np.asarray(images[0]).nbytes):
+      batch = np.stack([np.asarray(image) for image in images])
+      seeds = (self.assign_seeds(n) if seeds is None
+               else np.asarray(seeds, np.uint32))
+      if seeds.shape != (n,):
+        raise ValueError(f"need {n} seeds, got shape {seeds.shape}")
     try:
       fn, live_variables = self._predictor.device_fn()
     except NotImplementedError:
@@ -168,29 +171,32 @@ class CEMFleetPolicy:
       return (actions, None) if return_scores else actions
     variables = self._place(
         live_variables if variables is None else variables)
-    padded, bucket = self.ladder.pad_batch(batch)
-    padded_seeds, _ = self.ladder.pad_batch(seeds)
+    with trace_lib.span("serve/pad", bucket=bucket):
+      padded, _ = self.ladder.pad_batch(batch)
+      padded_seeds, _ = self.ladder.pad_batch(seeds)
+    # A compile or a weight upload (above) lies outside serve/put: it
+    # must not read as H2D.
     compiled = self._executable_for(bucket, fn, variables, padded,
                                     padded_seeds)
-    if self._ledger is None:
-      actions, scores = compiled(variables, self._put(padded),
-                                 self._put(padded_seeds))
+    # Returns once the runtime has the transfer: its threads re-lay the
+    # frames out and copy them while this thread goes on.
+    with trace_lib.span("serve/put",
+                        bytes=padded.nbytes + padded_seeds.nbytes) as put:
+      device_images = self._put(padded)
+      device_seeds = self._put(padded_seeds)
+    # Returns at enqueue.
+    with trace_lib.span("serve/execute", bucket=bucket):
+      actions, scores = compiled(variables, device_images, device_seeds)
+    # The wait for that transfer and for the device, then D2H.
+    with trace_lib.span("serve/readback") as readback:
       actions = np.asarray(actions)[:n]
-      if return_scores:
-        return actions, np.asarray(scores)[:n]
-      return actions
-    # Ledger path: the host→numpy conversion below synchronizes on the
-    # result, so the measured window is dispatch through completion.
-    start = time.perf_counter()
-    actions, scores = compiled(variables, self._put(padded),
-                               self._put(padded_seeds))
-    actions = np.asarray(actions)[:n]
-    scores = np.asarray(scores)[:n]
-    self._ledger.record_dispatch(self._ledger_key(bucket),
-                                 time.perf_counter() - start)
-    if return_scores:
-      return actions, scores
-    return actions
+      scores = np.asarray(scores)[:n]
+    if self._ledger is not None:
+      # Dispatch through completion, on the spans' own clock reads.
+      self._ledger.record_dispatch(
+          self._ledger_key(bucket),
+          readback["ts_s"] + readback["dur_s"] - put["ts_s"])
+    return (actions, scores) if return_scores else actions
 
   @property
   def device_label(self) -> Optional[str]:
@@ -304,9 +310,10 @@ class CEMFleetPolicy:
     with self._compile_lock:
       compiled = self._executables.get(bucket)
       if compiled is None:
-        lowered = jax.jit(self._build_control(fn)).lower(
-            variables, self._put(padded), self._put(padded_seeds))
-        compiled = lowered.compile()
+        with trace_lib.span("serve/compile", bucket=bucket):
+          lowered = jax.jit(self._build_control(fn)).lower(
+              variables, self._put(padded), self._put(padded_seeds))
+          compiled = lowered.compile()
         self._executables[bucket] = compiled
         self.compile_counts[bucket] = (
             self.compile_counts.get(bucket, 0) + 1)

@@ -145,6 +145,7 @@ class ServingStats:
     self._lock = threading.Lock()
     self._registry = registry or registry_lib.get_registry()
     self.latency = LatencyHistogram()
+    self.queue_wait = LatencyHistogram()
     self._requests = 0
     self._logical_requests = 0
     self._flushes = 0
@@ -261,6 +262,16 @@ class ServingStats:
       self._registry.histogram(
           f"serving/class/{class_name}/latency_ms").record(latency_ms)
 
+  def record_queue_wait_ms(self, wait_ms: float,
+                           class_name: Optional[str] = None) -> None:
+    """One flushed request's time from enqueue to the start of its
+    flush: the part of its latency that no flush of its own explains."""
+    self.queue_wait.record(wait_ms)
+    self._registry.histogram("serving/queue_wait_ms").record(wait_ms)
+    if class_name is not None:
+      self._registry.histogram(
+          f"serving/class/{class_name}/queue_wait_ms").record(wait_ms)
+
   def snapshot(self) -> Dict[str, float]:
     """One dict: counters + derived ratios + latency percentiles, plus
     a ``per_class`` sub-dict keyed by SLO class name (empty when no
@@ -295,6 +306,8 @@ class ServingStats:
     for key, value in self.latency.summary().items():
       out["latency_" + key if not key.startswith("count") else
           "latency_samples"] = value
+    for pct in (50, 99):
+      out[f"queue_wait_p{pct}_ms"] = self.queue_wait.percentile(pct)
     out["per_class"] = per_class
     q_sketches = self.q_sketch_summaries()
     if q_sketches:

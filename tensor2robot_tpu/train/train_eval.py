@@ -33,6 +33,7 @@ from tensor2robot_tpu.config import configurable, operative_config_str
 from tensor2robot_tpu.data.prefetch import prefetch_to_device
 from tensor2robot_tpu.hooks.hook_builder import Hook, HookBuilder
 from tensor2robot_tpu.obs import registry as registry_lib
+from tensor2robot_tpu.obs import trace as trace_lib
 from tensor2robot_tpu.train.checkpoints import CheckpointManager
 from tensor2robot_tpu.train.trainer import Trainer
 from tensor2robot_tpu.train.train_state import TrainState
@@ -368,7 +369,8 @@ def train_eval_model(
           inflight.popleft().block_until_ready()
 
         if crossed(log_every_steps, prev_step, step) or step == max_train_steps:
-          host_metrics = {k: float(v) for k, v in pending_metrics.items()}
+          with trace_lib.span("train/readback", step=step):
+            host_metrics = {k: float(v) for k, v in pending_metrics.items()}
           train_metrics = host_metrics
           if metric_writer:
             _emit_metrics(metric_writer, step, host_metrics)
@@ -386,7 +388,8 @@ def train_eval_model(
 
         if checkpoint_manager and checkpoint_manager.should_save(
             step, last_step=prev_step):
-          checkpoint_manager.save(step, state)
+          with trace_lib.span("train/checkpoint", step=step):
+            checkpoint_manager.save(step, state)
           for hook in hooks:
             hook.after_checkpoint(step, state)
 
@@ -405,7 +408,8 @@ def train_eval_model(
     if checkpoint_manager:
       final_step = int(state.step)
       if checkpoint_manager.latest_step() != final_step:
-        checkpoint_manager.save(final_step, state, force=True)
+        with trace_lib.span("train/checkpoint", step=final_step):
+          checkpoint_manager.save(final_step, state, force=True)
         for hook in hooks:
           hook.after_checkpoint(final_step, state)
 

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import optax
 
 from tensor2robot_tpu import modes
+from tensor2robot_tpu.obs import trace as trace_lib
 from tensor2robot_tpu.parallel import mesh as mesh_lib
 from tensor2robot_tpu.parallel import tp_rules
 from tensor2robot_tpu.train.train_state import TrainState
@@ -198,6 +199,7 @@ class Trainer:
 
   # --- steps ---------------------------------------------------------------
 
+  @jax.named_scope("apply_grads")
   def _apply_grads(self, state: TrainState, grads, new_model_state
                    ) -> TrainState:
     """Optimizer update + EMA + step bump, shared by the single-step and
@@ -389,7 +391,9 @@ class Trainer:
     """One compiled optimizer step. Donates `state`."""
     if self._train_step is None:
       self._train_step = self._build_train_step()
-    return self._train_step(state, features, labels)
+    # Host time to flatten the TensorSpecStructs and enqueue.
+    with trace_lib.span("train/dispatch", kind="step"):
+      return self._train_step(state, features, labels)
 
   def train_steps(self, state: TrainState, features, labels=None
                   ) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
@@ -399,7 +403,8 @@ class Trainer:
     except for one possible partial final loop."""
     if self._train_steps is None:
       self._train_steps = self._build_train_steps()
-    return self._train_steps(state, features, labels)
+    with trace_lib.span("train/dispatch", kind="steps"):
+      return self._train_steps(state, features, labels)
 
   def aot_train_step(self, state: TrainState, features, labels=None,
                      with_health: bool = False):
@@ -440,7 +445,8 @@ class Trainer:
     O(1-microbatch) activation memory. Donates `state`."""
     if self._train_step_accum is None:
       self._train_step_accum = self._build_train_step_accum()
-    return self._train_step_accum(state, features, labels)
+    with trace_lib.span("train/dispatch", kind="accum"):
+      return self._train_step_accum(state, features, labels)
 
   def eval_step(self, state: TrainState, features, labels=None
                 ) -> Dict[str, jnp.ndarray]:

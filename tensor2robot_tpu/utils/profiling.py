@@ -19,7 +19,6 @@ from typing import List, Optional
 import jax
 
 from tensor2robot_tpu.hooks.hook_builder import Hook, HookBuilder
-from tensor2robot_tpu.obs import trace as obs_trace
 
 _log = logging.getLogger(__name__)
 
@@ -30,9 +29,8 @@ annotate = jax.profiler.TraceAnnotation
 # armed (the train ProfilerHook and the replay loop's --profile
 # window). Every capture in this repo goes through start_trace /
 # stop_trace below, so a second window logs-and-skips instead of
-# killing the loop that lost the race. The guard also flips the obs
-# tracer's device-annotation flag, so host spans appear as
-# TraceAnnotations exactly while a device trace can see them.
+# killing the loop that lost the race. The obs tracer's spans are
+# TraceAnnotations always, so any window opened here shows them.
 _TRACE_LOCK = threading.Lock()
 _TRACE_DIR: Optional[str] = None
 
@@ -59,9 +57,6 @@ def start_trace(log_dir: str) -> bool:
     os.makedirs(log_dir, exist_ok=True)
     jax.profiler.start_trace(log_dir)
     _TRACE_DIR = log_dir
-    # Inside the lock: the annotation flag must never disagree with
-    # the trace state under a concurrent start/stop race.
-    obs_trace.set_device_annotations(True)
   return True
 
 
@@ -74,7 +69,6 @@ def stop_trace() -> Optional[str]:
       return None
     log_dir, _TRACE_DIR = _TRACE_DIR, None
     jax.profiler.stop_trace()
-    obs_trace.set_device_annotations(False)
   return log_dir
 
 

@@ -405,19 +405,130 @@ class TestGuardedProfiler:
       with pytest.raises(ValueError):
         parse_profile(bad)
 
-  def test_device_annotations_follow_trace_window(self, monkeypatch):
-    from tensor2robot_tpu.obs import trace as trace_lib
-    from tensor2robot_tpu.utils import profiling
+  def test_spans_reach_a_plain_profiler_session(self, tmp_path):
+    """One clock: a span opened while ANY profiler session is open (a
+    plain jax.profiler.start_trace, as the benchmark's harness makes
+    it, not utils.profiling's window) is on /host:CPU of the xplane
+    under its own name, with its attrs as stats; a comma-joined
+    request_ids rides whole, in brackets."""
+    import glob
 
-    monkeypatch.setattr(profiling.jax.profiler, "start_trace",
-                        lambda d: None)
-    monkeypatch.setattr(profiling.jax.profiler, "stop_trace",
-                        lambda: None)
-    assert not trace_lib.get_tracer().annotate_devices
-    assert profiling.start_trace("/tmp/w")
-    assert trace_lib.get_tracer().annotate_devices
-    profiling.stop_trace()
-    assert not trace_lib.get_tracer().annotate_devices
+    import jax
+
+    from tensor2robot_tpu.obs import context as context_lib
+    from tensor2robot_tpu.obs import trace as trace_lib
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+      with context_lib.bind(request_ids="r-1,r-2"):
+        with trace_lib.span("serve/flush", batch=2,
+                            queue_wait_ms_max=1.5):
+          jax.numpy.ones((8, 8)).sum().block_until_ready()
+    finally:
+      jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    (plane,) = [p for p in data.planes if p.name == "/host:CPU"]
+    found = [dict(event.stats) for line in plane.lines
+             for event in line.events if event.name == "serve/flush"]
+    assert len(found) == 1, found
+    assert found[0]["batch"] == 2
+    assert found[0]["queue_wait_ms_max"] == 1.5
+    assert found[0]["request_ids"] == "[r-1,r-2]"
+
+
+class TestLoopSpans:
+  """The train loop's spans (ISSUE 30): one train/dispatch per compiled
+  call, the three waits of the loop around what they wait for."""
+
+  def _spans_of(self, run, names):
+    from tensor2robot_tpu.obs import trace as trace_lib
+    before = trace_lib.get_tracer().total_spans
+    run()
+    spans = trace_lib.get_tracer().spans()
+    new = spans[len(spans) - (trace_lib.get_tracer().total_spans - before):]
+    return [s for s in new if s["name"] in names]
+
+  def _trainer_and_feed(self, stack=None):
+    import numpy as np
+
+    from tensor2robot_tpu import modes
+    from tensor2robot_tpu.data.default_input_generator import (
+        DefaultRandomInputGenerator)
+    from tensor2robot_tpu.train.trainer import Trainer
+    from tensor2robot_tpu.utils.mocks import MockT2RModel
+
+    model = MockT2RModel()
+    trainer = Trainer(model, seed=0)
+    gen = DefaultRandomInputGenerator(batch_size=8, seed=0)
+    gen.set_specification_from_model(model, modes.TRAIN)
+    batches = gen.create_dataset_fn(modes.TRAIN)()
+    if stack is None:
+      return trainer, trainer.shard_batch(next(batches))
+    import jax
+    rows = [next(batches) for _ in range(stack)]
+    return trainer, jax.tree_util.tree_map(
+        lambda *leaves: np.stack(leaves), *rows)
+
+  @pytest.mark.parametrize("kind, stack", [
+      ("step", None), ("steps", 3), ("accum", 2)])
+  def test_one_train_dispatch_span_per_call(self, kind, stack):
+    trainer, (features, labels) = self._trainer_and_feed(stack)
+    call = {"step": trainer.train_step, "steps": trainer.train_steps,
+            "accum": trainer.train_step_accum}[kind]
+    state = [trainer.create_train_state()]
+
+    def run():
+      for _ in range(3):
+        state[0], _ = call(state[0], features, labels)
+
+    spans = self._spans_of(run, {"train/dispatch"})
+    assert [s["kind"] for s in spans] == [kind] * 3
+    assert int(state[0].step) == 3 * (stack if kind == "steps" else 1)
+
+  def test_prefetch_splits_input_wait_from_put(self):
+    import time
+
+    import numpy as np
+
+    from tensor2robot_tpu.data.prefetch import prefetch_to_device
+
+    def slow_host():
+      for i in range(3):
+        time.sleep(0.02)
+        yield {"x": np.full((4, 2), i, np.float32)}
+
+    spans = self._spans_of(
+        lambda: list(prefetch_to_device(slow_host(), depth=2)),
+        {"input/wait", "input/put"})
+    waits = [s for s in spans if s["name"] == "input/wait"]
+    puts = [s for s in spans if s["name"] == "input/put"]
+    # One wait per batch and one that finds the stream ended.
+    assert len(waits) == 4 and len(puts) == 3
+    assert all(s["dur_s"] >= 0.015 for s in waits[:3])
+    assert all(s["bytes"] == 32 for s in puts)
+
+  def test_train_loop_spans_readback_and_checkpoint(self, tmp_path):
+    from tensor2robot_tpu.data.default_input_generator import (
+        DefaultRandomInputGenerator)
+    from tensor2robot_tpu.train.train_eval import train_eval_model
+    from tensor2robot_tpu.utils.mocks import MockT2RModel
+
+    spans = self._spans_of(
+        lambda: train_eval_model(
+            MockT2RModel(),
+            input_generator_train=DefaultRandomInputGenerator(
+                batch_size=8, seed=0),
+            max_train_steps=4, log_every_steps=2,
+            model_dir=str(tmp_path), save_checkpoints_steps=4),
+        {"train/dispatch", "train/readback", "train/checkpoint",
+         "input/wait", "input/put"})
+    count = lambda name: sum(1 for s in spans if s["name"] == name)
+    assert count("train/dispatch") == 4
+    assert count("train/readback") == 2
+    assert count("train/checkpoint") >= 1
+    assert count("input/put") >= 4 and count("input/wait") >= 4
 
 
 @pytest.fixture(scope="module")

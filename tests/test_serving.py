@@ -196,6 +196,57 @@ class TestMicroBatcher:
     # a hair early on coarse clocks).
     assert snap["latency_p50_ms"] >= 18.0
 
+  def test_queue_wait_is_exported_apart_from_service_time(self):
+    """Held for a known time, every request's wait (enqueue to the
+    start of its flush) reads at least that time and no more than its
+    latency: in the stats, in the registry histogram and on the
+    serve/flush span, whose duration is the rest of the latency."""
+    from tensor2robot_tpu.obs import trace as trace_lib
+    from tensor2robot_tpu.obs.registry import MetricRegistry
+    from tensor2robot_tpu.serving.stats import ServingStats
+
+    registry = MetricRegistry()
+    stats = ServingStats(registry=registry)
+    hold_s, service_s = 0.08, 0.03
+
+    def batch_fn(items):
+      time.sleep(service_s)
+      return list(items)
+
+    from tensor2robot_tpu.serving.batcher import MicroBatcher
+    with MicroBatcher(batch_fn, max_batch=4, deadline_ms=0.0,
+                      stats=stats) as batcher:
+      with batcher.hold_flushes():
+        futures = [batcher.submit(i, request_id=f"wait-{i}")
+                   for i in range(3)]
+        time.sleep(hold_s)
+      [f.result(timeout=10) for f in futures]
+    snap = stats.snapshot()
+    assert hold_s * 1e3 <= snap["queue_wait_p50_ms"] <= snap[
+        "queue_wait_p99_ms"]
+    assert snap["queue_wait_p99_ms"] <= snap["latency_max_ms"] - (
+        service_s * 1e3 * 0.9)
+    waits = registry.snapshot()
+    assert waits["serving/queue_wait_ms/count"] == 3
+    assert waits["serving/queue_wait_ms/p50"] >= hold_s * 1e3
+    (flush,) = [s for s in trace_lib.get_tracer().spans()
+                if s["name"] == "serve/flush"
+                and "wait-0" in str(s.get("request_ids"))]
+    assert flush["batch"] == 3
+    assert hold_s * 1e3 <= flush["queue_wait_ms_max"] <= snap[
+        "latency_max_ms"]
+    assert (3 * hold_s * 1e3 <= flush["queue_wait_ms_sum"]
+            <= 3 * flush["queue_wait_ms_max"])
+    # Wait + the span = the latency (the future resolves just after).
+    assert flush["queue_wait_ms_max"] + flush["dur_s"] * 1e3 == (
+        pytest.approx(snap["latency_max_ms"], abs=5.0))
+
+  def test_snapshot_without_flushes_has_no_queue_wait(self):
+    from tensor2robot_tpu.serving.stats import ServingStats
+    snap = ServingStats().snapshot()
+    assert snap["queue_wait_p50_ms"] is None
+    assert snap["queue_wait_p99_ms"] is None
+
 
 class TestSLOBatcher:
   """ISSUE 10: EDF admission, priority shedding, and the deadline edge
@@ -614,6 +665,90 @@ class TestCEMFleetPolicy:
     host_out = CEMFleetPolicy(HostOnly(tiny_predictor), **kwargs)(
         images, seeds)
     np.testing.assert_allclose(device_out, host_out, atol=1e-4)
+
+  def test_flush_phases_nest_under_the_replica_dispatch(
+      self, tiny_predictor):
+    """One flush through a replica leaves exactly one each of the five
+    phase spans, nested under serve/dispatch (itself under the
+    batcher's serve/flush), inside it in time, carrying the flush's
+    request_ids; a compile inside a flush has a span of its own and
+    lies outside serve/put."""
+    import jax
+
+    from tensor2robot_tpu.obs import trace as trace_lib
+    from tensor2robot_tpu.obs.registry import MetricRegistry
+    from tensor2robot_tpu.serving.router import FleetRouter
+    from tensor2robot_tpu.serving.stats import ServingStats
+
+    router = FleetRouter(
+        tiny_predictor, devices=jax.devices()[:1], num_samples=16,
+        num_elites=4, iterations=2, seed=0, ladder_sizes=(1, 4),
+        stats=ServingStats(registry=MetricRegistry()))
+    router.warmup(tiny_predictor.make_image)
+    ids = [f"phase-{i}" for i in range(3)]
+    with router:
+      with router.replicas[0].batcher.hold_flushes():
+        futures = [router.submit(tiny_predictor.make_image(i),
+                                 request_id=rid)
+                   for i, rid in enumerate(ids)]
+      [f.result(timeout=30) for f in futures]
+    spans = trace_lib.get_tracer().spans()
+    mine = [s for s in spans
+            if str(s.get("request_ids", "")) == ",".join(ids)]
+    by_name = {}
+    for s in mine:
+      by_name.setdefault(s["name"], []).append(s)
+    phases = ("serve/stack", "serve/pad", "serve/put", "serve/execute",
+              "serve/readback")
+    assert sorted(by_name) == sorted(
+        phases + ("serve/flush", "serve/dispatch")), sorted(by_name)
+    assert all(len(rows) == 1 for rows in by_name.values())
+    (dispatch,), (flush,) = by_name["serve/dispatch"], by_name["serve/flush"]
+    assert dispatch["parent"] == "serve/flush"
+    end = lambda s: s["ts_s"] + s["dur_s"]
+    for name in phases:
+      (phase,) = by_name[name]
+      assert phase["parent"] == "serve/dispatch"
+      assert phase["tid"] == dispatch["tid"] == flush["tid"]
+      assert dispatch["ts_s"] <= phase["ts_s"]
+      assert end(phase) <= end(dispatch) + 1e-5
+    assert sum(by_name[n][0]["dur_s"] for n in phases) <= (
+        dispatch["dur_s"] + 1e-5)
+    assert dispatch["dur_s"] <= flush["dur_s"] + 1e-5
+    assert by_name["serve/stack"][0]["rows"] == 3
+    assert by_name["serve/stack"][0]["bytes"] == 3 * np.asarray(
+        tiny_predictor.make_image(0)).nbytes
+    assert by_name["serve/pad"][0]["bucket"] == 4
+    assert by_name["serve/execute"][0]["bucket"] == 4
+    # Warm-up compiled both rungs, each under its own span, none of
+    # them inside a put.
+    compiles = [s for s in spans if s["name"] == "serve/compile"
+                and s["tid"] == threading.get_ident()]
+    assert {s["bucket"] for s in compiles[-2:]} == {1, 4}
+    assert all(s.get("parent") != "serve/put" for s in compiles)
+
+  def test_same_answers_with_and_without_a_ledger(self, tiny_predictor):
+    """The device path is one path: a ledger only adds the dispatch
+    record (seconds from the phase spans' own clock reads)."""
+    from tensor2robot_tpu.obs.ledger import ExecutableLedger
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+
+    kwargs = dict(action_size=4, num_samples=32, num_elites=4,
+                  iterations=2, seed=3)
+    images = [tiny_predictor.make_image(i) for i in range(3)]
+    seeds = [2, 4, 6]
+    plain = CEMFleetPolicy(tiny_predictor, **kwargs)
+    ledger = ExecutableLedger()
+    ledgered = CEMFleetPolicy(tiny_predictor, ledger=ledger, **kwargs)
+    actions, scores = plain(images, seeds, return_scores=True)
+    ledger_actions, ledger_scores = ledgered(images, seeds,
+                                             return_scores=True)
+    np.testing.assert_array_equal(actions, ledger_actions)
+    np.testing.assert_array_equal(scores, ledger_scores)
+    np.testing.assert_array_equal(plain(images, seeds), actions)
+    (row,) = ledger.attribution()["executables"]
+    assert row["compiles"] == 1 and row["dispatches"] == 1
+    assert 0.0 < row["seconds_total"] < 60.0
 
 
 class TestPredictBatched:
