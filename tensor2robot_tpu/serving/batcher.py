@@ -1,11 +1,19 @@
 """Deadline-driven, SLO-aware micro-batcher for multi-client inference.
 
 Concurrent clients enqueue one item each (``submit`` returns a Future);
-a single dispatcher thread flushes pending requests into ``batch_fn``
-when either (a) ``max_batch`` requests are pending, or (b) the pending
+a dispatcher thread flushes pending requests into ``batch_fn`` when
+either (a) ``max_batch`` requests are pending, or (b) the pending
 request with the EARLIEST deadline has exhausted its budget — so a lone
 robot never waits longer than its class's deadline, and a busy fleet
 always ships full batches.
+
+With ``flush_depth`` above 1 that many dispatchers share the queue, so
+up to that many flushes are open at once: a FULL batch is popped at
+once even while another flush is in flight (its host work then runs
+while the device computes the other), a PARTIAL batch only once none
+is — the device could not have started it anyway, and popped early it
+would ship short, padded, and leave a smaller one behind. A load under
+one full batch per flush time is therefore served exactly as at depth 1.
 
 Ordering is **earliest-deadline-first** (serving/slo.py): every request
 carries an SLO class whose ``deadline_ms`` budget sets its absolute
@@ -96,6 +104,10 @@ class MicroBatcher:
       unbounded, the pre-SLO behavior. With a bound, an arrival into a
       full queue sheds the lowest-priority pending request
       (lowest SLOClass.priority; latest deadline breaks ties).
+    flush_depth: how many flushes may be open at once (one dispatcher
+      thread each; see the module docstring for what may overlap).
+      Above 1, `batch_fn` is called concurrently: only the owner of a
+      `batch_fn` that is safe to call so raises it.
   """
 
   def __init__(self, batch_fn: Callable[[Sequence[Any]], Sequence[Any]],
@@ -108,7 +120,8 @@ class MicroBatcher:
                watchdog: Optional[watchdog_lib.Watchdog] = None,
                fault_plan: Optional[faults_lib.FaultPlan] = None,
                site: str = "batcher",
-               restart_budget: int = 3):
+               restart_budget: int = 3,
+               flush_depth: int = 1):
     """See class docstring. `dispatch_margin_ms` budgets the flush's own
     cost: a partial batch ships `margin` BEFORE its head's deadline, so
     a class's p99 can actually sit inside its budget (set it to a
@@ -117,10 +130,12 @@ class MicroBatcher:
     receives every shed as an SLO-breach trigger and the dispatcher's
     unhandled exceptions — dumps fire only once a dump_dir is
     configured on it. `watchdog` (default: the process watchdog) gets a
-    per-instance dispatcher heartbeat: beats per flush, idle while the
-    queue is empty, so a dispatcher stuck with pending work (a wedged
-    batch_fn, a hold that outlived its test) is flagged as a stall —
-    but only once the owning deployment STARTS the watchdog monitor.
+    heartbeat per dispatcher: beats per flush, idle while the queue is
+    empty (or holds only a partial batch that waits for another
+    dispatcher's open flush), so a dispatcher stuck with pending work
+    (a wedged batch_fn, a hold that outlived its test) is flagged as a
+    stall whatever the other dispatchers do — but only once the owning
+    deployment STARTS the watchdog monitor.
 
     `fault_plan` (ISSUE 14) is the deterministic injection seam: each
     flush checks the plan's ``batcher_flush`` point under this
@@ -147,6 +162,8 @@ class MicroBatcher:
     if restart_budget < 0:
       raise ValueError(
           f"restart_budget must be >= 0, got {restart_budget}")
+    if flush_depth < 1:
+      raise ValueError(f"flush_depth must be >= 1, got {flush_depth}")
     self._batch_fn = batch_fn
     self._max_batch = max_batch
     self._margin_s = dispatch_margin_ms / 1e3
@@ -156,17 +173,19 @@ class MicroBatcher:
     self._max_queue = max_queue
     self._recorder = flight_recorder or flight_lib.get_recorder()
     self._watchdog = watchdog or watchdog_lib.get_watchdog()
-    self._heartbeat: Optional[watchdog_lib.Heartbeat] = None
+    # One dispatcher thread and one heartbeat per slot of the depth.
+    self._heartbeats: list = [None] * flush_depth
+    self._threads: list = [None] * flush_depth
     # Min-heap of (deadline, seq, request); shed entries stay in the
     # heap with request.shed=True and are skipped on pop (lazy
     # deletion), _live tracks the real pending count.
     self._heap: list = []
     self._live = 0
-    self._in_flight = 0
+    self._in_flight = 0  # requests popped and not yet answered
+    self._open_flushes = 0  # batches popped and not yet finished
     self._seq = itertools.count()
     self._cond = threading.Condition()
     self._running = False
-    self._thread: Optional[threading.Thread] = None
     self._release = threading.Event()  # hold_flushes gate; normally set
     self._release.set()
     # Fault-tolerance state (ISSUE 14): the injection seam and the
@@ -191,38 +210,43 @@ class MicroBatcher:
         raise DispatcherDead("cannot restart a batcher that exhausted "
                              "its dispatcher restart budget")
       self._running = True
-    self._heartbeat = self._watchdog.register("serve/batcher")
-    self._spawn_dispatcher()
+    for slot in range(len(self._threads)):
+      self._heartbeats[slot] = self._watchdog.register("serve/batcher")
+      self._spawn_dispatcher(slot)
     return self
 
-  def _spawn_dispatcher(self) -> None:
-    self._thread = threading.Thread(
-        target=self._dispatcher_main, name="micro-batcher", daemon=True)
-    self._thread.start()
+  def _spawn_dispatcher(self, slot: int) -> None:
+    thread = threading.Thread(
+        target=self._dispatcher_main, args=(slot,), name="micro-batcher",
+        daemon=True)
+    self._threads[slot] = thread
+    thread.start()
 
   def stop(self) -> None:
-    """Stops accepting work, drains what is queued, joins the thread.
+    """Stops accepting work, drains what is queued, joins the threads.
 
-    Safe on a batcher whose dispatcher already died (the heartbeat is
+    Safe on a batcher whose dispatcher already died (the heartbeats are
     unregistered either way), and against a concurrent dispatcher
-    RESTART: the join loops until the thread reference stops changing,
-    so a death-and-respawn racing the stop cannot leak a live thread.
+    RESTART: each slot's join loops until its thread reference stops
+    changing, so a death-and-respawn racing the stop cannot leak a live
+    thread.
     """
     with self._cond:
       self._running = False
       self._cond.notify_all()
-    while True:
-      thread = self._thread
-      if thread is None or thread is threading.current_thread():
-        break
-      thread.join()
-      if self._thread is thread:
-        self._thread = None
-        break
-      # A restart swapped the thread mid-join; join the successor too.
-    if self._heartbeat is not None:
-      self._watchdog.unregister(self._heartbeat)
-      self._heartbeat = None
+    for slot in range(len(self._threads)):
+      while True:
+        thread = self._threads[slot]
+        if thread is None or thread is threading.current_thread():
+          break
+        thread.join()
+        if self._threads[slot] is thread:
+          self._threads[slot] = None
+          break
+        # A restart swapped the thread mid-join; join the successor too.
+      if self._heartbeats[slot] is not None:
+        self._watchdog.unregister(self._heartbeats[slot])
+        self._heartbeats[slot] = None
 
   def __enter__(self) -> "MicroBatcher":
     return self.start()
@@ -392,7 +416,7 @@ class MicroBatcher:
 
   # -- dispatcher ----------------------------------------------------------
 
-  def _dispatcher_main(self) -> None:
+  def _dispatcher_main(self, slot: int) -> None:
     """Thread entry: the loop plus the DEATH handler (ISSUE 14). An
     escaping non-Exception (a poison request aborting the thread, an
     injected thread_kill) used to leave every queued client hanging —
@@ -401,11 +425,11 @@ class MicroBatcher:
     down LOUDLY: all pending futures resolve DispatcherDead, and the
     heartbeat stays armed-busy for the watchdog escalation."""
     try:
-      self._dispatch_loop()
+      self._dispatch_loop(slot)
     except BaseException as e:  # noqa: BLE001 — the death handler
-      self._on_dispatcher_death(e)
+      self._on_dispatcher_death(e, slot)
 
-  def _on_dispatcher_death(self, exc: BaseException) -> None:
+  def _on_dispatcher_death(self, exc: BaseException, slot: int) -> None:
     detail = f"{type(exc).__name__}: {exc}"
     with self._cond:
       restart = (self._running
@@ -428,8 +452,9 @@ class MicroBatcher:
       pass  # diagnostics never block the recovery path
     if restart:
       # The queue (and its futures) survive: only the batch that was
-      # in flight when the thread died has already been failed typed.
-      self._spawn_dispatcher()
+      # in flight ON THIS THREAD when it died has already been failed
+      # typed; another dispatcher's open flush finishes as it would.
+      self._spawn_dispatcher(slot)
       return
     # Unrecoverable: resolve EVERY pending future — a dead dispatcher
     # must never leave a client blocked in result(). The heartbeat is
@@ -438,7 +463,7 @@ class MicroBatcher:
     # watchdog monitor escalates it (counter -> dump -> callback);
     # stop() unregisters it when the owner shuts the batcher down.
     self._fail_all_pending(DispatcherDead(detail))
-    heartbeat = self._heartbeat
+    heartbeat = self._heartbeats[slot]
     if heartbeat is not None:
       heartbeat.busy()
 
@@ -463,13 +488,13 @@ class MicroBatcher:
     for request in pending:
       self._resolve_failed(request.future, exc)
 
-  def _dispatch_loop(self) -> None:
+  def _dispatch_loop(self, slot: int) -> None:
     while True:
-      batch, deadline_expired = self._next_batch()
+      batch, deadline_expired, others_open = self._next_batch(slot)
       if batch is None:
         return
       try:
-        self._flush(batch, deadline_expired)
+        self._flush(batch, deadline_expired, others_open)
       except Exception as e:  # e.g. a raising bucket_for/stats hook —
         # the dispatcher must outlive ANY flush failure or every
         # queued and future request hangs unresolved.
@@ -491,28 +516,41 @@ class MicroBatcher:
       finally:
         with self._cond:
           self._in_flight -= len(batch)
+          self._open_flushes -= 1
+          # A partial batch that waited for this flush may be due now.
+          self._cond.notify_all()
 
-  def _next_batch(self):
-    """Blocks until a flush is due; returns (requests, deadline_expired).
+  def _next_batch(self, slot: int):
+    """Blocks until a flush is due; returns (requests, deadline_expired,
+    others_open): the last is how many other flushes of this batcher
+    were open when this one was popped.
 
-    (None, _) signals shutdown with an empty queue — on stop() the
+    (None, _, _) signals shutdown with an empty queue — on stop() the
     queue is drained (every accepted Future resolves) before exit.
+
+    What may be popped: a full batch always, even while another flush
+    is open; a partial one (its head's flush time passed, or draining
+    on stop()) only while none is — the flush that ends notifies.
 
     No-busy-spin invariant: every pass either returns a batch, or waits
     with a STRICTLY positive timeout (now < head deadline on that
-    branch), or waits untimed on an empty queue — a zero-slack deadline
-    therefore flushes immediately rather than re-arming a zero-length
-    wait in a loop.
+    branch), or waits untimed (an empty queue, or a partial batch
+    behind an open flush) — a zero-slack deadline therefore flushes
+    immediately rather than re-arming a zero-length wait in a loop.
     """
-    heartbeat = self._heartbeat
+    heartbeat = self._heartbeats[slot]
     with self._cond:
       while True:
         self._dispatch_iterations += 1
-        # Liveness: pending work arms the stall clock (busy), an empty
-        # queue is intentional waiting (idle) — so a dispatcher wedged
-        # with live requests is a stall, a quiet fleet is not.
+        full = self._live >= self._max_batch
+        may_pop = full or self._open_flushes == 0
+        # Liveness: work this dispatcher may take arms the stall clock
+        # (busy); an empty queue, or a partial batch that waits for
+        # another dispatcher's flush, is intentional waiting (idle) —
+        # so a dispatcher wedged with live requests is a stall, a quiet
+        # fleet is not, and a wedged flush is its own dispatcher's.
         if heartbeat is not None:
-          if self._live > 0:
+          if self._live > 0 and may_pop:
             heartbeat.busy()
           else:
             heartbeat.idle()
@@ -528,8 +566,7 @@ class MicroBatcher:
         head = self._head_flush_at_locked()
         if head is not None:
           now = time.perf_counter()
-          if (self._live >= self._max_batch or now >= head
-              or not self._running):
+          if full or (may_pop and (now >= head or not self._running)):
             n = min(self._live, self._max_batch)
             batch = []
             while len(batch) < n:
@@ -538,17 +575,21 @@ class MicroBatcher:
                 batch.append(request)
             self._live -= n
             self._in_flight += n
+            others_open = self._open_flushes
+            self._open_flushes += 1
             expired = now >= head and n < self._max_batch
             if heartbeat is not None:
               heartbeat.beat()
-            return batch, expired
-          self._cond.wait(timeout=head - now)
+            return batch, expired, others_open
+          # Untimed behind an open flush: its end notifies.
+          self._cond.wait(timeout=head - now if may_pop else None)
         elif not self._running:
-          return None, False
+          return None, False, 0
         else:
           self._cond.wait()
 
-  def _flush(self, batch, deadline_expired: bool) -> None:
+  def _flush(self, batch, deadline_expired: bool,
+             others_open: int) -> None:
     # Transition each future to RUNNING first: a request whose client
     # gave up (future.cancel() after a result() timeout) is dropped
     # from the flush, and the ones that remain can no longer be
@@ -577,7 +618,8 @@ class MicroBatcher:
       waits_ms = [(flush_start - r.enqueued_at) * 1e3 for r in batch]
       with trace_lib.span("serve/flush", batch=len(batch),
                           queue_wait_ms_sum=round(sum(waits_ms), 3),
-                          queue_wait_ms_max=round(max(waits_ms), 3)):
+                          queue_wait_ms_max=round(max(waits_ms), 3),
+                          in_flight=others_open):
         try:
           results = self._batch_fn([r.item for r in batch])
         except Exception as e:  # fail the flush's requests, not the loop
@@ -600,3 +642,5 @@ class MicroBatcher:
       self._stats.record_flush(
           len(batch), self._bucket_for(len(batch)), depth_after,
           deadline_expired)
+      if others_open:
+        self._stats.record_overlapped_flush()

@@ -109,6 +109,13 @@ class CEMFleetPolicy:
     self._compile_lock = threading.Lock()
     self._seed_lock = threading.Lock()
     self._place_lock = threading.Lock()
+    # The device turn: one caller at a time between the enqueue of its
+    # program and its answer. Concurrent callers (a replica with two
+    # flushes open) stack, pad and put side by side and queue here, so
+    # the device never holds two programs' temporaries (a rung-32
+    # program's are 7 GB of a 16 GB chip). Never contended with one
+    # caller at a time.
+    self._turn = threading.Lock()
     self._next_seed = 0
 
   @property
@@ -167,7 +174,8 @@ class CEMFleetPolicy:
             "variables override requires the predictor's device path "
             "(the host fallback scores through predictor.predict, whose "
             "params cannot be swapped per call).")
-      actions = self._host_call(batch, seeds)
+      with self._turn:  # predictor.predict was never called concurrently
+        actions = self._host_call(batch, seeds)
       return (actions, None) if return_scores else actions
     variables = self._place(
         live_variables if variables is None else variables)
@@ -184,13 +192,20 @@ class CEMFleetPolicy:
                         bytes=padded.nbytes + padded_seeds.nbytes) as put:
       device_images = self._put(padded)
       device_seeds = self._put(padded_seeds)
-    # Returns at enqueue.
-    with trace_lib.span("serve/execute", bucket=bucket):
-      actions, scores = compiled(variables, device_images, device_seeds)
-    # The wait for that transfer and for the device, then D2H.
-    with trace_lib.span("serve/readback") as readback:
-      actions = np.asarray(actions)[:n]
-      scores = np.asarray(scores)[:n]
+    # The wait for another caller's program, if one is on the device:
+    # the transfer above goes on beside it.
+    with trace_lib.span("serve/turn", bucket=bucket):
+      self._turn.acquire()
+    try:
+      # Returns at enqueue.
+      with trace_lib.span("serve/execute", bucket=bucket):
+        actions, scores = compiled(variables, device_images, device_seeds)
+      # The wait for that transfer and for the device, then D2H.
+      with trace_lib.span("serve/readback") as readback:
+        actions = np.asarray(actions)[:n]
+        scores = np.asarray(scores)[:n]
+    finally:
+      self._turn.release()
     if self._ledger is not None:
       # Dispatch through completion, on the spans' own clock reads.
       self._ledger.record_dispatch(

@@ -76,7 +76,12 @@ class PolicyReplica:
         max_queue=max_queue, dispatch_margin_ms=dispatch_margin_ms,
         flight_recorder=flight_recorder,
         fault_plan=fault_plan, site=f"batcher@{policy.device}",
-        restart_budget=restart_budget)
+        restart_budget=restart_budget,
+        # Two flushes open: the next full batch is stacked, padded and
+        # put while the device runs the current one (the policy's call
+        # path is asynchronous up to its device turn, and everything
+        # `_flush` touches is per call or locked).
+        flush_depth=2)
 
   def use_policy(self, policy: CEMFleetPolicy) -> None:
     """Hot-swaps this replica's policy (the precision-tier promotion

@@ -152,6 +152,7 @@ class ServingStats:
     self._occupied_slots = 0   # sum of real requests over flushes
     self._padded_slots = 0     # sum of compiled bucket sizes over flushes
     self._deadline_flushes = 0  # flushed by deadline, not by a full batch
+    self._overlapped_flushes = 0  # popped while another flush was open
     self._queue_depth_sum = 0   # queue depth left behind at flush time
     self._per_class: Dict[str, _ClassStats] = {}
     self._q_sketches: Dict[str, QSketch] = {}
@@ -251,6 +252,13 @@ class ServingStats:
       if deadline_expired:
         self._deadline_flushes += 1
 
+  def record_overlapped_flush(self) -> None:
+    """One flush (already counted by `record_flush`) that was popped
+    while another flush of its batcher was still open: its host work
+    ran beside the other's device time (MicroBatcher `flush_depth`)."""
+    with self._lock:
+      self._overlapped_flushes += 1
+
   def record_latency_ms(self, latency_ms: float,
                         class_name: Optional[str] = None) -> None:
     self.latency.record(latency_ms)
@@ -283,6 +291,9 @@ class ServingStats:
           "logical_requests": self._logical_requests,
           "flushes": flushes,
           "deadline_flushes": self._deadline_flushes,
+          "overlapped_flushes": self._overlapped_flushes,
+          "flush_overlap_share": round(
+              self._overlapped_flushes / flushes, 4) if flushes else None,
           "batch_occupancy": round(
               self._occupied_slots / self._padded_slots, 4)
           if self._padded_slots else None,

@@ -147,6 +147,74 @@ class TestFleetRouter:
     assert not errors, errors
     assert min(flushed.values()) > 0, flushed
 
+  def test_two_open_flushes_feed_the_sinks_in_order_on_one_thread(
+      self, tiny_predictor):
+    """A replica keeps two flushes open (ISSUE 31). Each flush still
+    hands its scores to `record_q_values` and then its batch to
+    `record_served` on ONE thread, so a sink that pairs the two through
+    a thread-local (the benchmark's) reads every request's own Q."""
+    from tensor2robot_tpu.obs.registry import MetricRegistry
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+    from tensor2robot_tpu.serving.stats import ServingStats
+
+    class Sink(ServingStats):
+
+      def __init__(self):
+        super().__init__(registry=MetricRegistry())
+        self._local = threading.local()
+        self.calls, self.served = [], {}
+
+      def record_q_values(self, replica, values):
+        self._local.scores = np.array(values, np.float32)
+        self.calls.append((threading.get_ident(), "q", len(values)))
+        super().record_q_values(replica, values)
+
+      def record_served(self, items, actions, device, params_version=None):
+        scores, self._local.scores = self._local.scores, None
+        self.calls.append((threading.get_ident(), "served", len(items)))
+        for (_, seed), action, score in zip(items, actions, scores):
+          self.served[int(seed)] = (np.array(action), float(score))
+
+    sink = Sink()
+    router = _make_router(tiny_predictor, n_devices=1, max_batch=4,
+                          stats=sink, episode_recorder=sink)
+    router.warmup(tiny_predictor.make_image)
+    replica = router.replicas[0]
+    # Both flushes are inside the policy's call before either goes on.
+    both_open = threading.Barrier(2)
+    policy = replica.policy
+
+    class Meeting:
+      device, ladder, _predictor = (policy.device, policy.ladder,
+                                    policy._predictor)
+
+      def __call__(self, *args, **kwargs):
+        both_open.wait(timeout=10)
+        return policy(*args, **kwargs)
+
+    replica.policy = Meeting()
+    images = [tiny_predictor.make_image(70 + i) for i in range(8)]
+    with router:
+      with replica.batcher.hold_flushes():
+        futures = [router.submit(image, seed=500 + i)
+                   for i, image in enumerate(images)]
+      answers = np.stack([f.result(timeout=30) for f in futures])
+    assert sink.snapshot()["overlapped_flushes"] == 1
+    by_thread = {}
+    for tid, kind, n in sink.calls:
+      by_thread.setdefault(tid, []).append((kind, n))
+    assert sorted(by_thread.values()) == [[("q", 4), ("served", 4)]] * 2
+    alone = CEMFleetPolicy(tiny_predictor, action_size=4, num_samples=32,
+                           num_elites=4, iterations=2, seed=0)
+    actions, scores = alone(images, np.arange(500, 508, dtype=np.uint32),
+                            return_scores=True)
+    np.testing.assert_allclose(answers, actions, atol=1e-4)
+    assert sorted(sink.served) == list(range(500, 508))
+    for i in range(8):
+      served_action, served_score = sink.served[500 + i]
+      np.testing.assert_array_equal(served_action, answers[i])
+      assert served_score == pytest.approx(float(scores[i]), abs=1e-4)
+
   def test_warmed_but_unstarted_router_raises_typed(self, tiny_predictor):
     """ISSUE 19 satellite: warmup() compiles the ladders but does NOT
     start the batcher dispatch threads; submit() on a warmed-but-

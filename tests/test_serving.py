@@ -10,6 +10,7 @@ amortization included — on every PR without a TPU.
 
 import json
 import os
+import queue
 import subprocess
 import sys
 import threading
@@ -439,6 +440,243 @@ class TestSLOBatcher:
     assert "serving/latency_p50_ms" in record
 
 
+def _wait_until(predicate, timeout=10.0):
+  """Waits on STATE, with a limit: polls until `predicate()` holds."""
+  deadline = time.monotonic() + timeout
+  while not predicate():
+    assert time.monotonic() < deadline, "condition not reached in time"
+    time.sleep(0.001)
+
+
+class _GatedFn:
+  """A batch_fn whose every call announces itself on `entered` (its
+  items) and blocks until `release(first item)`; `log` keeps the order
+  of entries and exits, `calls` each call's items."""
+
+  def __init__(self):
+    self.entered = queue.Queue()
+    self.log = []
+    self.calls = []
+    self._gates = {}
+    self._lock = threading.Lock()
+
+  def _gate(self, key):
+    with self._lock:
+      return self._gates.setdefault(key, threading.Event())
+
+  def __call__(self, items):
+    items = list(items)
+    self.log.append(("enter", items[0]))
+    self.calls.append(items)
+    self.entered.put(items)
+    assert self._gate(items[0]).wait(timeout=10), "gate never released"
+    self.log.append(("exit", items[0]))
+    return items
+
+  def release(self, first_item):
+    self._gate(first_item).set()
+
+
+def _flush_span(request_id):
+  from tensor2robot_tpu.obs import trace as trace_lib
+  (span,) = [s for s in trace_lib.get_tracer().spans()
+             if s["name"] == "serve/flush"
+             and request_id in str(s.get("request_ids", "")).split(",")]
+  return span
+
+
+class TestOverlappedFlushes:
+  """ISSUE 31: `flush_depth` dispatchers share the queue. A full batch
+  is popped while another flush is open; a partial one only once none
+  is, so a load under one full batch per flush time is served exactly
+  as at depth 1. Every flush is gated on events: nothing here waits on
+  elapsed time."""
+
+  def _batcher(self, fn, depth, **kwargs):
+    from tensor2robot_tpu.serving.batcher import MicroBatcher
+    kwargs.setdefault("deadline_ms", 10_000.0)
+    return MicroBatcher(fn, flush_depth=depth, **kwargs)
+
+  @pytest.mark.parametrize("depth", [1, 2])
+  def test_second_full_batch_enters_while_the_first_is_blocked(
+      self, depth):
+    from tensor2robot_tpu.serving.stats import ServingStats
+    fn, stats = _GatedFn(), ServingStats()
+    tag = f"overlap-d{depth}"
+    with self._batcher(fn, depth, max_batch=2, stats=stats) as batcher:
+      futures = [batcher.submit(i, request_id=f"{tag}-{i}")
+                 for i in range(4)]
+      assert fn.entered.get(timeout=10) == [0, 1]
+      if depth == 2:
+        # In batch_fn while the first flush is still blocked in it.
+        assert fn.entered.get(timeout=10) == [2, 3]
+        assert batcher.pending() == 4
+      fn.release(0)
+      fn.release(2)
+      assert [f.result(timeout=10) for f in futures] == [0, 1, 2, 3]
+    order = [event for event, _ in fn.log]
+    assert order == (["enter", "enter", "exit", "exit"] if depth == 2
+                     else ["enter", "exit", "enter", "exit"]), fn.log
+    assert _flush_span(f"{tag}-0")["in_flight"] == 0
+    assert _flush_span(f"{tag}-2")["in_flight"] == depth - 1
+    snap = stats.snapshot()
+    assert snap["flushes"] == 2
+    assert snap["overlapped_flushes"] == depth - 1
+    assert snap["flush_overlap_share"] == pytest.approx((depth - 1) / 2)
+
+  def test_partial_batch_waits_for_the_open_flush_then_ships_at_once(
+      self):
+    """deadline 0: the partial batch's flush time has passed the
+    moment it arrives, and still it is not popped while a flush is
+    open; the end of that flush ships it (no timer involved)."""
+    from tensor2robot_tpu.serving.stats import ServingStats
+    fn, stats = _GatedFn(), ServingStats()
+    with self._batcher(fn, 2, max_batch=4, deadline_ms=0.0,
+                       stats=stats) as batcher:
+      full = [batcher.submit(i) for i in range(4)]
+      assert fn.entered.get(timeout=10) == [0, 1, 2, 3]
+      seen = batcher._dispatch_iterations
+      partial = [batcher.submit(i, request_id=f"partial-{i}")
+                 for i in (4, 5)]
+      # The free dispatcher has looked at the queue and declined.
+      _wait_until(lambda: batcher._dispatch_iterations > seen)
+      assert fn.entered.empty() and batcher._open_flushes == 1
+      settle = batcher._dispatch_iterations
+      fn.release(0)
+      assert [f.result(timeout=10) for f in full] == [0, 1, 2, 3]
+      assert fn.entered.get(timeout=10) == [4, 5]
+      fn.release(4)
+      assert [f.result(timeout=10) for f in partial] == [4, 5]
+      # Declining did not spin: a handful of passes, not a loop.
+      assert batcher._dispatch_iterations - settle <= 8
+    assert _flush_span("partial-4")["in_flight"] == 0
+    snap = stats.snapshot()
+    assert snap["overlapped_flushes"] == 0
+    assert snap["deadline_flushes"] == 1  # the partial one
+
+  @pytest.mark.parametrize("depth", [1, 2])
+  def test_under_a_full_batch_per_flush_the_sizes_are_depth_ones(
+      self, depth):
+    """One scripted arrival sequence, never a full batch pending:
+    whatever arrives during a flush ships together when it ends."""
+    fn = _GatedFn()
+    with self._batcher(fn, depth, max_batch=4,
+                       deadline_ms=0.0) as batcher:
+      futures = []
+      with batcher.hold_flushes():
+        futures += [batcher.submit(i) for i in (0, 1, 2)]
+      assert fn.entered.get(timeout=10) == [0, 1, 2]
+      open_flush = 0
+      for arrivals in ([3, 4], [5]):
+        seen = batcher._dispatch_iterations
+        futures.append(batcher.submit(arrivals[0]))
+        if depth == 2:  # the free dispatcher wakes, and has to decline
+          _wait_until(lambda: batcher._dispatch_iterations > seen)
+        futures += [batcher.submit(i) for i in arrivals[1:]]
+        fn.release(open_flush)
+        assert fn.entered.get(timeout=10) == arrivals
+        open_flush = arrivals[0]
+      fn.release(open_flush)
+      assert [f.result(timeout=10) for f in futures] == list(range(6))
+    assert fn.calls == [[0, 1, 2], [3, 4], [5]]
+
+  def test_stop_with_two_flushes_open_resolves_every_future(self):
+    fn = _GatedFn()
+    batcher = self._batcher(fn, 2, max_batch=2).start()
+    futures = [batcher.submit(i) for i in range(5)]
+    assert sorted([fn.entered.get(timeout=10),
+                   fn.entered.get(timeout=10)]) == [[0, 1], [2, 3]]
+    stopper = threading.Thread(target=batcher.stop)
+    stopper.start()  # joins both dispatchers: blocks on the gates
+    fn.release(0)
+    fn.release(2)
+    assert fn.entered.get(timeout=10) == [4]  # the drain's partial batch
+    fn.release(4)
+    stopper.join(timeout=10)
+    assert not stopper.is_alive()
+    assert [f.result(timeout=10) for f in futures] == list(range(5))
+    assert batcher._open_flushes == 0 and batcher.pending() == 0
+    with pytest.raises(RuntimeError):
+      batcher.submit(99)
+
+  def test_hold_flushes_gates_both_dispatchers(self):
+    fn = _GatedFn()
+    with self._batcher(fn, 2, max_batch=2) as batcher:
+      with batcher.hold_flushes():
+        seen = batcher._dispatch_iterations
+        futures = [batcher.submit(i, request_id=f"held-{i}")
+                   for i in range(4)]
+        # Two full batches pending and both dispatchers have looked.
+        _wait_until(lambda: batcher._dispatch_iterations >= seen + 2)
+        assert fn.entered.empty() and batcher._open_flushes == 0
+        assert batcher.pending() == 4
+      assert sorted([fn.entered.get(timeout=10),
+                     fn.entered.get(timeout=10)]) == [[0, 1], [2, 3]]
+      fn.release(0)
+      fn.release(2)
+      assert [f.result(timeout=10) for f in futures] == [0, 1, 2, 3]
+    assert sorted(_flush_span(f"held-{i}")["in_flight"]
+                  for i in (0, 2)) == [0, 1]
+
+  def test_thread_kill_in_one_open_flush_fails_only_that_batch(self):
+    from tensor2robot_tpu.obs import faults
+    from tensor2robot_tpu.serving.slo import DispatcherDead
+    fn = _GatedFn()
+    plan = faults.FaultPlan([faults.FaultSpec(
+        kind="thread_kill", point="batcher_flush", site="two", at=1)])
+    with self._batcher(fn, 2, max_batch=2, fault_plan=plan, site="two",
+                       restart_budget=1) as batcher:
+      first = [batcher.submit(i) for i in (0, 1)]
+      assert fn.entered.get(timeout=10) == [0, 1]
+      # The second flush is popped beside the first and dies at the
+      # fault seam: its batch, and only its batch, fails typed.
+      killed = [batcher.submit(i) for i in (2, 3)]
+      for future in killed:
+        with pytest.raises(DispatcherDead):
+          future.result(timeout=10)
+      _wait_until(lambda: batcher.dispatcher_restarts == 1)
+      assert not batcher.dispatcher_dead and not first[0].done()
+      # The restarted dispatcher serves beside the still-open flush.
+      later = [batcher.submit(i) for i in (4, 5)]
+      assert fn.entered.get(timeout=10) == [4, 5]
+      fn.release(4)
+      assert [f.result(timeout=10) for f in later] == [4, 5]
+      fn.release(0)
+      assert [f.result(timeout=10) for f in first] == [0, 1]
+    assert batcher.dispatcher_restarts == 1
+    assert plan.fired_counts() == {"thread_kill": 1}
+
+  def test_hung_flush_is_reported_while_the_other_dispatcher_runs(self):
+    from tensor2robot_tpu.obs import watchdog as watchdog_lib
+    from tensor2robot_tpu.obs.registry import MetricRegistry
+    fn = _GatedFn()
+    watchdog = watchdog_lib.Watchdog(default_deadline_s=30.0,
+                                     registry=MetricRegistry())
+    with self._batcher(fn, 2, max_batch=2,
+                       watchdog=watchdog) as batcher:
+      hung = [batcher.submit(i) for i in (0, 1)]
+      assert fn.entered.get(timeout=10) == [0, 1]  # never released: hung
+      for first in (2, 4, 6):  # the other dispatcher keeps beating
+        served = [batcher.submit(i) for i in (first, first + 1)]
+        assert fn.entered.get(timeout=10) == [first, first + 1]
+        fn.release(first)
+        assert [f.result(timeout=10) for f in served] == [first, first + 1]
+      heartbeats = list(batcher._heartbeats)
+      _wait_until(lambda: sum(h.is_idle for h in heartbeats) == 1)
+      (running,) = [h for h in heartbeats if h.is_idle]
+      (wedged,) = [h for h in heartbeats if not h.is_idle]
+      assert running.beats == 3 and wedged.beats == 1
+      events = watchdog.check_once(now=time.monotonic() + 60.0)
+      assert [e["component"] for e in events] == [wedged.name]
+      assert events[0]["event"] == "watchdog_stall"
+      fn.release(0)
+      assert [f.result(timeout=10) for f in hung] == [0, 1]
+
+  def test_depth_must_be_positive(self):
+    with pytest.raises(ValueError, match="flush_depth"):
+      self._batcher(lambda items: list(items), 0)
+
+
 class TestHotReloadLedger:
 
   def test_param_refresh_never_recompiles_bucket_executables(self):
@@ -668,7 +906,7 @@ class TestCEMFleetPolicy:
 
   def test_flush_phases_nest_under_the_replica_dispatch(
       self, tiny_predictor):
-    """One flush through a replica leaves exactly one each of the five
+    """One flush through a replica leaves exactly one each of the six
     phase spans, nested under serve/dispatch (itself under the
     batcher's serve/flush), inside it in time, carrying the flush's
     request_ids; a compile inside a flush has a span of its own and
@@ -698,8 +936,8 @@ class TestCEMFleetPolicy:
     by_name = {}
     for s in mine:
       by_name.setdefault(s["name"], []).append(s)
-    phases = ("serve/stack", "serve/pad", "serve/put", "serve/execute",
-              "serve/readback")
+    phases = ("serve/stack", "serve/pad", "serve/put", "serve/turn",
+              "serve/execute", "serve/readback")
     assert sorted(by_name) == sorted(
         phases + ("serve/flush", "serve/dispatch")), sorted(by_name)
     assert all(len(rows) == 1 for rows in by_name.values())
@@ -720,12 +958,78 @@ class TestCEMFleetPolicy:
         tiny_predictor.make_image(0)).nbytes
     assert by_name["serve/pad"][0]["bucket"] == 4
     assert by_name["serve/execute"][0]["bucket"] == 4
+    assert by_name["serve/turn"][0]["bucket"] == 4
+    assert flush["in_flight"] == 0
     # Warm-up compiled both rungs, each under its own span, none of
     # them inside a put.
     compiles = [s for s in spans if s["name"] == "serve/compile"
                 and s["tid"] == threading.get_ident()]
     assert {s["bucket"] for s in compiles[-2:]} == {1, 4}
     assert all(s.get("parent") != "serve/put" for s in compiles)
+
+  def test_concurrent_calls_take_turns_on_the_device(self, tiny_predictor):
+    """Two callers at once (a replica with two flushes open): each gets
+    the sequential call's actions and scores for every (image, seed),
+    nothing recompiles, and the second's program is not enqueued until
+    the first's answer is back — while its stack and put run beside it."""
+    from tensor2robot_tpu.obs import trace as trace_lib
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+
+    policy = CEMFleetPolicy(tiny_predictor, action_size=4, num_samples=32,
+                            num_elites=4, iterations=2, seed=3)
+    batches = [([tiny_predictor.make_image(10 * k + i) for i in range(n)],
+                np.arange(100 * k, 100 * k + n, dtype=np.uint32))
+               for k, n in ((1, 4), (2, 3))]  # both bucket 4
+    sequential = [policy(images, seeds, return_scores=True)
+                  for images, seeds in batches]
+    # The first caller is held inside its device turn until the second
+    # has put its inputs and queues for the turn.
+    real, gate, inside = policy._executables[4], threading.Event(), []
+
+    def held(*args):
+      inside.append(threading.get_ident())
+      if len(inside) == 1:
+        assert gate.wait(timeout=10)
+      return real(*args)
+
+    policy._executables[4] = held
+    results, tids = [None, None], [None, None]
+    with trace_lib.get_tracer().span("test/concurrent_calls") as mark:
+      pass  # thread ids are reused: only spans from here on count
+
+    def call(k):
+      tids[k] = threading.get_ident()
+      results[k] = policy(*batches[k], return_scores=True)
+
+    threads = [threading.Thread(target=call, args=(k,)) for k in (0, 1)]
+    threads[0].start()
+    _wait_until(lambda: len(inside) == 1)
+    threads[1].start()
+    spans_of = lambda k, name: [
+        s for s in trace_lib.get_tracer().spans()
+        if s["tid"] == tids[k] and s["name"] == name
+        and s["ts_s"] >= mark["ts_s"]]
+    _wait_until(lambda: tids[1] is not None and spans_of(1, "serve/put"))
+    assert len(inside) == 1 and policy._turn.locked()
+    gate.set()
+    for thread in threads:
+      thread.join(timeout=30)
+      assert not thread.is_alive()
+    for (actions, scores), (seq_actions, seq_scores) in zip(results,
+                                                            sequential):
+      np.testing.assert_array_equal(actions, seq_actions)
+      np.testing.assert_array_equal(scores, seq_scores)
+    assert policy.compile_counts == {4: 1}
+    end = lambda s: s["ts_s"] + s["dur_s"]
+    (execute0,), (readback0,) = (spans_of(0, "serve/execute"),
+                                 spans_of(0, "serve/readback"))
+    (execute1,), (turn1,), (stack1,) = (
+        spans_of(1, "serve/execute"), spans_of(1, "serve/turn"),
+        spans_of(1, "serve/stack"))
+    assert execute1["ts_s"] >= end(readback0)
+    assert turn1["ts_s"] < end(readback0) <= end(turn1) + 1e-5
+    # The host phases did overlap the first caller's device turn.
+    assert execute0["ts_s"] < stack1["ts_s"] < end(readback0)
 
   def test_same_answers_with_and_without_a_ledger(self, tiny_predictor):
     """The device path is one path: a ledger only adds the dispatch
