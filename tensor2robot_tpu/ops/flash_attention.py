@@ -29,6 +29,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from tensor2robot_tpu.ops import dispatch
 
+# The three kernels' names, as the device trace shows them: forward
+# (run again where a block is rematerialized), dq and dk/dv.
+KERNEL_NAMES = ("flash_attention_fwd", "flash_attention_dq",
+                "flash_attention_dkv")
+
 _BLOCK = 128
 _MAX_SINGLE_BLOCK_T = 1024
 # K and V are staged whole per (b·h) row, and Pallas double-buffers
@@ -39,11 +44,16 @@ _MAX_SINGLE_BLOCK_T = 1024
 # Longer sequences belong to ring_attention.
 _MAX_KV_VMEM_BYTES = 14 * 1024 * 1024
 _PIPELINE_BUFFERS = 2
+# Room beside the staged blocks for a kernel's float32 working set
+# (the cast blocks, the score tile, the accumulators).
+_VMEM_WORKING_BYTES = 8 * 1024 * 1024
+_MIN_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 
 
 def flash_attention_reference(q, k, v, causal: bool = False,
                               scale: Optional[float] = None):
-  """XLA reference: materializes (B, H, T, T) scores. (B, T, H, D) in/out."""
+  """XLA reference: materializes (B, H, T, T) scores. (B, T, H, D) in/out;
+  v (and so the output) may have a head width of its own."""
   if scale is None:
     scale = 1.0 / math.sqrt(q.shape[-1])
   scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
@@ -54,7 +64,7 @@ def flash_attention_reference(q, k, v, causal: bool = False,
     scores = jnp.where(mask[None, None], scores, -jnp.inf)
   weights = jax.nn.softmax(scores, axis=-1)
   out = jnp.einsum("bhqk,bkhd->bqhd", weights, v.astype(jnp.float32))
-  return out.astype(q.dtype)
+  return out.astype(v.dtype)
 
 
 def _causal_mask(s, qi, kj, block_q: int, block_k: int):
@@ -73,7 +83,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
   Also emits the per-row logsumexp (the flash-backward residual)."""
   q = q_ref[0].astype(jnp.float32) * scale                 # (BQ, D)
   qi = pl.program_id(1)
-  head_dim = q.shape[-1]
+  head_dim = v_ref.shape[-1]
 
   def body(kj, carry):
     m, l, acc = carry
@@ -122,18 +132,34 @@ def _block_sizes(t: int):
   return None
 
 
-def _supported(q, k) -> Optional[str]:
+def _supported(q, k, v) -> Optional[str]:
   """None if the Pallas path can run, else the reason it cannot."""
-  t, d = q.shape[1], q.shape[3]
+  t = q.shape[1]
   if _block_sizes(t) is None:
     return (f"T must be divisible by {_BLOCK} or <= "
             f"{_MAX_SINGLE_BLOCK_T}; got T={t}")
-  kv_bytes = _PIPELINE_BUFFERS * 2 * t * d * k.dtype.itemsize
+  # K and V each at its own head width (MLA: 192 and 128).
+  kv_bytes = _PIPELINE_BUFFERS * t * (
+      k.shape[3] * k.dtype.itemsize + v.shape[3] * v.dtype.itemsize)
   if kv_bytes > _MAX_KV_VMEM_BYTES:
-    return (f"double-buffered K+V row ({kv_bytes} bytes at T={t}, D={d})"
-            f" exceeds the {_MAX_KV_VMEM_BYTES}-byte VMEM budget; use "
+    return (f"double-buffered K+V row ({kv_bytes} bytes at T={t}, "
+            f"D={k.shape[3]}/{v.shape[3]}) exceeds the "
+            f"{_MAX_KV_VMEM_BYTES}-byte VMEM budget; use "
             "ring_attention for sequences this long")
   return None
+
+
+def _compiler_params(*blocks):
+  """Scoped-VMEM limit for a call that stages `blocks` ((rows, width,
+  dtype) each, double-buffered): a (rows, 1) float32 column takes a
+  whole 128-lane tile per 8 rows, so the backward's full-row lse and
+  delta outgrow Mosaic's 16 MiB default from T = 4096 on."""
+  staged = 0
+  for rows, width, dtype in blocks:
+    lanes = -(-width // 128) * 128
+    staged += _PIPELINE_BUFFERS * rows * lanes * jnp.dtype(dtype).itemsize
+  return pltpu.CompilerParams(vmem_limit_bytes=max(
+      _MIN_VMEM_LIMIT_BYTES, staged + _VMEM_WORKING_BYTES))
 
 
 def _to_rows(x):
@@ -149,6 +175,7 @@ def _from_rows(x, b, h):
 def _pallas_forward(q, k, v, causal: bool, scale: float,
                     with_residuals: bool = False):
   b, t, h, d = q.shape
+  dv = v.shape[3]
   block_q, block_k = _block_sizes(t)
   # (B, T, H, D) → (B·H, T, D): heads become independent grid rows.
   qr, kr, vr = _to_rows(q), _to_rows(k), _to_rows(v)
@@ -158,19 +185,23 @@ def _pallas_forward(q, k, v, causal: bool, scale: float,
   out, lse = pl.pallas_call(
       functools.partial(_kernel, scale=scale, causal=causal,
                         block_q=block_q, block_k=block_k, seq_len=t),
-      out_shape=(jax.ShapeDtypeStruct((b * h, t, d), q.dtype),
+      out_shape=(jax.ShapeDtypeStruct((b * h, t, dv), v.dtype),
                  jax.ShapeDtypeStruct((b * h, t, 1), jnp.float32)),
       grid=grid,
       in_specs=[
           pl.BlockSpec((1, block_q, d), tile, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, t, d), full, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, t, d), full, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, t, dv), full, memory_space=pltpu.VMEM),
       ],
       out_specs=(
-          pl.BlockSpec((1, block_q, d), tile, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, block_q, dv), tile, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, block_q, 1), tile, memory_space=pltpu.VMEM),
       ),
+      compiler_params=_compiler_params(
+          (block_q, d, q.dtype), (t, d, k.dtype), (t, dv, v.dtype),
+          (block_q, dv, v.dtype), (block_q, 1, jnp.float32)),
       interpret=jax.default_backend() != "tpu",
+      name=KERNEL_NAMES[0],
   )(qr, kr, vr)
   out4 = _from_rows(out, b, h)
   if with_residuals:
@@ -221,7 +252,6 @@ def _kernel_dkv(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
   k_tile = k_ref[0].astype(jnp.float32)                    # (BK, D)
   v_tile = v_ref[0].astype(jnp.float32)                    # (BK, D)
   kj = pl.program_id(1)
-  head_dim = k_tile.shape[-1]
 
   def body(qi, carry):
     dk_acc, dv_acc = carry
@@ -251,8 +281,8 @@ def _kernel_dkv(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
   num_q = seq_len // block_q
   # Causal: only Q tiles whose last row reaches this K tile contribute.
   start = (kj * block_k) // block_q if causal else 0
-  init = (jnp.zeros((block_k, head_dim), jnp.float32),
-          jnp.zeros((block_k, head_dim), jnp.float32))
+  init = (jnp.zeros(k_tile.shape, jnp.float32),
+          jnp.zeros(v_tile.shape, jnp.float32))
   dk, dv = jax.lax.fori_loop(start, num_q, body, init)
   dk_ref[0] = dk.astype(dk_ref.dtype)
   dv_ref[0] = dv.astype(dv_ref.dtype)
@@ -268,6 +298,7 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
   activation, so saving it as a residual costs no extra memory.
   """
   b, t, h, d = q.shape
+  dv = v.shape[3]
   block_q, block_k = _block_sizes(t)
   qr, kr, vr, dor = _to_rows(q), _to_rows(k), _to_rows(v), _to_rows(do)
   # Δ_i = Σ_d dO_id · O_id — cheap elementwise reduction, XLA fuses it.
@@ -288,33 +319,43 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
       in_specs=[
           pl.BlockSpec((1, block_q, d), tile_q, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, t, d), full, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, t, d), full, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, block_q, d), tile_q, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, t, dv), full, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, block_q, dv), tile_q, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, block_q, 1), tile_q, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, block_q, 1), tile_q, memory_space=pltpu.VMEM),
       ],
       out_specs=pl.BlockSpec((1, block_q, d), tile_q,
                              memory_space=pltpu.VMEM),
+      compiler_params=_compiler_params(
+          (block_q, d, q.dtype), (t, d, k.dtype), (t, dv, v.dtype),
+          (block_q, dv, do.dtype), (block_q, 1, jnp.float32),
+          (block_q, 1, jnp.float32), (block_q, d, q.dtype)),
       interpret=interpret,
+      name=KERNEL_NAMES[1],
   )(qr, kr, vr, dor, lse, delta)
   dk, dv = pl.pallas_call(
       functools.partial(_kernel_dkv, **kwargs),
       out_shape=(jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-                 jax.ShapeDtypeStruct((b * h, t, d), v.dtype)),
+                 jax.ShapeDtypeStruct((b * h, t, dv), v.dtype)),
       grid=(b * h, t // block_k),
       in_specs=[
           pl.BlockSpec((1, t, d), full, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, block_k, d), tile_k, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, block_k, d), tile_k, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, t, d), full, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, block_k, dv), tile_k, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, t, dv), full, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, t, 1), full, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, t, 1), full, memory_space=pltpu.VMEM),
       ],
       out_specs=(
           pl.BlockSpec((1, block_k, d), tile_k, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, block_k, d), tile_k, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, block_k, dv), tile_k, memory_space=pltpu.VMEM),
       ),
+      compiler_params=_compiler_params(
+          (t, d, q.dtype), (block_k, d, k.dtype), (block_k, dv, v.dtype),
+          (t, dv, do.dtype), (t, 1, jnp.float32), (t, 1, jnp.float32),
+          (block_k, d, k.dtype), (block_k, dv, v.dtype)),
       interpret=interpret,
+      name=KERNEL_NAMES[2],
   )(qr, kr, vr, dor, lse, delta)
   return (_from_rows(dq, b, h), _from_rows(dk, b, h),
           _from_rows(dv, b, h))
@@ -344,14 +385,16 @@ def flash_attention(q, k, v, causal: bool = False,
   """Multi-head attention over (B, T, H, D) without the (T, T) tensor.
 
   Args:
-    q, k, v: (B, T, H, D) arrays (same layout as ring_attention).
+    q, k, v: (B, T, H, D) arrays (same layout as ring_attention); q and
+      k share one head width, v may have its own (MLA: 192 and 128),
+      which is then the output's.
     causal: apply a causal mask.
     scale: attention scale; default 1/sqrt(D).
     implementation: "pallas", "xla", or "auto" (pallas when T is
       blockable: divisible by 128 or ≤ 1024 as one block).
 
   Returns:
-    (B, T, H, D) attention output in q's dtype.
+    (B, T, H, Dv) attention output in v's dtype.
   """
   if implementation not in ("auto", "pallas", "xla"):
     raise ValueError(
@@ -359,7 +402,7 @@ def flash_attention(q, k, v, causal: bool = False,
         f"{implementation!r}")
   if scale is None:
     scale = 1.0 / math.sqrt(q.shape[-1])
-  unsupported = _supported(q, k)
+  unsupported = _supported(q, k, v)
   if implementation == "xla" or (implementation == "auto"
                                  and (unsupported is not None
                                       or dispatch.use_xla_only()

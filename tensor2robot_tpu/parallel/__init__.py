@@ -29,7 +29,8 @@ from tensor2robot_tpu.parallel.expert_parallel import (
     MoEParams,
     expert_parallel_moe,
     init_moe_params,
-    switch_moe,
+    moe_share,
+    route,
 )
 from tensor2robot_tpu.parallel.tp_rules import (
     infer_dense_tp_specs,
@@ -53,7 +54,8 @@ __all__ = [
     "MoEParams",
     "expert_parallel_moe",
     "init_moe_params",
-    "switch_moe",
+    "moe_share",
+    "route",
     "infer_dense_tp_specs",
     "infer_dense_tp_specs_from_model",
     "infer_fsdp_specs",

@@ -1,24 +1,34 @@
-"""Expert parallelism: Switch-style mixture-of-experts with all-to-all
-token dispatch over an `expert` mesh axis.
+"""Expert parallelism: one drop-free mixture-of-experts layer that is
+told which experts it holds.
 
-Beyond the reference (pure data parallelism — SURVEY.md §2 "Parallelism
-strategies"): the fifth axis of the dp/tp/sp/pp/ep family. Experts are
-feed-forward blocks whose weights are sharded one-group-per-device over
-the `expert` mesh axis; tokens are routed top-1 (Switch) with a capacity
-limit, exchanged device↔expert with a pair of `all_to_all`s (the
-canonical MoE mesh transpose: (E, C, D) split over E in, concat over C),
-processed by the local expert group, and combined back gate-weighted.
+Every holder routes over ALL the layer's experts (sigmoid scores, the
+top k of score + correction bias, weights the scores of the chosen k
+over their sum, DeepSeek-V3's `noaux_tc`), and computes the part of the
+result its own experts give: tokens are sorted by expert, the held
+experts' gated MLPs run as grouped matrix products over the sorted rows
+(`jax.lax.ragged_dot`: on TPU a grouped kernel that visits only the
+tiles the groups fill), and the rows go back weighted. No capacity, so
+no assignment is ever dropped, whatever the imbalance: the buffers are
+sized for the worst case and the products for what arrived.
 
-The dense path (`switch_moe`) is the single-device reference — identical
-math, no collectives — used for tests and small models; both paths are
-differentiable and share the routing implementation, so they cannot
-drift.
+Two entry points over the same routing and grouping:
+
+  `moe_share`            one holder, no exchange: the sum over its held
+                         experts only. What the absent experts would add
+                         is left out (a model cut to one chip's share of
+                         a wider expert group trains on this).
+  `expert_parallel_moe`  experts sharded over an `expert` mesh axis,
+                         tokens sharded over the same axis, a pair of
+                         `all_to_all`s carrying each row to its
+                         expert's holder and back: the whole layer.
+
+Summed over all shares, `moe_share` equals `expert_parallel_moe`.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -27,125 +37,188 @@ from jax.sharding import Mesh, PartitionSpec
 
 
 class MoEParams(NamedTuple):
-  """Router + stacked expert FFN weights.
+  """Router over all E experts + the H held experts' stacked gated MLPs.
 
-  router: (D, E). w1/b1: (E, D, H). w2/b2: (E, H, D) — leading expert
-  axis is what the `expert` mesh axis shards.
+  router: (D, E). bias: (E,), the score-correction bias: it moves the
+  choice and not the weights, and the loss gives it no gradient.
+  gate/up: (H, D, F). down: (H, F, D): the leading axis is what the
+  `expert` mesh axis shards (then H = E).
   """
   router: jnp.ndarray
-  w1: jnp.ndarray
-  b1: jnp.ndarray
-  w2: jnp.ndarray
-  b2: jnp.ndarray
+  bias: jnp.ndarray
+  gate: jnp.ndarray
+  up: jnp.ndarray
+  down: jnp.ndarray
 
 
 def init_moe_params(rng: jax.Array, num_experts: int, d_model: int,
-                    d_hidden: int, dtype=jnp.float32) -> MoEParams:
-  k1, k2, k3 = jax.random.split(rng, 3)
-  scale1 = 1.0 / jnp.sqrt(d_model).astype(dtype)
-  scale2 = 1.0 / jnp.sqrt(d_hidden).astype(dtype)
+                    d_hidden: int, experts_held: Optional[int] = None,
+                    dtype=jnp.float32) -> MoEParams:
+  held = num_experts if experts_held is None else experts_held
+  k1, k2, k3, k4, k5 = jax.random.split(rng, 5)
+  normal = lambda k, shape, fan_in: (
+      jax.random.normal(k, shape, dtype) / jnp.sqrt(fan_in).astype(dtype))
   return MoEParams(
-      router=jax.random.normal(k1, (d_model, num_experts), dtype) * scale1,
-      w1=jax.random.normal(k2, (num_experts, d_model, d_hidden),
-                           dtype) * scale1,
-      b1=jnp.zeros((num_experts, d_hidden), dtype),
-      w2=jax.random.normal(k3, (num_experts, d_hidden, d_model),
-                           dtype) * scale2,
-      b2=jnp.zeros((num_experts, d_model), dtype),
+      router=normal(k1, (d_model, num_experts), d_model),
+      bias=0.1 * jax.random.normal(k2, (num_experts,), dtype),
+      gate=normal(k3, (held, d_model, d_hidden), d_model),
+      up=normal(k4, (held, d_model, d_hidden), d_model),
+      down=normal(k5, (held, d_hidden, d_model), d_hidden),
   )
 
 
-class _Routing(NamedTuple):
-  combine: jnp.ndarray    # (N, E, C) — one-hot dispatch/combine tensor
-  gate: jnp.ndarray       # (N,) — top-1 router probability
-  fraction: jnp.ndarray   # (E,) — fraction of tokens routed per expert
-  mean_prob: jnp.ndarray  # (E,) — mean router probability per expert
+def route(tokens: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray,
+          top_k: int, scale: float = 1.0
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+  """(N, D) tokens → ((N, k) expert ids, (N, k) float32 weights).
+
+  Float32 throughout, the product at full precision: a choice that
+  flipped on rounding would move a token's whole result."""
+  scores = jax.nn.sigmoid(jnp.dot(
+      tokens.astype(jnp.float32), router.astype(jnp.float32),
+      precision=jax.lax.Precision.HIGHEST))                    # (N, E)
+  _, index = jax.lax.top_k(
+      scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+  chosen = jnp.take_along_axis(scores, index, axis=-1)
+  weight = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+  return index, weight * scale
 
 
-def _route(tokens: jnp.ndarray, router: jnp.ndarray,
-           capacity: int) -> _Routing:
-  """Top-1 routing with per-expert capacity; overflow tokens drop (the
-  residual connection around the MoE block carries them unchanged)."""
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _take_rows(x, order, inverse, repeats):
+  """x[order // repeats]: row i of x `repeats` times, then permuted by
+  `order`. Backward is a gather by `inverse` and a sum, not a scatter."""
+  del inverse
+  return x[order // repeats]
+
+
+def _take_rows_fwd(x, order, inverse, repeats):
+  return x[order // repeats], (inverse, x.shape[0])
+
+
+def _take_rows_bwd(repeats, residuals, g):
+  inverse, n = residuals
+  return g[inverse].reshape(n, repeats, -1).sum(axis=1), None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _sort_by_expert(expert_of_row: jnp.ndarray, num_held: int):
+  """Order that groups rows by held expert (rows of no held expert,
+  marked `num_held`, last), its inverse and the groups' sizes."""
+  order = jnp.argsort(expert_of_row, stable=True)
+  inverse = jnp.argsort(order)
+  sizes = jnp.bincount(expert_of_row, length=num_held + 1)[:num_held]
+  return order, inverse, sizes.astype(jnp.int32)
+
+
+def _grouped_mlp(rows: jnp.ndarray, sizes: jnp.ndarray, params: MoEParams,
+                 compute_dtype) -> jnp.ndarray:
+  """Gated MLP of expert g over its `sizes[g]` consecutive rows; rows
+  past the last group come out zero."""
+  # Every product leaves in the compute dtype, as a dense layer's does
+  # (float32 accumulation inside): the rows are N·k by D, and a float32
+  # product would hand float32 cotangents of that size back.
+  dot = functools.partial(jax.lax.ragged_dot, group_sizes=sizes,
+                          preferred_element_type=compute_dtype)
+  cast = lambda w: w.astype(compute_dtype)
+  # The grouped kernel leaves the rows past the last group unwritten
+  # (on TPU: whatever the buffer held), forward and backward alike. The
+  # mask on the way out zeroes the result there; the same mask on the
+  # way in is a no-op forward and zeroes those rows' cotangent, which
+  # would otherwise be summed into their tokens' gradients.
+  in_a_group = (jnp.arange(rows.shape[0]) < jnp.sum(sizes))[:, None]
+  mask = lambda x: jnp.where(in_a_group, x, jnp.zeros((), x.dtype))
+  rows = mask(rows.astype(compute_dtype))
+  with jax.named_scope("moe/experts"):
+    gate = dot(rows, cast(params.gate)).astype(jnp.float32)
+    up = dot(rows, cast(params.up)).astype(jnp.float32)
+    out = dot((jax.nn.silu(gate) * up).astype(compute_dtype),
+              cast(params.down))
+    return mask(out)
+
+
+def _counters(sizes, held, top_k: int) -> Dict[str, jnp.ndarray]:
+  return {
+      "expert_tokens": sizes,
+      "held_assignments": jnp.sum(held.astype(jnp.int32)),
+      "total_assignments": jnp.asarray(held.shape[0] * top_k, jnp.int32),
+  }
+
+
+def moe_share(tokens: jnp.ndarray, params: MoEParams, first_expert: int,
+              top_k: int, scale: float = 1.0, compute_dtype=None):
+  """One holder's part of the layer: Σ over top-k ∩ held of w_i E_i(x).
+
+  Args:
+    tokens: (N, D).
+    params: router over all E experts, H held experts' weights; the
+      held experts are `first_expert .. first_expert + H - 1`.
+    top_k, scale: experts per token; factor on the normalized weights.
+    compute_dtype: dtype of the expert products' operands (float32
+      accumulation); default the tokens'.
+
+  Returns:
+    ((N, D) in the tokens' dtype, counters: `expert_tokens` (H,) rows
+    each held expert saw, `held_assignments`, `total_assignments`).
+  """
   n, _ = tokens.shape
-  num_experts = router.shape[-1]
-  logits = tokens.astype(jnp.float32) @ router.astype(jnp.float32)
-  probs = jax.nn.softmax(logits, axis=-1)                  # (N, E)
-  expert_index = jnp.argmax(probs, axis=-1)                # (N,)
-  gate = jnp.take_along_axis(probs, expert_index[:, None], axis=-1)[:, 0]
-  onehot = jax.nn.one_hot(expert_index, num_experts,
-                          dtype=jnp.float32)               # (N, E)
-  # Position of each token within its expert's queue (first-come).
-  position = jnp.cumsum(onehot, axis=0) * onehot           # 1-based
-  keep = (position > 0) & (position <= capacity)
-  pos_onehot = jax.nn.one_hot(
-      ((position - 1.0) * onehot).astype(jnp.int32), capacity,
-      dtype=jnp.float32)
-  combine = jnp.where(keep[..., None], onehot[..., None] * pos_onehot,
-                      0.0)                                 # (N, E, C)
-  return _Routing(combine=combine, gate=gate,
-                  fraction=jnp.mean(onehot, axis=0),
-                  mean_prob=jnp.mean(probs, axis=0))
+  num_held = params.gate.shape[0]
+  compute_dtype = compute_dtype or tokens.dtype
+  with jax.named_scope("moe/route"):
+    index, weight = route(tokens, params.router, params.bias, top_k, scale)
+    local = index - first_expert
+    held = (local >= 0) & (local < num_held)                   # (N, k)
+  with jax.named_scope("moe/dispatch"):
+    order, inverse, sizes = _sort_by_expert(
+        jnp.where(held, local, num_held).reshape(-1), num_held)
+    rows = _take_rows(tokens, order, inverse, top_k)           # (N·k, D)
+  out = _grouped_mlp(rows, sizes, params, compute_dtype)
+  with jax.named_scope("moe/combine"):
+    out = _take_rows(out, inverse, order, 1).reshape(n, top_k, -1)
+    y = jnp.einsum("nkd,nk->nd", out.astype(jnp.float32),
+                   jnp.where(held, weight, 0.0))
+  return y.astype(tokens.dtype), _counters(sizes, held, top_k)
 
 
-def _aux_loss(fraction: jnp.ndarray, mean_prob: jnp.ndarray) -> jnp.ndarray:
-  """Switch aux loss: E · Σ_e fraction_tokens_e · mean_router_prob_e."""
-  return fraction.shape[-1] * jnp.sum(fraction * mean_prob)
-
-
-def _expert_ffn(buf: jnp.ndarray, params: MoEParams) -> jnp.ndarray:
-  """Applies expert e's FFN to buffer row e: (E, C, D) → (E, C, D)."""
-  h = jax.nn.relu(
-      jnp.einsum("ecd,edh->ech", buf, params.w1.astype(buf.dtype))
-      + params.b1[:, None].astype(buf.dtype))
-  return (jnp.einsum("ech,ehd->ecd", h, params.w2.astype(buf.dtype))
-          + params.b2[:, None].astype(buf.dtype))
-
-
-def default_capacity(num_tokens: int, num_experts: int,
-                     capacity_factor: float = 1.25) -> int:
-  return max(1, int(num_tokens * capacity_factor / num_experts))
-
-
-def switch_moe(tokens: jnp.ndarray, params: MoEParams,
-               capacity: Optional[int] = None,
-               capacity_factor: float = 1.25):
-  """Dense single-device Switch MoE: (N, D) tokens → ((N, D), aux_loss)."""
-  n, d = tokens.shape
-  num_experts = params.router.shape[-1]
-  if capacity is None:
-    capacity = default_capacity(n, num_experts, capacity_factor)
-  routing = _route(tokens, params.router, capacity)
-  f32 = tokens.astype(jnp.float32)
-  buf = jnp.einsum("nec,nd->ecd", routing.combine, f32)    # (E, C, D)
-  out = _expert_ffn(buf, params)
-  y = jnp.einsum("nec,ecd->nd", routing.combine, out)
-  y = y * routing.gate[:, None]
-  return (y.astype(tokens.dtype),
-          _aux_loss(routing.fraction, routing.mean_prob))
-
-
-def _ep_local(tokens, params: MoEParams, *, axis_name: str, capacity: int):
+def _ep_local(tokens, params: MoEParams, *, axis_name: str, top_k: int,
+              scale: float, num_devices: int):
   """Per-device body: tokens (N_local, D); expert weights (E/P, ...)."""
-  routing = _route(tokens, params.router, capacity)
-  f32 = tokens.astype(jnp.float32)
-  buf = jnp.einsum("nec,nd->ecd", routing.combine, f32)    # (E, C, D)
-  # Mesh transpose: every device sends expert-shard e its (C, D) queue →
-  # local buffer (E/P, P·C, D) holding ALL devices' tokens for the
-  # local expert group.
-  buf = jax.lax.all_to_all(buf, axis_name, split_axis=0, concat_axis=1,
-                           tiled=True)
-  out = _expert_ffn(buf, params)
-  # Inverse transpose: results return to their source device.
-  out = jax.lax.all_to_all(out, axis_name, split_axis=1, concat_axis=0,
-                           tiled=True)                     # (E, C, D)
-  y = jnp.einsum("nec,ecd->nd", routing.combine, out)
-  y = y * routing.gate[:, None]
-  # Global aux statistics FIRST (token shards are equal-size, so pmean of
-  # per-shard means is the global mean), then the nonlinear product —
-  # this keeps the EP aux loss bit-identical to the dense path's.
-  fraction = jax.lax.pmean(routing.fraction, axis_name)
-  mean_prob = jax.lax.pmean(routing.mean_prob, axis_name)
-  return y.astype(tokens.dtype), _aux_loss(fraction, mean_prob)
+  n, d = tokens.shape
+  num_held = params.gate.shape[0]
+  index, weight = route(tokens, params.router, params.bias, top_k, scale)
+  holder = (index // num_held).reshape(-1)                    # (N·k,)
+  local = (index % num_held).reshape(-1)
+  # One queue per holder, long enough for every row this device could
+  # send it: nothing is dropped.
+  queue = n * min(top_k, num_held)
+  order = jnp.argsort(holder, stable=True)
+  inverse = jnp.argsort(order)
+  per_holder = jnp.bincount(holder, length=num_devices)
+  to = holder[order]
+  slot = jnp.arange(n * top_k) - (jnp.cumsum(per_holder) - per_holder)[to]
+  send = jnp.zeros((num_devices, queue, d), tokens.dtype).at[to, slot].set(
+      tokens[order // top_k])
+  send_expert = jnp.full((num_devices, queue), num_held, jnp.int32).at[
+      to, slot].set(local[order])
+  # Mesh transpose: queue p goes to holder p, which receives one queue
+  # from every device.
+  exchange = functools.partial(jax.lax.all_to_all, axis_name=axis_name,
+                               split_axis=0, concat_axis=0)
+  got = exchange(send).reshape(num_devices * queue, d)
+  got_expert = exchange(send_expert).reshape(-1)
+  by_expert, back, sizes = _sort_by_expert(got_expert, num_held)
+  out = _grouped_mlp(got[by_expert], sizes, params, tokens.dtype)[back]
+  # Inverse transpose: results return to the rows' source device.
+  out = exchange(out.reshape(num_devices, queue, d))
+  out = out[to, slot][inverse].reshape(n, top_k, d)
+  y = jnp.einsum("nkd,nk->nd", out.astype(jnp.float32), weight)
+  # Every assignment has its holder on the mesh: all are held.
+  total = jax.lax.psum(jnp.asarray(n * top_k, jnp.int32), axis_name)
+  counters = {"expert_tokens": sizes, "held_assignments": total,
+              "total_assignments": total}
+  return y.astype(tokens.dtype), counters
 
 
 def expert_parallel_moe(
@@ -153,25 +226,24 @@ def expert_parallel_moe(
     params: MoEParams,
     mesh: Mesh,
     axis: str = "expert",
-    capacity: Optional[int] = None,
-    capacity_factor: float = 1.25,
+    top_k: int = 1,
+    scale: float = 1.0,
 ):
-  """Switch MoE with experts sharded over the `axis` mesh axis.
+  """The whole layer with its experts sharded over the `axis` mesh axis.
 
   Args:
     tokens: (N, D); N must divide evenly over the axis (tokens are
-      data-sharded over the same axis the experts live on — each device
+      data-sharded over the same axis the experts live on: each device
       routes its token shard to all expert shards via all_to_all).
-    params: MoEParams; the leading expert axis (size E) must divide
-      evenly over the axis and is sharded one-group-per-device.
+    params: MoEParams holding ALL E experts; the leading expert axis
+      must divide evenly over the axis and is sharded one group per
+      device.
     mesh: device mesh containing `axis`.
-    capacity: per-expert, per-source-device token queue length; default
-      `default_capacity(N/P, E, capacity_factor)`.
+    top_k, scale: as `moe_share`.
 
   Returns:
-    ((N, D) output, scalar load-balancing aux loss) — numerically equal
-    to `switch_moe` with capacity=P·(per-device capacity) modulo
-    first-come ordering of the token shards.
+    ((N, D) output, counters as `moe_share`'s, `expert_tokens` (E,)
+    over all experts): equal to `moe_share` summed over the shares.
   """
   num_devices = mesh.shape[axis]
   n, _ = tokens.shape
@@ -179,22 +251,24 @@ def expert_parallel_moe(
   if n % num_devices != 0:
     raise ValueError(f"Token count {n} not divisible by {axis!r} axis "
                      f"size {num_devices}.")
-  if num_experts % num_devices != 0:
-    raise ValueError(f"Expert count {num_experts} not divisible by "
-                     f"{axis!r} axis size {num_devices}.")
-  if capacity is None:
-    capacity = default_capacity(n // num_devices, num_experts,
-                                capacity_factor)
+  if (num_experts % num_devices != 0
+      or params.gate.shape[0] != num_experts):
+    raise ValueError(f"Expert count {params.gate.shape[0]} of "
+                     f"{num_experts} routed not divisible by {axis!r} "
+                     f"axis size {num_devices}.")
   token_spec = PartitionSpec(axis)
   param_specs = MoEParams(
-      router=PartitionSpec(),           # replicated — every device routes
-      w1=PartitionSpec(axis), b1=PartitionSpec(axis),
-      w2=PartitionSpec(axis), b2=PartitionSpec(axis),
-  )
+      router=PartitionSpec(), bias=PartitionSpec(),  # every device routes
+      gate=PartitionSpec(axis), up=PartitionSpec(axis),
+      down=PartitionSpec(axis))
+  counter_specs = {"expert_tokens": PartitionSpec(axis),
+                   "held_assignments": PartitionSpec(),
+                   "total_assignments": PartitionSpec()}
   fn = shard_map(
-      functools.partial(_ep_local, axis_name=axis, capacity=capacity),
+      functools.partial(_ep_local, axis_name=axis, top_k=top_k,
+                        scale=scale, num_devices=num_devices),
       mesh=mesh,
       in_specs=(token_spec, param_specs),
-      out_specs=(token_spec, PartitionSpec()),
+      out_specs=(token_spec, counter_specs),
   )
   return fn(tokens, params)

@@ -370,7 +370,10 @@ def train_eval_model(
 
         if crossed(log_every_steps, prev_step, step) or step == max_train_steps:
           with trace_lib.span("train/readback", step=step):
-            host_metrics = {k: float(v) for k, v in pending_metrics.items()}
+            # Scalars only: a model may also report arrays (an expert
+            # layer's per-expert counts), which no scalar writer takes.
+            host_metrics = {k: float(v) for k, v in pending_metrics.items()
+                            if np.ndim(v) == 0}
           train_metrics = host_metrics
           if metric_writer:
             _emit_metrics(metric_writer, step, host_metrics)
@@ -494,7 +497,8 @@ def _evaluate(trainer, model, input_generator_eval, state,
     features, labels = batch
     metrics = trainer.eval_step(state, features, labels)
     for key, value in metrics.items():
-      sums[key] = sums.get(key, 0.0) + float(value)
+      if np.ndim(value) == 0:
+        sums[key] = sums.get(key, 0.0) + float(value)
     count += 1
     last_features = features
   metrics = {key: value / max(count, 1) for key, value in sums.items()}

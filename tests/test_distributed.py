@@ -338,8 +338,8 @@ distributed.sync_global_devices("cross_process_sp_done")
 # Replicated operands must still be GLOBAL arrays in multi-process JAX —
 # each host contributes the identical full value.
 from tensor2robot_tpu.parallel import (expert_parallel_moe,
-                                       init_moe_params, pipeline_apply,
-                                       stack_stage_params, switch_moe)
+                                       init_moe_params, moe_share,
+                                       pipeline_apply, stack_stage_params)
 
 
 def replicate(mesh, tree):
@@ -353,15 +353,15 @@ ep_mesh = mesh_lib.create_mesh({"expert": -1})  # 4 experts over 2 procs
 moe_params_host = jax.device_get(init_moe_params(
     jax.random.key(0), num_experts=4, d_model=8, d_hidden=16))
 tokens_host = np.asarray(sp_rng.standard_normal((16, 8)), np.float32)
-out_dense, _ = switch_moe(jnp.asarray(tokens_host),
-                          jax.tree_util.tree_map(jnp.asarray,
-                                                 moe_params_host),
-                          capacity=16)
+out_dense, _ = moe_share(jnp.asarray(tokens_host),
+                         jax.tree_util.tree_map(jnp.asarray,
+                                                moe_params_host),
+                         first_expert=0, top_k=2)
 out_dense = np.asarray(out_dense)
 tokens_g = replicate(ep_mesh, tokens_host)
 params_g = replicate(ep_mesh, moe_params_host)
 out_ep, _ = jax.jit(
-    lambda t, p: expert_parallel_moe(t, p, ep_mesh, capacity=16)
+    lambda t, p: expert_parallel_moe(t, p, ep_mesh, top_k=2)
 )(tokens_g, params_g)
 for shard in out_ep.addressable_shards:
   err = float(np.max(np.abs(np.asarray(shard.data)

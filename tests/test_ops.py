@@ -125,6 +125,37 @@ class TestFlashAttention:
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=2e-5)
 
+  @pytest.mark.parametrize("t,d,dv,causal", [
+      (256, 192, 128, True),    # MLA's widths, two blocks
+      (256, 24, 16, False),
+      (40, 192, 128, True),     # one block
+  ])
+  def test_v_narrower_than_qk(self, t, d, dv, causal):
+    q, k, _ = self._qkv(b=1, t=t, h=2, d=d, seed=4)
+    v = self._qkv(b=1, t=t, h=2, d=dv, seed=5)[2]
+    got = flash_attention(q, k, v, causal=causal,
+                          implementation="pallas")
+    want = flash_attention_reference(q, k, v, causal=causal)
+    assert got.shape == (1, t, 2, dv)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5)
+
+  @pytest.mark.parametrize("t,d,dv", [(256, 192, 128), (128, 24, 16)])
+  def test_gradients_with_v_narrower_than_qk(self, t, d, dv):
+    q, k, _ = self._qkv(b=1, t=t, h=1, d=d, seed=6)
+    v = self._qkv(b=1, t=t, h=1, d=dv, seed=7)[2]
+    weight = jnp.asarray(np.random.default_rng(8).standard_normal(
+        (1, t, 1, dv)), jnp.float32)
+    loss = lambda fn, **kw: (lambda q, k, v: jnp.sum(
+        fn(q, k, v, causal=True, **kw) * weight))
+    got = jax.grad(loss(flash_attention, implementation="pallas"),
+                   argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(flash_attention_reference),
+                    argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+      assert g.shape == w.shape
+      np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-5)
+
   def test_auto_falls_back_on_odd_t(self):
     q, k, v = self._qkv(t=1030, b=1, h=1, d=8, seed=2)
     got = flash_attention(q, k, v)  # auto → XLA fallback, no error
@@ -221,9 +252,18 @@ class TestDispatch:
     t = 1 << 16
     big = jnp.zeros((1, t, 1, 64), jnp.bfloat16)
     from tensor2robot_tpu.ops.flash_attention import _supported
-    assert _supported(big, big) is not None  # exceeds VMEM budget
+    assert _supported(big, big, big) is not None  # exceeds VMEM budget
     with pytest.raises(ValueError, match="VMEM"):
       flash_attention(big, big, big, implementation="pallas")
+
+  def test_flash_attention_vmem_guard_counts_k_and_v_at_own_widths(self):
+    # T = 10240: K at 192 and V at 128 are 13.1 MB double-buffered,
+    # inside the 14 MB guard; both counted at q's 192 would be 15.7 MB.
+    from tensor2robot_tpu.ops.flash_attention import _supported
+    wide = jax.ShapeDtypeStruct((1, 10240, 1, 192), jnp.bfloat16)
+    narrow = jax.ShapeDtypeStruct((1, 10240, 1, 128), jnp.bfloat16)
+    assert _supported(wide, wide, narrow) is None
+    assert "VMEM" in _supported(wide, wide, wide)
 
 
 class TestFoldedS2dStem:

@@ -21,7 +21,8 @@ from tensor2robot_tpu.parallel import (
     pipeline_apply,
     ring_attention,
     stack_stage_params,
-    switch_moe,
+    moe_share,
+    route,
     ulysses_attention,
 )
 from tensor2robot_tpu.train.trainer import Trainer
@@ -255,6 +256,10 @@ class TestPipeline:
 
 
 class TestExpertParallel:
+  """One drop-free expert layer that is told its share (`moe_share`),
+  and the same routing and grouping behind the `all_to_all` path."""
+
+  TOP_K, SCALE = 2, 2.5
 
   def _setup(self, n=32, d=8, h=16, e=8, seed=0):
     params = init_moe_params(jax.random.key(seed), num_experts=e,
@@ -263,56 +268,104 @@ class TestExpertParallel:
     tokens = jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
     return tokens, params
 
-  def test_dense_matches_per_token_computation(self):
+  def _share(self, params, first, held):
+    return params._replace(gate=params.gate[first:first + held],
+                           up=params.up[first:first + held],
+                           down=params.down[first:first + held])
+
+  def test_whole_layer_matches_per_token_computation(self):
     tokens, params = self._setup()
-    out, aux = switch_moe(tokens, params, capacity=tokens.shape[0])
-    logits = tokens @ params.router
-    probs = jax.nn.softmax(logits, axis=-1)
+    out, counters = moe_share(tokens, params, 0, self.TOP_K, self.SCALE)
+    scores = jax.nn.sigmoid(tokens @ params.router)
     for i in range(tokens.shape[0]):
-      e = int(jnp.argmax(probs[i]))
-      h = jax.nn.relu(tokens[i] @ params.w1[e] + params.b1[e])
-      expected = (h @ params.w2[e] + params.b2[e]) * probs[i, e]
+      chosen = np.argsort(-np.asarray(scores[i] + params.bias))[:self.TOP_K]
+      total = sum(float(scores[i, e]) for e in chosen)
+      expected = 0.0
+      for e in chosen:
+        hidden = (jax.nn.silu(tokens[i] @ params.gate[e])
+                  * (tokens[i] @ params.up[e]))
+        expected = expected + (self.SCALE * scores[i, e] / total
+                               * (hidden @ params.down[e]))
       np.testing.assert_allclose(np.asarray(out[i]), np.asarray(expected),
                                  atol=1e-5)
-    assert float(aux) > 0
+    assert int(counters["expert_tokens"].sum()) == 32 * self.TOP_K
+    assert int(counters["held_assignments"]) == 32 * self.TOP_K
 
-  def test_expert_parallel_matches_dense(self):
+  def test_expert_parallel_equals_the_shares_summed(self):
+    # The all_to_all path over 4 virtual devices against the one-device
+    # layer summed over the 4 shares of 2 experts.
     tokens, params = self._setup()
-    n = tokens.shape[0]
-    mesh = create_mesh({"expert": -1})
-    # Ample capacity → no drops → EP must equal the dense path exactly.
-    out_ep, aux_ep = expert_parallel_moe(tokens, params, mesh,
-                                         capacity=n)
-    out_dense, aux_dense = switch_moe(tokens, params, capacity=n)
-    np.testing.assert_allclose(np.asarray(out_ep), np.asarray(out_dense),
+    mesh = create_mesh({"expert": 4}, devices=jax.devices()[:4])
+    out_ep, counters = expert_parallel_moe(
+        tokens, params, mesh, top_k=self.TOP_K, scale=self.SCALE)
+    summed, counts = 0.0, []
+    for first in range(0, 8, 2):
+      y, c = moe_share(tokens, self._share(params, first, 2), first,
+                       self.TOP_K, self.SCALE)
+      summed = summed + y
+      counts.append(np.asarray(c["expert_tokens"]))
+    np.testing.assert_allclose(np.asarray(out_ep), np.asarray(summed),
                                atol=1e-5)
-    # The aux loss must match too (global statistics pmean'd before the
-    # nonlinear fraction·prob product).
-    np.testing.assert_allclose(float(aux_ep), float(aux_dense), rtol=1e-5)
+    np.testing.assert_array_equal(np.asarray(counters["expert_tokens"]),
+                                  np.concatenate(counts))
+    assert (int(counters["expert_tokens"].sum())
+            == int(counters["total_assignments"]) == 32 * self.TOP_K)
 
-  def test_capacity_drops_tokens(self):
+  def test_expert_parallel_matches_whole_layer_on_all_devices(self):
+    tokens, params = self._setup()
+    mesh = create_mesh({"expert": -1})
+    out_ep, _ = expert_parallel_moe(tokens, params, mesh, top_k=self.TOP_K)
+    out_one, _ = moe_share(tokens, params, 0, self.TOP_K)
+    np.testing.assert_allclose(np.asarray(out_ep), np.asarray(out_one),
+                               atol=1e-5)
+
+  def test_nothing_drops_under_imbalance(self):
+    # Every token's first choice is expert 1 (the old layer's capacity
+    # of 1 kept one token of sixteen): all sixteen arrive, none drops,
+    # on one device and across the mesh.
     tokens, params = self._setup(n=16, e=4)
-    # capacity=1: at most one token per expert survives; dropped tokens
-    # produce exactly zero output (the residual path carries them).
-    out, _ = switch_moe(tokens, params, capacity=1)
-    zero_rows = np.sum(~np.any(np.asarray(out) != 0.0, axis=-1))
-    assert zero_rows >= 16 - 4
+    params = params._replace(bias=params.bias.at[1].set(10.0))
+    out, counters = moe_share(tokens, params, 0, self.TOP_K)
+    assert int(counters["expert_tokens"][1]) == 16
+    assert int(counters["expert_tokens"].sum()) == 16 * self.TOP_K
+    assert not np.any(np.all(np.asarray(out) == 0.0, axis=-1))
+    mesh = create_mesh({"expert": 4}, devices=jax.devices()[:4])
+    out_ep, counters_ep = expert_parallel_moe(tokens, params, mesh,
+                                              top_k=self.TOP_K)
+    assert int(counters_ep["expert_tokens"][1]) == 16
+    assert int(counters_ep["expert_tokens"].sum()) == 16 * self.TOP_K
+    np.testing.assert_allclose(np.asarray(out_ep), np.asarray(out),
+                               atol=1e-5)
+
+  def test_a_share_leaves_out_what_absent_experts_would_add(self):
+    tokens, params = self._setup()
+    index, _ = route(tokens, params.router, params.bias, self.TOP_K)
+    out, counters = moe_share(tokens, self._share(params, 4, 2), 4,
+                              self.TOP_K)
+    here = np.any((np.asarray(index) >= 4) & (np.asarray(index) < 6), -1)
+    assert np.all(np.asarray(out)[~here] == 0.0)
+    assert np.all(np.any(np.asarray(out)[here] != 0.0, axis=-1))
+    assert int(counters["held_assignments"]) < int(
+        counters["total_assignments"])
 
   @pytest.mark.slow  # fast-lane budget (VERDICT r3 #8): covered by the full suite; EP forward/dense-equivalence tests stay fast
   def test_gradients_flow_through_ep(self):
     tokens, params = self._setup()
     mesh = create_mesh({"expert": -1})
 
-    def loss(params):
-      out, aux = expert_parallel_moe(tokens, params, mesh,
-                                     capacity=tokens.shape[0])
-      return jnp.sum(out ** 2) + 0.01 * aux
+    def loss(params, fn):
+      return jnp.sum(fn(params)[0] ** 2)
 
-    grads = jax.grad(loss)(params)
-    for leaf in jax.tree_util.tree_leaves(grads):
-      assert np.all(np.isfinite(np.asarray(leaf)))
-    # Router receives gradient through the gate weighting.
+    grads = jax.grad(loss)(params, lambda p: expert_parallel_moe(
+        tokens, p, mesh, top_k=self.TOP_K))
+    want = jax.grad(loss)(params, lambda p: moe_share(
+        tokens, p, 0, self.TOP_K))
+    for got, ref in zip(grads, want):
+      np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                 atol=1e-4)
+    # The router learns through the weights; the bias only chooses.
     assert float(jnp.max(jnp.abs(grads.router))) > 0
+    assert float(jnp.max(jnp.abs(grads.bias))) == 0
 
   def test_indivisible_raises(self):
     tokens, params = self._setup(n=30)

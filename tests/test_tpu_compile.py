@@ -1,0 +1,64 @@
+"""The main path's Pallas kernels compiled for a v5e chip that is
+described, not attached (the TPU's compiler is installed here): what
+interpret mode cannot show — a block Mosaic refuses, more scoped VMEM
+than a kernel may use. Nothing runs; a compile that passes is not a
+chip run. All such compiles live in this one file: the worker that is
+handed it loads the TPU's library, once."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+  os.environ.setdefault("TPU_LOG_DIR", "disabled")
+  from jax.experimental import topologies
+  try:
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+  except Exception as e:  # no compiler for that chip here
+    pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+  return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+  """The kernels ask `jax.default_backend()` whether to interpret; here
+  it says cpu. A compile for the chip cannot be read back from the
+  persistent cache without one, so the cache is off around it."""
+  from jax.experimental.compilation_cache import compilation_cache
+  monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+  before = jax.config.jax_enable_compilation_cache
+  jax.config.update("jax_enable_compilation_cache", False)
+  compilation_cache.reset_cache()
+  yield
+  jax.config.update("jax_enable_compilation_cache", before)
+  compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("qk_width, v_width", [(192, 128), (128, 128)])
+def test_flash_attention_compiles_at_8k_forward_and_backward(
+    one_chip, as_on_tpu, qk_width, v_width):
+  """MLA's widths (q/k 128 + 64 rotary, v 128) and equal widths at
+  T = 8,192, 32 heads, bf16: the dk/dv program stages (T, 1) float32
+  columns that VMEM pads 128x, past Mosaic's 16 MiB default."""
+  from tensor2robot_tpu.ops.flash_attention import flash_attention
+  shape = lambda d: jax.ShapeDtypeStruct((1, 8192, 32, d), jnp.bfloat16,
+                                         sharding=one_chip)
+  q, k, v = shape(qk_width), shape(qk_width), shape(v_width)
+
+  def loss(q, k, v):
+    out = flash_attention(q, k, v, causal=True, implementation="pallas")
+    return jnp.sum(out.astype(jnp.float32))
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+      q, k, v).compile()
+  text = compiled.as_text()
+  for name in ("flash_attention_fwd", "flash_attention_dq",
+               "flash_attention_dkv"):
+    assert name in text
+  assert text.count("tpu_custom_call") >= 3
