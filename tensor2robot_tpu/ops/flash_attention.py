@@ -14,6 +14,12 @@ logsumexp; dq and dk/dv are recomputed blockwise in two passes), so
 training memory stays O(T) end to end. First-order only — custom_vjp
 does not compose with forward-over-reverse, so models differentiated
 twice (MAML inner loops) must pass implementation="xla".
+
+Precision follows the inputs: every product takes q, k, v and dO as
+they are staged, and p and dS cast to that dtype, and sums in float32
+(bf16 inputs: what a dense layer of a bf16 model does; float32 inputs:
+float32 operands at Mosaic's default precision). The scale, max, exp,
+sums, lse, delta and all accumulators are float32 whatever comes in.
 """
 
 from __future__ import annotations
@@ -34,20 +40,28 @@ from tensor2robot_tpu.ops import dispatch
 KERNEL_NAMES = ("flash_attention_fwd", "flash_attention_dq",
                 "flash_attention_dkv")
 
-_BLOCK = 128
+# Tile sides, tried in this order: the first that divides T is taken.
+# A probe of the three programs alone on v5e at T=8192, 32 heads, widths
+# 192/128, bf16 (ms a call with the layout change around it, forward /
+# dq / dkv): 128 29.3 / 30.3 / 40.7, 256 13.3 / 12.9 / 15.5, 512 9.1 /
+# 12.0 / 14.1; 256x512, 256x1024 and 1024x1024 read as 512 does.
+_BLOCKS = (512, 256, 128)
 _MAX_SINGLE_BLOCK_T = 1024
-# K and V are staged whole per (b·h) row, and Pallas double-buffers
-# pipelined inputs — so the resident K/V footprint is 2× their size.
-# Bound that under the ~16 MB scoped-VMEM budget with headroom for the
-# Q/O/lse tiles and f32 working set (measured on v5e: T=8192, D=128
-# bf16 fits; T=16384 overflows the 16 MB limit by the double buffer).
-# Longer sequences belong to ring_attention.
+# K and V (in the dk/dv program Q and dO) are staged whole per (b·h)
+# row, and Pallas double-buffers pipelined inputs — so the resident
+# footprint is 2× their size. Each call asks for the scoped VMEM it
+# reckons (_compiler_params: the staged blocks and the tiles it works
+# on); the rows are bounded here so that the sum stays a small part of
+# the chip's VMEM (T=8192 at widths 192/128 in bf16 stages 12 MiB of
+# rows and asks for 20-21 MiB). Longer sequences belong to
+# ring_attention.
 _MAX_KV_VMEM_BYTES = 14 * 1024 * 1024
 _PIPELINE_BUFFERS = 2
-# Room beside the staged blocks for a kernel's float32 working set
-# (the cast blocks, the score tile, the accumulators).
-_VMEM_WORKING_BYTES = 8 * 1024 * 1024
 _MIN_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
+# Q Kᵀ, dO Vᵀ and their transposes contract the last axis of both
+# operands.
+_NT = (((1,), (1,)), ((), ()))
 
 
 def flash_attention_reference(q, k, v, causal: bool = False,
@@ -67,13 +81,39 @@ def flash_attention_reference(q, k, v, causal: bool = False,
   return out.astype(v.dtype)
 
 
-def _causal_mask(s, qi, kj, block_q: int, block_k: int):
-  """Mask the (BQ, BK) score tile to the causal triangle with -inf."""
-  rows = qi * block_q + jax.lax.broadcasted_iota(
-      jnp.int32, (block_q, block_k), 0)
-  cols = kj * block_k + jax.lax.broadcasted_iota(
-      jnp.int32, (block_q, block_k), 1)
-  return jnp.where(rows >= cols, s, -jnp.inf)
+def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
+  """An MXU product: the operands in the dtype they come in, never
+  cast up, the sum in float32."""
+  return jax.lax.dot_general(a, b, dims,
+                             preferred_element_type=jnp.float32)
+
+
+def _causal_mask(s, row0, col0, transposed: bool = False):
+  """Mask a score tile whose first query is row0 and first key col0 to
+  the causal triangle with -inf; queries run down the tile, or across
+  it where it is `transposed`."""
+  query_axis = 1 if transposed else 0
+  ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - query_axis)
+           - jax.lax.broadcasted_iota(jnp.int32, s.shape, query_axis))
+  return jnp.where(ahead <= row0 - col0, s, -jnp.inf)
+
+
+def _tile_loop(body, carry, lower, upper, *, masked: bool):
+  return jax.lax.fori_loop(
+      lower, upper, functools.partial(body, masked=masked), carry)
+
+
+def _key_tiles(body, carry, row0, *, causal: bool, block_q: int,
+               block_k: int, seq_len: int):
+  """Runs `body` over the K tiles a Q tile starting at row `row0` sees:
+  all of them, or causally those under the diagonal unmasked and then
+  the few the diagonal crosses, masked."""
+  if not causal:
+    return _tile_loop(body, carry, 0, seq_len // block_k, masked=False)
+  below = (row0 + 1) // block_k          # last column <= first row
+  reached = (row0 + block_q + block_k - 1) // block_k
+  carry = _tile_loop(body, carry, 0, below, masked=False)
+  return _tile_loop(body, carry, below, reached, masked=True)
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
@@ -81,52 +121,50 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
   """One (block_q, D) query tile vs all K/V tiles of this (b·h) row.
 
   Also emits the per-row logsumexp (the flash-backward residual)."""
-  q = q_ref[0].astype(jnp.float32) * scale                 # (BQ, D)
-  qi = pl.program_id(1)
+  q = q_ref[0]                                             # (BQ, D)
+  row0 = pl.program_id(1) * block_q
   head_dim = v_ref.shape[-1]
 
-  def body(kj, carry):
+  def body(kj, carry, masked):
     m, l, acc = carry
-    k_blk = k_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-    v_blk = v_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (BQ, BK)
-    if causal:
-      s = _causal_mask(s, qi, kj, block_q, block_k)
-    m_blk = jnp.max(s, axis=-1, keepdims=True)
-    m_new = jnp.maximum(m, m_blk)
-    safe_max = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
-    correction = jnp.exp(m - safe_max)
-    p = jnp.exp(s - safe_max)
+    col0 = pl.multiple_of(kj * block_k, block_k)
+    k_blk = k_ref[0, pl.ds(col0, block_k), :]
+    v_blk = v_ref[0, pl.ds(col0, block_k), :]
+    s = _dot(q, k_blk, _NT) * scale                        # (BQ, BK)
+    if masked:
+      s = _causal_mask(s, row0, col0)
+    # Every row sees column 0 in the first tile, so m is finite from
+    # there on and a tile that masks a whole row adds exp(-inf) = 0.
+    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+    correction = jnp.exp(m - m_new)
+    p = jnp.exp(s - m_new)
     l_new = l * correction + jnp.sum(p, axis=-1, keepdims=True)
-    acc_new = acc * correction + jnp.dot(
-        p, v_blk, preferred_element_type=jnp.float32)
+    acc_new = acc * correction + _dot(p.astype(v_blk.dtype), v_blk)
     return m_new, l_new, acc_new
 
-  if causal:
-    # Only K blocks that intersect the causal triangle of this Q tile.
-    num_k = (qi * block_q + block_q + block_k - 1) // block_k
-  else:
-    num_k = seq_len // block_k
   init = (jnp.full((block_q, 1), -jnp.inf, jnp.float32),
           jnp.zeros((block_q, 1), jnp.float32),
           jnp.zeros((block_q, head_dim), jnp.float32))
-  m, l, acc = jax.lax.fori_loop(0, num_k, body, init)
-  safe_m = jnp.where(jnp.isneginf(m), 0.0, m)
-  # Fully-masked rows (l == 0, only possible non-causally with explicit
-  # masks) get a large-negative finite lse via the 1e-37 clamp; the
-  # backward's exp(s - lse) is still 0 there because s is -inf. Shape
-  # (BQ, 1): the lse array carries a trailing unit dim so its blocks
-  # satisfy the TPU (8, 128) block-shape rule.
-  lse_ref[0] = safe_m + jnp.log(jnp.maximum(l, 1e-37))
-  l = jnp.where(l == 0.0, 1.0, l)
+  m, l, acc = _key_tiles(body, init, row0, causal=causal, block_q=block_q,
+                         block_k=block_k, seq_len=seq_len)
+  # Shape (BQ, 1): the lse array carries a trailing unit dim so its
+  # blocks satisfy the TPU (8, 128) block-shape rule.
+  lse_ref[0] = m + jnp.log(l)
   o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
 def _block_sizes(t: int):
-  if t % _BLOCK == 0:
-    return _BLOCK, _BLOCK
+  """(block_q, block_k) for a sequence of T, or None: the widest tile
+  side that divides T, or all of a short T as one tile. A tile's chain
+  of products and softmax passes runs start to end before the next
+  tile's begins, so a wide tile is what keeps the MXU fed; a wider K
+  tile also pays the running-max rescale of the accumulator once per
+  more keys. Head widths and dtype do not enter: the tiles' float32
+  working set is the same whatever is staged, and _supported bounds
+  the staged rows."""
+  for block in _BLOCKS:
+    if t % block == 0:
+      return block, block
   if t <= _MAX_SINGLE_BLOCK_T:
     return t, t
   return None
@@ -136,7 +174,7 @@ def _supported(q, k, v) -> Optional[str]:
   """None if the Pallas path can run, else the reason it cannot."""
   t = q.shape[1]
   if _block_sizes(t) is None:
-    return (f"T must be divisible by {_BLOCK} or <= "
+    return (f"T must be divisible by {_BLOCKS[-1]} or <= "
             f"{_MAX_SINGLE_BLOCK_T}; got T={t}")
   # K and V each at its own head width (MLA: 192 and 128).
   kv_bytes = _PIPELINE_BUFFERS * t * (
@@ -149,17 +187,22 @@ def _supported(q, k, v) -> Optional[str]:
   return None
 
 
-def _compiler_params(*blocks):
+def _compiler_params(block_q: int, block_k: int, d: int, dv: int,
+                     *blocks):
   """Scoped-VMEM limit for a call that stages `blocks` ((rows, width,
-  dtype) each, double-buffered): a (rows, 1) float32 column takes a
-  whole 128-lane tile per 8 rows, so the backward's full-row lse and
-  delta outgrow Mosaic's 16 MiB default from T = 4096 on."""
-  staged = 0
-  for rows, width, dtype in blocks:
-    lanes = -(-width // 128) * 128
-    staged += _PIPELINE_BUFFERS * rows * lanes * jnp.dtype(dtype).itemsize
+  dtype) each, double-buffered, counted at padded sublanes and lanes: a
+  (rows, 1) float32 column takes a whole 128-lane tile per 8 rows) and
+  works on (block_q, block_k) tiles: beside the staged blocks a kernel
+  keeps float32 tiles of the scores' shape (s, p, dP, dS, and p and dS
+  again in the operands' dtype: six at most) and float32 accumulators
+  d and dv wide."""
+  pad = lambda n, to: -(-n // to) * to
+  staged = sum(_PIPELINE_BUFFERS * pad(rows, 8) * pad(width, 128)
+               * jnp.dtype(dtype).itemsize for rows, width, dtype in blocks)
+  working = 4 * (6 * block_q * pad(block_k, 128)
+                 + max(block_q, block_k) * (pad(d, 128) + pad(dv, 128)))
   return pltpu.CompilerParams(vmem_limit_bytes=max(
-      _MIN_VMEM_LIMIT_BYTES, staged + _VMEM_WORKING_BYTES))
+      _MIN_VMEM_LIMIT_BYTES, staged + working))
 
 
 def _to_rows(x):
@@ -198,6 +241,7 @@ def _pallas_forward(q, k, v, causal: bool, scale: float,
           pl.BlockSpec((1, block_q, 1), tile, memory_space=pltpu.VMEM),
       ),
       compiler_params=_compiler_params(
+          block_q, block_k, d, dv,
           (block_q, d, q.dtype), (t, d, k.dtype), (t, dv, v.dtype),
           (block_q, dv, v.dtype), (block_q, 1, jnp.float32)),
       interpret=jax.default_backend() != "tpu",
@@ -213,78 +257,73 @@ def _kernel_dq(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                *, scale: float, causal: bool, block_q: int, block_k: int,
                seq_len: int):
   """dq for one query tile: dq_i = Σ_j (P_ij ⊙ (dO_i V_jᵀ − Δ_i)) K_j."""
-  q = q_ref[0].astype(jnp.float32)                         # (BQ, D)
-  do = do_ref[0].astype(jnp.float32)                       # (BQ, D)
+  q = q_ref[0]                                             # (BQ, D)
+  do = do_ref[0]                                           # (BQ, Dv)
   lse = lse_ref[0]                                         # (BQ, 1)
   delta = delta_ref[0]                                     # (BQ, 1)
-  qi = pl.program_id(1)
+  row0 = pl.program_id(1) * block_q
 
-  def body(kj, dq_acc):
-    k_blk = k_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-    v_blk = v_ref[0, pl.ds(kj * block_k, block_k), :].astype(jnp.float32)
-    s = jax.lax.dot_general(
-        q, k_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # (BQ, BK)
-    if causal:
-      s = _causal_mask(s, qi, kj, block_q, block_k)
+  def body(kj, dq_acc, masked):
+    col0 = pl.multiple_of(kj * block_k, block_k)
+    k_blk = k_ref[0, pl.ds(col0, block_k), :]
+    v_blk = v_ref[0, pl.ds(col0, block_k), :]
+    s = _dot(q, k_blk, _NT) * scale                        # (BQ, BK)
+    if masked:
+      s = _causal_mask(s, row0, col0)
     p = jnp.exp(s - lse)
-    dpv = jax.lax.dot_general(
-        do, v_blk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (BQ, BK)
-    ds = p * (dpv - delta)
-    return dq_acc + jnp.dot(ds, k_blk,
-                            preferred_element_type=jnp.float32) * scale
+    ds = p * (_dot(do, v_blk, _NT) - delta)                # (BQ, BK)
+    return dq_acc + _dot(ds.astype(k_blk.dtype), k_blk)
 
-  if causal:
-    num_k = (qi * block_q + block_q + block_k - 1) // block_k
-  else:
-    num_k = seq_len // block_k
-  dq = jax.lax.fori_loop(
-      0, num_k, body, jnp.zeros((block_q, q.shape[-1]), jnp.float32))
-  dq_ref[0] = dq.astype(dq_ref.dtype)
+  dq = _key_tiles(body, jnp.zeros(q.shape, jnp.float32), row0,
+                  causal=causal, block_q=block_q, block_k=block_k,
+                  seq_len=seq_len)
+  dq_ref[0] = (dq * scale).astype(dq_ref.dtype)
 
 
 def _kernel_dkv(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, *, scale: float, causal: bool,
                 block_q: int, block_k: int, seq_len: int):
   """dk/dv for one key tile: dV_j = Σ_i P_ijᵀ dO_i;
-  dK_j = Σ_i (P_ij ⊙ (dO_i V_jᵀ − Δ_i))ᵀ Q_i · scale."""
-  k_tile = k_ref[0].astype(jnp.float32)                    # (BK, D)
-  v_tile = v_ref[0].astype(jnp.float32)                    # (BK, D)
-  kj = pl.program_id(1)
+  dK_j = Σ_i (P_ij ⊙ (dO_i V_jᵀ − Δ_i))ᵀ Q_i · scale.
 
-  def body(qi, carry):
+  The score tile is built transposed, (BK, BQ), with lse and delta as
+  rows, one (1, BQ) row per Q tile: Pᵀ and dSᵀ are then what the two
+  accumulating products take as they are, where (BQ, BK) tiles would
+  be transposed once each per tile."""
+  k_tile = k_ref[0]                                        # (BK, D)
+  v_tile = v_ref[0]                                        # (BK, Dv)
+  col0 = pl.program_id(1) * block_k
+
+  def body(qi, carry, masked):
     dk_acc, dv_acc = carry
-    q_blk = q_ref[0, pl.ds(qi * block_q, block_q), :].astype(jnp.float32)
-    do_blk = do_ref[0, pl.ds(qi * block_q, block_q), :].astype(
-        jnp.float32)
-    lse_blk = lse_ref[0, pl.ds(qi * block_q, block_q), :]   # (BQ, 1)
-    delta_blk = delta_ref[0, pl.ds(qi * block_q, block_q), :]
-    s = jax.lax.dot_general(
-        q_blk, k_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # (BQ, BK)
-    if causal:
-      s = _causal_mask(s, qi, kj, block_q, block_k)
-    p = jnp.exp(s - lse_blk)                               # (BQ, BK)
-    dv_acc = dv_acc + jax.lax.dot_general(
-        p, do_blk, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (BK, D)
-    dpv = jax.lax.dot_general(
-        do_blk, v_tile, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                # (BQ, BK)
-    ds = p * (dpv - delta_blk)
-    dk_acc = dk_acc + jax.lax.dot_general(
-        ds, q_blk, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # (BK, D)
+    row0 = pl.multiple_of(qi * block_q, block_q)
+    q_blk = q_ref[0, pl.ds(row0, block_q), :]
+    do_blk = do_ref[0, pl.ds(row0, block_q), :]
+    lse_blk = lse_ref[0, pl.ds(qi, 1), :]                   # (1, BQ)
+    delta_blk = delta_ref[0, pl.ds(qi, 1), :]
+    s_t = _dot(k_tile, q_blk, _NT) * scale                 # (BK, BQ)
+    if masked:
+      s_t = _causal_mask(s_t, row0, col0, transposed=True)
+    p_t = jnp.exp(s_t - lse_blk)
+    dv_acc = dv_acc + _dot(p_t.astype(do_blk.dtype), do_blk)
+    ds_t = p_t * (_dot(v_tile, do_blk, _NT) - delta_blk)
+    dk_acc = dk_acc + _dot(ds_t.astype(q_blk.dtype), q_blk)
     return dk_acc, dv_acc
 
   num_q = seq_len // block_q
-  # Causal: only Q tiles whose last row reaches this K tile contribute.
-  start = (kj * block_k) // block_q if causal else 0
-  init = (jnp.zeros(k_tile.shape, jnp.float32),
-          jnp.zeros(v_tile.shape, jnp.float32))
-  dk, dv = jax.lax.fori_loop(start, num_q, body, init)
-  dk_ref[0] = dk.astype(dk_ref.dtype)
+  carry = (jnp.zeros(k_tile.shape, jnp.float32),
+           jnp.zeros(v_tile.shape, jnp.float32))
+  if causal:
+    # Only Q tiles whose last row reaches this K tile contribute: first
+    # those the diagonal crosses, masked, then the ones wholly under it.
+    reached = col0 // block_q
+    below = jnp.minimum(
+        (col0 + block_k - 1 + block_q - 1) // block_q, num_q)
+    carry = _tile_loop(body, carry, reached, below, masked=True)
+    dk, dv = _tile_loop(body, carry, below, num_q, masked=False)
+  else:
+    dk, dv = _tile_loop(body, carry, 0, num_q, masked=False)
+  dk_ref[0] = (dk * scale).astype(dk_ref.dtype)
   dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
@@ -306,6 +345,9 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
   delta = _to_rows(jnp.sum(do.astype(jnp.float32)
                            * out.astype(jnp.float32), axis=-1,
                            keepdims=True))                  # (BH, T, 1)
+  # The dk/dv program reads both as one row per Q tile, see _kernel_dkv.
+  lse_rows, delta_rows = (x.reshape(b * h, t // block_q, block_q)
+                          for x in (lse, delta))
   interpret = jax.default_backend() != "tpu"
   tile_q = lambda i, qi: (i, qi, 0)
   tile_k = lambda i, kj: (i, kj, 0)
@@ -327,6 +369,7 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
       out_specs=pl.BlockSpec((1, block_q, d), tile_q,
                              memory_space=pltpu.VMEM),
       compiler_params=_compiler_params(
+          block_q, block_k, d, dv,
           (block_q, d, q.dtype), (t, d, k.dtype), (t, dv, v.dtype),
           (block_q, dv, do.dtype), (block_q, 1, jnp.float32),
           (block_q, 1, jnp.float32), (block_q, d, q.dtype)),
@@ -343,20 +386,24 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
           pl.BlockSpec((1, block_k, d), tile_k, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, block_k, dv), tile_k, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, t, dv), full, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, t, 1), full, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, t, 1), full, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, t // block_q, block_q), full,
+                       memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, t // block_q, block_q), full,
+                       memory_space=pltpu.VMEM),
       ],
       out_specs=(
           pl.BlockSpec((1, block_k, d), tile_k, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, block_k, dv), tile_k, memory_space=pltpu.VMEM),
       ),
       compiler_params=_compiler_params(
+          block_q, block_k, d, dv,
           (t, d, q.dtype), (block_k, d, k.dtype), (block_k, dv, v.dtype),
-          (t, dv, do.dtype), (t, 1, jnp.float32), (t, 1, jnp.float32),
+          (t, dv, do.dtype), (t // block_q, block_q, jnp.float32),
+          (t // block_q, block_q, jnp.float32),
           (block_k, d, k.dtype), (block_k, dv, v.dtype)),
       interpret=interpret,
       name=KERNEL_NAMES[2],
-  )(qr, kr, vr, dor, lse, delta)
+  )(qr, kr, vr, dor, lse_rows, delta_rows)
   return (_from_rows(dq, b, h), _from_rows(dk, b, h),
           _from_rows(dv, b, h))
 
@@ -391,7 +438,8 @@ def flash_attention(q, k, v, causal: bool = False,
     causal: apply a causal mask.
     scale: attention scale; default 1/sqrt(D).
     implementation: "pallas", "xla", or "auto" (pallas when T is
-      blockable: divisible by 128 or ≤ 1024 as one block).
+      blockable: divisible by 128, in tiles of the widest of 512, 256
+      and 128 that divides it, or ≤ 1024 as one block).
 
   Returns:
     (B, T, H, Dv) attention output in v's dtype.

@@ -4,6 +4,8 @@ Off-TPU the kernels run in Pallas interpreter mode, so these tests
 exercise the real kernel bodies, not just the XLA references.
 """
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -181,6 +183,97 @@ class TestFlashAttention:
     for a, b in zip(gp, gr):
       np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                  atol=5e-5)
+
+  # bf16 keeps 8 bits: the output and the gradients (entries up to 2-4
+  # here) are rounded 2^-7 to 2^-6 apart once at the end, and p and dS
+  # once as operands, so entries may sit 2e-2 apart and a whole array
+  # 6e-3 of its norm (read: 2.0e-3 to 2.8e-3). A tile skipped, masked
+  # wrongly or normalized wrongly shows at O(1).
+  @pytest.mark.parametrize("t,blocks,chosen", [
+      (384, (128, 128), True),    # three tiles
+      (768, (256, 256), True),    # three tiles
+      (1024, (512, 512), True),   # two tiles
+      (200, (200, 200), True),    # one tile, the diagonal through it
+      (512, (128, 256), False),   # K tiles the diagonal leaves half-way
+      (512, (256, 128), False),   # a Q tile's rows end inside a K tile
+      (384, (128, 384), False),   # the last Q tile alone sees all of K
+  ])
+  def test_bf16_at_mla_widths_matches_reference(self, monkeypatch, t,
+                                                blocks, chosen):
+    """`chosen`: the tiles `_block_sizes` picks itself; else tiles put in
+    its place, for the loops' bounds where the two sides differ."""
+    module = importlib.import_module("tensor2robot_tpu.ops.flash_attention")
+    if chosen:
+      assert module._block_sizes(t) == blocks
+    else:
+      monkeypatch.setattr(module, "_block_sizes", lambda t: blocks)
+    rng = np.random.default_rng(t)
+    mk = lambda d: jnp.asarray(rng.standard_normal((1, t, 2, d)),
+                               jnp.bfloat16)
+    q, k, v, weight = mk(192), mk(192), mk(128), mk(128)
+    f32 = lambda x: np.asarray(x.astype(jnp.float32))
+
+    def run(fn, **kw):
+      def loss(q, k, v):
+        out = fn(q, k, v, causal=True, **kw)
+        return jnp.sum(out.astype(jnp.float32)
+                       * weight.astype(jnp.float32)), out
+      grads, out = jax.grad(loss, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+      return (out,) + grads
+
+    got = run(flash_attention, implementation="pallas")
+    want = run(flash_attention_reference)
+    for name, g, w in zip(("out", "dq", "dk", "dv"), got, want):
+      assert g.dtype == jnp.bfloat16 and g.shape == w.shape, name
+      np.testing.assert_allclose(f32(g), f32(w), atol=2e-2, rtol=2e-2,
+                                 err_msg=name)
+      assert (np.linalg.norm(f32(g) - f32(w))
+              < 6e-3 * np.linalg.norm(f32(w))), name
+
+  @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+  @pytest.mark.parametrize("causal", [True, False])
+  def test_products_take_operands_as_staged(self, dtype, causal):
+    """Every product of the three programs takes both operands in the
+    inputs' own dtype (q, k, v, dO as staged; p and dS cast to it) and
+    sums in float32: a cast of a block up to float32 before a product
+    would show here as a float32 operand under bf16 inputs."""
+    from tensor2robot_tpu.ops.flash_attention import KERNEL_NAMES
+    q = jnp.zeros((1, 256, 2, 192), dtype)
+    v = jnp.zeros((1, 256, 2, 128), dtype)
+
+    def loss(q, k, v):
+      return jnp.sum(flash_attention(
+          q, k, v, causal=causal,
+          implementation="pallas").astype(jnp.float32))
+
+    def sub_jaxprs(eqn):
+      for value in eqn.params.values():
+        for item in value if isinstance(value, (tuple, list)) else [value]:
+          item = getattr(item, "jaxpr", item)
+          if hasattr(item, "eqns"):
+            yield item
+
+    def find(jaxpr, primitive):
+      for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+          yield eqn
+        else:
+          for sub in sub_jaxprs(eqn):
+            yield from find(sub, primitive)
+
+    traced = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, v)
+    kernels = {eqn.params["name"]: eqn.params["jaxpr"]
+               for eqn in find(traced.jaxpr, "pallas_call")}
+    assert sorted(kernels) == sorted(KERNEL_NAMES)
+    # Forward S and P V; dq S, dP and dS K; dkv S, Pt dO, dP and dSt Q:
+    # once in the loop, causally once more in the masked loop.
+    loops = 2 if causal else 1
+    for name, products in zip(KERNEL_NAMES, (2, 3, 4)):
+      dots = list(find(kernels[name], "dot_general"))
+      assert len(dots) == products * loops, name
+      for dot in dots:
+        assert [x.aval.dtype for x in dot.invars] == [dtype, dtype], name
+        assert dot.outvars[0].aval.dtype == jnp.float32, name
 
   def test_agrees_with_ring_attention(self):
     # The in-chip blockwise kernel and the cross-chip ring must agree:
