@@ -42,14 +42,13 @@ _SCALES = {
              "full": dict(steps=2000, image=64)},
 }
 # Expectation per (check, scale), set just-under-measured (10-15%
-# slack) from the committed r2 runs on cluttered scenes
-# (CAPABILITY_r02_full.jsonl / CAPABILITY_r02_fast.jsonl, one v5e,
-# 2026-07-30): pose_env 0.765 fast / 0.925 full (tight 0.05 gate),
+# slack) from the r2 runs on cluttered scenes (one v5e, 2026-07-30;
+# the records are no longer in the tree): pose_env 0.765 fast / 0.925 full (tight 0.05 gate),
 # qtopt 0.47/0.85 (random 0.05), grasp2vec 0.453/0.734 (chance 0.016).
 # vrgripper: recalibrated r3 — the r3 pose_env occluder randomization
 # hardened its training scenes (measured r3: 0.75 fast / 0.925 full vs
 # 0.86/0.95 at r2), so the bars moved to keep the 10-15% slack
-# (CAPABILITY_r03_*.jsonl, 2026-07-31). maml: recalibrated r3 (VERDICT r2 #6 — the old
+# (2026-07-31). maml: recalibrated r3 (VERDICT r2 #6 — the old
 # gate was saturated at 1.0): noisy-demonstrations regime (sigma=0.22
 # condition labels) scored at half the object radius measured 0.879
 # fast / 0.922 full (one v5e, 2026-07-31), so the gate now sits in the

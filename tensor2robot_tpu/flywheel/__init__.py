@@ -13,7 +13,10 @@ Layout:
                     flywheel_rules (the poisoning-interlock HealthRules)
   loop.py           FleetClient (episode driver + outcome closer) and
                     FlywheelLoop (the closed cycle end to end)
-  flywheel_bench.py the FLYWHEEL_r18 proof artifact
+
+tests/test_flywheel.py walks the gate, the closed loop and the
+stale-params control (the staleness ceiling must breach when no export
+reaches the fleet).
 """
 
 from tensor2robot_tpu.flywheel.capture import (  # noqa: F401

@@ -20,7 +20,7 @@ RECORDED in the ring either way. Without a configured ``dump_dir`` the
 recorder runs ring-only (record everything, write nothing): safe to
 wire into every component by default.
 
-Dump schema (``docs/ARTIFACTS.md`` round-12 section)::
+Dump schema::
 
     {"schema": "t2r-flightrec-1", "host": ..., "pid": ...,
      "reason": ..., "dumped_at": <unix s>, "events_total": N,

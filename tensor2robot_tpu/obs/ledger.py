@@ -23,7 +23,7 @@ anakin/megastep D2H metric reads) record true device+D2H time; staging
 calls that fire and forget (the device ring's host extend) record
 dispatch time only — attribution shares are therefore lower bounds for
 async call sites, and on scanned executables ``cost_analysis`` reports
-the scan body ONCE (bench.py convention), so FLOPs-derived fields are
+the scan body ONCE, so FLOPs-derived fields are
 per-body, not per-dispatch-of-K.
 
 ``check_compile_ledger`` is the ONE shared assertion helper the replay,
@@ -37,9 +37,9 @@ import threading
 import time
 from typing import Dict, Iterable, List, Optional
 
-# Chip peak FLOP/s keyed by substrings of jax device_kind (the bench.py
-# table, now owned here so every MFU estimate in the repo shares one
-# source). v5e ("TPU v5 lite"): public spec bf16 peak.
+# Chip peak FLOP/s keyed by substrings of jax device_kind (ROADMAP D9:
+# a second copy of benchmark/peaks.json). v5e ("TPU v5 lite"): public
+# spec bf16 peak.
 CHIP_PEAKS = {
     "v5 lite": 197e12,
     "v5e": 197e12,

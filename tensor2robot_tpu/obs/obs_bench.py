@@ -38,16 +38,15 @@ the aggregator, not just the spine. One run, ONE JSON line:
    logdir: the merged view's shed rollup must be consistent (global
    counters == per-class sums across sources), the breach request's
    correlation timeline must link enqueue → flush → dispatch in the
-   merged trace, and the hosts_merged / stall counts land in bench.py's
-   compact keys. The MULTI-process version of this merge is the
+   merged trace, and the hosts_merged / stall counts land in the
+   artifact's compact keys. The MULTI-process version of this merge is the
    separate committed FLEETOBS artifact (bin/obs_aggregate --smoke).
 
 HONESTY CAVEAT (mirrors MULTICHIP/FLEET): chipless, the mesh is 8
 virtual CPU devices sharing this host's cores — `estimated_mfu` is
 null (no CPU peak-FLOPs model) and shares are host wall-clock
 attribution, structural evidence rather than chip rates. Real-chip
-attribution lands via bench.py's `obs` block (same schema) on a pool
-window.
+attribution is not measured (ROADMAP D9).
 """
 
 from __future__ import annotations
@@ -322,7 +321,7 @@ def measure_obs(
           "host work outside any executable). estimated_mfu is null "
           "with virtual_mesh=true — no peak-FLOPs model for this host "
           "(the MULTICHIP caveat applied to utilization); real-chip "
-          "attribution lands via bench.py's obs block, same schema. "
+          "attribution is not measured. "
           "The Chrome trace and flight-recorder dump live in the "
           "run's logdir (paths are run-local, basenames recorded "
           "here); the fused anakin path reports act/step/extend/learn "

@@ -15,8 +15,8 @@ subgradients of the same function (ties are common after relu, where
 whole windows can be 0). tests/test_ops.py pins both contracts.
 
 Measured use: a candidate swap for the QT-Opt stem's 118²→59² pool —
-adopted only if the step budget shows a real win (bench.py
-§step_budget_parity_b32 measures the stem piece both ways).
+adopted only if the chip shows a real win: one paired run on the
+`qtopt_train_resident` cell (ROADMAP S3b), not yet made.
 """
 
 from __future__ import annotations

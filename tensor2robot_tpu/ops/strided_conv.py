@@ -26,8 +26,8 @@ Weights stay in the parity (3, 3, C, O) layout; the fold runs inside
 jit on the tiny kernel tensor, so checkpoints and the model's param
 tree are untouched and autodiff transposes the fold for free.
 
-Adopted only where the step budget shows a measured win (bench.py
-§step_budget_parity_b32 measures the post tower both ways);
+Adopted only where the chip shows a measured win (one paired run on
+the `qtopt_train_resident` cell, ROADMAP S3b, not yet made);
 correctness is pinned CPU-side in tests/test_ops.py either way.
 """
 

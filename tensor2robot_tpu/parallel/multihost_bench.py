@@ -609,7 +609,7 @@ def measure_multihost(seed: int = 0, num_steps: int = 15,
       "oracle_parity": oracle,
       "fused_resume": resume,
       "frontdoor": frontdoor,
-      # Compact sentinels (bench.py round 19; null-safe): structure/
+      # Compact sentinels (round 19; null-safe): structure/
       # parity claims are meaningful chipless; rates are not.
       "multihost_processes": bringup["processes"],
       "oracle_bit_identical": all(oracle["bars"].values()),

@@ -45,7 +45,8 @@ Learner-side dataflow (all inside the learner process):
       extend executable; chunks are already device-resident)
   -> MegastepLearner.step every `chunks_per_megastep` chunks.
 
-Determinism contract (the SEBULBA_r20 bit-identity bar): the learner
+Determinism contract (the bit-identity bar of
+tests/test_sebulba.py::TestSebulbaLiveOracleParity): the learner
 consumes chunks in QUEUE order and runs one megastep per fixed chunk
 count, so its param evolution is a pure function of the arrival
 manifest — the recorded `(actor, seq)` ingestion order. Replaying the
@@ -1174,9 +1175,8 @@ def main(argv=None) -> None:
                       help=argparse.SUPPRESS)
   args = parser.parse_args(argv)
   if args.worker is None:
-    parser.error("this module's CLI is the worker entry point; the "
-                 "user-facing protocol lives in "
-                 "tensor2robot_tpu.bin.bench_sebulba")
+    parser.error("this module's CLI is the worker entry point; "
+                 "sebulba.run_live starts it")
   from tensor2robot_tpu.utils import compile_cache
   compile_cache.configure()
   spec = json.loads(args.worker)

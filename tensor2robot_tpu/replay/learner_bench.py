@@ -34,7 +34,7 @@ import numpy as np
 
 
 def _spread(values, digits=3):
-  """{median,min,max,trials} — bench.py's committed field shape.
+  """{median,min,max,trials} — the committed field shape of a timing.
 
   Shared with replay/actor_bench.py (as is `_synthetic_transitions`):
   the learner and actor throughput blocks must carry the same citable

@@ -749,7 +749,7 @@ class ReplayTrainLoop:
     """Metrics go THROUGH the process registry (gauges), then the one
     registry→MetricWriter bridge flushes exactly this block — JSONL/TB
     records keep the pre-registry schema while the registry holds the
-    same series process-wide for the obs bench and bench.py."""
+    same series process-wide for the obs bench."""
     self.registry.set_gauges(scalars)
     self.registry.flush_to(self.writer, step, names=scalars.keys())
 

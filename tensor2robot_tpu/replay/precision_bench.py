@@ -42,8 +42,7 @@ XLA virtual CPU devices and bf16 matmuls are emulated — the measured
 scoring rates say nothing about chip speedups (CPU bf16 is typically
 SLOWER), so the compact `cem_bf16_speedup` is null on a virtual mesh
 and the chipless artifact's claims are structure + parity. The real
-speedup lands through bench.py's `precision` block when the TPU pool
-returns (same schema, measured rates become citable).
+speedup is not measured: a served-tier cell decides it (ROADMAP B10).
 """
 
 from __future__ import annotations
@@ -505,7 +504,7 @@ def measure_precision(
           "tier_shares": tier_shares,
       },
       "rollout": rollout,
-      # Compact sentinels (bench.py round 14; null-safe): the agreement
+      # Compact sentinels (round 14; null-safe): the agreement
       # rate is meaningful chipless (numerics, not timing); the speedup
       # is a CHIP claim and stays null on a virtual mesh.
       "cem_bf16_action_agreement": agreement["overall_rate"],

@@ -433,7 +433,7 @@ def measure_tpquant(
           "tier_shares": tier_shares,
       },
       "rollout": rollout,
-      # Compact sentinels (bench.py round 17; null-safe): agreement and
+      # Compact sentinels (round 17; null-safe): agreement and
       # byte counts are device-independent; scaling efficiency is a
       # CHIP claim and stays null on a virtual mesh.
       "tp_scaling_efficiency": (

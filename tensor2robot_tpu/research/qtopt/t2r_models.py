@@ -72,7 +72,7 @@ class _GraspingQModule(nn.Module):
   # (lanes-folded strided conv) — with IDENTICAL param names/shapes
   # (post_conv{i}/kernel+bias), so checkpoints interchange freely.
   # Outputs differ only by float reassociation (tested). Adoption as
-  # default awaits the on-chip step-budget numbers (bench.py).
+  # default awaits one paired chip run (ROADMAP S3b).
   impl: str = "parity"
 
   @nn.compact
@@ -151,9 +151,6 @@ class _GraspingQModule(nn.Module):
 @configurable
 class QTOptGraspingModel(CriticModel):
   """(image, action) → grasp-success Q, cross-entropy vs Bellman target."""
-
-  # bench.py reads this: the per-chip benchmark batch.
-  benchmark_batch_size = 32
 
   def __init__(self, image_size: int = IMAGE_SIZE,
                in_image_size: Optional[int] = None,

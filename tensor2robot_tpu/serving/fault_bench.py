@@ -756,7 +756,7 @@ def measure_faults(
       "dispatcher": dispatcher,
       "export_watcher": export_watcher,
       "learner": {"parity": parity, "live": live},
-      # Compact sentinels (bench.py round 15; null-safe): recovery is
+      # Compact sentinels (round 15; null-safe): recovery is
       # meaningful chipless as STRUCTURE (typed sheds, ordering, the
       # breaker arc, bit-parity resume); recovery LATENCY on real
       # chips is the queued chip claim.
@@ -775,8 +775,7 @@ def measure_faults(
           "crash-resume proven bit-exact on a deterministic stream "
           "and within the r14 TD tolerance on live threaded runs. "
           "virtual_mesh=true: structure/ordering claims only — "
-          "recovery latency on real chips lands via bench.py's "
-          "faults block."),
+          "recovery latency on real chips is not measured."),
   }
 
   if enforce_bars:

@@ -446,7 +446,7 @@ def measure_fleet(
           "host-scale with virtual_mesh=true (virtual devices share "
           "this host's cores — structure, ledger, shed ordering, and "
           "the rollout cycle are the chipless claims; rates/latencies "
-          "become citable on real chips via bench.py's fleet block). "
+          "on real chips are the benchmark's serving cells'). "
           "fleet_p99_headroom = min over classes of "
           "(budget - p99)/budget at the top sweep point; "
           "fleet_clients_sustained = clients x largest multiplier "

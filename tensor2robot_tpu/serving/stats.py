@@ -1,7 +1,7 @@
 """Serving observability: latency histograms + batching counters.
 
-The fleet numbers the artifact schema carries (docs/ARTIFACTS.md
-serving row): per-request latency p50/p99, queue depth at flush, batch
+The fleet numbers the serving artifacts and the benchmark's serving
+cells carry: per-request latency p50/p99, queue depth at flush, batch
 occupancy (real requests / compiled bucket slots), and padding waste.
 Since round 11 every counter is additionally kept PER SLO CLASS
 (serving/slo.py): class-keyed latency histograms plus shed counters

@@ -431,9 +431,8 @@ class Trainer:
   def aot_train_steps(self, state: TrainState, features, labels=None):
     """AOT-lowered+compiled `train_steps` executable for the same
     arguments. Exposes XLA's per-executable introspection
-    (`.cost_analysis()` → flops / bytes accessed), which bench.py uses
-    to emit a measured roofline instead of hand-derived numbers. The
-    executable shares `train_steps`' donation semantics."""
+    (`.cost_analysis()` → flops / bytes accessed). The executable
+    shares `train_steps`' donation semantics."""
     if self._train_steps is None:
       self._train_steps = self._build_train_steps()
     return self._train_steps.lower(state, features, labels).compile()

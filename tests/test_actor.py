@@ -17,6 +17,7 @@ block's vector-vs-threaded speedup at the same policy and env count.
 
 import json
 import os
+import time
 
 import numpy as np
 import optax
@@ -389,11 +390,59 @@ class TestVectorActorSmokeCLI:
     assert all(value == 1 for value in counts.values()), counts
 
 
+def _hold_until_probe_admitted(chunks, spool_dir, actor_id, resume_seq,
+                               hold_after, timeout_s=180.0):
+  """The learner's chunk stream, held after `hold_after` chunks until the
+  dead actor's probe incarnation has had a chunk admitted.
+
+  Left alone, the learner finishes on the survivor's stream in about a
+  second, and whether the probe is back by then is the scheduler's to
+  say. Held, it is not: the bounded queue fills, admission stops within
+  a dozen chunks, and the learner takes a further chunk only once the
+  probe's first chunk (`resume_seq`) is on disk, to make the room that
+  admits it. Everything waited on is a file the transport writes; the
+  timeout only bounds a run in which the probe never comes back."""
+  from tensor2robot_tpu.parallel import sebulba
+  probe_chunk = sebulba.chunk_path(spool_dir, actor_id, resume_seq)
+  acks_path = os.path.join(spool_dir, sebulba.ACKS_FILE)
+
+  def admitted():
+    acks = sebulba._read_json(acks_path) or {}
+    return int(acks.get(str(actor_id), 0)) > resume_seq
+
+  def wait(condition, seconds):
+    deadline = time.monotonic() + seconds
+    while not condition():
+      if time.monotonic() > deadline:
+        return False
+      time.sleep(0.01)
+    return True
+
+  for taken, chunk in enumerate(chunks):
+    yield chunk
+    if taken + 1 < hold_after or admitted():
+      continue
+    if not wait(lambda: os.path.exists(probe_chunk), timeout_s):
+      raise TimeoutError(f"actor{actor_id}'s probe never landed chunk "
+                         f"{resume_seq} in {timeout_s}s")
+    # One more chunk leaves the queue per pass (the `yield` above); give
+    # the ingest thread a moment to admit into the room before the next.
+    wait(admitted, 1.0)
+
+
 class TestActorProcessCrashRecovery:
   """ISSUE 20 satellite: a Sebulba actor PROCESS dies mid-stream; the
   learner-side watchdog flags the silent spool, the breaker walks
   quarantine -> half-open probe -> reinstate, and the learner trains
-  through on the survivor at fixed shapes with zero recompiles."""
+  through on the survivor at fixed shapes with zero recompiles.
+
+  No assertion here is a deadline: the learner is held on the
+  transport's own files until the probe is back (above), and the stall
+  deadline (5 s against a 0.05 s chunk cadence) is wide enough that a
+  live actor starved by six test workers is not taken for a dead one
+  (at 2 s one was, with the cores three times oversubscribed)."""
+
+  _DIE_AFTER = 3
 
   @pytest.fixture(scope="class")
   def crash_run(self, tmp_path_factory):
@@ -401,13 +450,30 @@ class TestActorProcessCrashRecovery:
     config = sebulba.SebulbaConfig(
         seed=11, num_actors=2, envs_per_actor=8, capacity=64,
         batch_size=8, inner_steps=1, chunks_per_megastep=2,
-        num_megasteps=10, mesh_devices=2, queue_capacity=96,
+        # 40 chunks: the manifest is the first 40 ADMITTED, and the
+        # probe's first chunk is admitted 24th at the latest (4 taken +
+        # 12 the queue holds + at most 2 a pass for actor0's chunks 0-3,
+        # should the survivor have filled the queue before actor0 began).
+        num_megasteps=20, mesh_devices=2, queue_capacity=96,
         synthetic_actors=True, actor_max_chunks=512,
-        actor_deadline_s=0.25, quarantine_s=0.5,
+        actor_deadline_s=5.0, quarantine_s=0.5,
         actor_step_sleep_s=0.05)
     workdir = str(tmp_path_factory.mktemp("sebulba_crash"))
-    return config, sebulba.run_live(config, workdir,
-                                    die_after={0: 3}, timeout_s=240.0)
+    spool_dir = os.path.join(workdir, "spool")
+    drive = sebulba.SebulbaLearner.drive
+
+    def held_drive(learner, host_chunks, **kwargs):
+      # The probe resumes at the dead incarnation's last landed chunk + 1.
+      return drive(learner, _hold_until_probe_admitted(
+          host_chunks, spool_dir, actor_id=0, resume_seq=self._DIE_AFTER,
+          hold_after=self._DIE_AFTER + 1), **kwargs)
+
+    with pytest.MonkeyPatch.context() as patch:
+      patch.setattr(sebulba.SebulbaLearner, "drive", held_drive)
+      live = sebulba.run_live(config, workdir,
+                              die_after={0: self._DIE_AFTER},
+                              timeout_s=240.0)
+    return config, live
 
   def test_two_real_processes_and_rc3_crash(self, crash_run):
     _, live = crash_run
@@ -442,11 +508,31 @@ class TestActorProcessCrashRecovery:
     config, live = crash_run
     probe = next(entry for entry in live["supervisor"]["timeline"]
                  if entry["event"] == "probe")
-    assert probe["start_seq"] >= 3  # never overwrites landed chunks
+    # never overwrites landed chunks
+    assert probe["start_seq"] >= self._DIE_AFTER
     consumed0 = [entry["seq"] for entry in live["manifest"]
                  if entry["actor"] == 0]
-    assert max(consumed0) >= 3, consumed0  # post-death chunk ingested
+    # post-death chunk ingested
+    assert max(consumed0) >= self._DIE_AFTER, consumed0
     assert any(entry["actor"] == 1 for entry in live["manifest"])
+
+  def test_overlap_instruments_and_one_merged_fleet_view(self, crash_run):
+    """What only the removed generator's CEM-actor phase looked at: the
+    acting/learning overlap instruments are present and sane (magnitudes
+    are the scheduler's), and obs/aggregate merges the registry snapshots
+    the actor processes and the learner each exported under their own
+    host label into one view."""
+    from tensor2robot_tpu.obs.aggregate import aggregate_logdir
+    _, live = crash_run
+    overlap = live["overlap"]
+    assert 0.0 < overlap["overlap_fraction"] <= 1.0, overlap
+    assert overlap["learner_stall_s"] >= 0.0
+    assert overlap["learn_busy_s"] > 0.0
+    assert overlap["queue_occupancy"]["samples"] > 0
+    fleet = aggregate_logdir(live["obs_logdir"], merged_trace=False)
+    hosts = {key.split(":")[0]
+             for key in fleet["registry"]["gauges_per_host"]}
+    assert {"actor0", "actor1", "learner"} <= hosts, hosts
 
   def test_learner_trained_through_at_fixed_shapes(self, crash_run):
     config, live = crash_run

@@ -1,0 +1,337 @@
+"""What the program owes the benchmark that is actually run, pinned on the CPU.
+
+`BENCHMARK.json` runs `benchmark/run.py`; its per-layer readers find the
+program's spans, counters and kernels BY NAME, and its drivers import the
+program's classes by path. A rename on the program's side shows as `null`
+in the ledger or as a cell that cannot start, and until this file only a
+chip run found that out. Every case here reads the benchmark's own files
+for the names (nothing is listed by hand), so a benchmark PR that reads
+one more span gets one more case.
+
+Also here, because it is the same kind of promise: the documents a
+builder is sent to name no file that is gone, and the CPU artifact
+generators and their round-stamped records can shrink and cannot grow
+(ROADMAP D2b).
+"""
+
+import ast
+import glob
+import importlib
+import inspect
+import json
+import os
+import re
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PACKAGE = os.path.join(ROOT, "tensor2robot_tpu")
+BENCHMARK = os.path.join(ROOT, "benchmark")
+
+_SPAN_NAME = re.compile(r"[a-z]+/[a-z_]+")
+
+
+def _parse(path):
+  with open(path) as f:
+    return ast.parse(f.read(), filename=path)
+
+
+def _package_files():
+  return sorted(glob.glob(os.path.join(PACKAGE, "**", "*.py"),
+                          recursive=True))
+
+
+def _called_name(call):
+  """`f` of `f(...)`, `g` of `x.g(...)`."""
+  func = call.func
+  return func.attr if isinstance(func, ast.Attribute) else getattr(
+      func, "id", None)
+
+
+# --- 1. span names and attrs -------------------------------------------------
+
+
+def _span_names_the_benchmark_reads():
+  """Every string constant of the span readers that is a span name, whole
+  (a docstring that mentions one is a longer string and is not taken)."""
+  files = sorted(glob.glob(os.path.join(BENCHMARK, "layer_metrics", "*.py")))
+  files.append(os.path.join(BENCHMARK, "trace", "program_spans.py"))
+  names = set()
+  for path in files:
+    for node in ast.walk(_parse(path)):
+      if (isinstance(node, ast.Constant) and isinstance(node.value, str)
+          and _SPAN_NAME.fullmatch(node.value)):
+        names.add(node.value)
+  return sorted(names)
+
+
+def _attrs_read_off(span_name):
+  """Keys the readers subscript or test on a span dict `s`, in the reader
+  files that name `span_name` (or its constant in program_spans)."""
+  constants = {  # FLUSH = "serve/flush" and the like
+      node.value.value: node.targets[0].id
+      for node in _parse(
+          os.path.join(BENCHMARK, "trace", "program_spans.py")).body
+      if isinstance(node, ast.Assign) and isinstance(node.value, ast.Constant)}
+  span_keys = {"name", "ts_s", "dur_s"}
+  attrs = set()
+  for path in glob.glob(os.path.join(BENCHMARK, "layer_metrics", "*.py")):
+    with open(path) as f:
+      source = f.read()
+    if (span_name not in source
+        and f"program_spans.{constants.get(span_name)}" not in source):
+      continue
+    for node in ast.walk(ast.parse(source)):
+      key = None
+      if (isinstance(node, ast.Subscript)
+          and isinstance(node.value, ast.Name) and node.value.id == "s"
+          and isinstance(node.slice, ast.Constant)):
+        key = node.slice.value
+      elif (isinstance(node, ast.Compare)
+            and isinstance(node.left, ast.Constant)
+            and isinstance(node.ops[0], ast.In)
+            and isinstance(node.comparators[0], ast.Name)
+            and node.comparators[0].id == "s"):
+        key = node.left.value
+      if isinstance(key, str) and key not in span_keys:
+        attrs.add(key)
+  return attrs
+
+
+def _span_call_sites(span_name):
+  """`*.span("<name>", **attrs)` calls under tensor2robot_tpu/ whose first
+  argument is that literal: [(path, lineno, {keyword names})]."""
+  sites = []
+  for path in _package_files():
+    with open(path) as f:
+      source = f.read()
+    if span_name not in source:
+      continue
+    for node in ast.walk(ast.parse(source)):
+      if not (isinstance(node, ast.Call) and node.args):
+        continue
+      first = node.args[0]
+      if (_called_name(node) == "span" and isinstance(first, ast.Constant)
+          and first.value == span_name):
+        sites.append((os.path.relpath(path, ROOT), node.lineno,
+                      {kw.arg for kw in node.keywords}))
+  return sites
+
+
+_SPANS = _span_names_the_benchmark_reads()
+
+
+def test_the_readers_name_spans_at_all():
+  # Guards the parametrisation below: an empty list would pass in silence.
+  assert "serve/flush" in _SPANS and "train/dispatch" in _SPANS, _SPANS
+
+
+@pytest.mark.parametrize("span_name", _SPANS)
+def test_span_the_benchmark_reads_is_opened_by_the_program(span_name):
+  sites = _span_call_sites(span_name)
+  assert sites, (
+      f"benchmark/ reads the span {span_name!r} and no span(...) call under "
+      "tensor2robot_tpu/ opens it by that literal: its per-layer metrics "
+      "would read null in the ledger")
+  wanted = _attrs_read_off(span_name)
+  if span_name == "serve/flush":
+    assert {"batch", "queue_wait_ms_sum", "in_flight"} <= wanted, wanted
+  for path, lineno, keywords in sites:
+    assert wanted <= keywords, (
+        f"{path}:{lineno} opens {span_name!r} without the attrs "
+        f"{sorted(wanted - keywords)} that benchmark/layer_metrics reads")
+
+
+# --- 2. kernel names -----------------------------------------------------------
+
+
+def test_kernel_names_are_the_pallas_calls_names():
+  """`mla_attention_roofline.train` finds the kernel's device events by
+  `ops/flash_attention.KERNEL_NAMES`; a program named otherwise, or a
+  fourth one the tuple lacks, makes it read None."""
+  # `ops/__init__.py` re-exports the function under the module's name.
+  flash_attention = importlib.import_module(
+      "tensor2robot_tpu.ops.flash_attention")
+  tree = _parse(os.path.join(PACKAGE, "ops", "flash_attention.py"))
+  named = []
+  for node in ast.walk(tree):
+    if not (isinstance(node, ast.Call)
+            and _called_name(node) == "pallas_call"):
+      continue
+    (name,) = [kw.value for kw in node.keywords if kw.arg == "name"]
+    if isinstance(name, ast.Constant):
+      named.append(name.value)
+    else:  # KERNEL_NAMES[i]
+      assert (isinstance(name, ast.Subscript)
+              and name.value.id == "KERNEL_NAMES"), ast.dump(name)
+      named.append(flash_attention.KERNEL_NAMES[name.slice.value])
+  assert sorted(named) == sorted(flash_attention.KERNEL_NAMES)
+  assert len(set(named)) == len(named)
+
+
+# --- 3. what the drivers and configurations import -----------------------------
+
+
+def _program_imports(path):
+  """(module, attribute or None) for every import of tensor2robot_tpu.* in
+  the file, function-local ones included."""
+  found = []
+  for node in ast.walk(_parse(path)):
+    if (isinstance(node, ast.ImportFrom) and node.module
+        and node.module.split(".")[0] == "tensor2robot_tpu"):
+      found.extend((node.module, alias.name) for alias in node.names)
+    elif isinstance(node, ast.Import):
+      found.extend((alias.name, None) for alias in node.names
+                   if alias.name.split(".")[0] == "tensor2robot_tpu")
+  return found
+
+
+def _resolve(module, attribute):
+  loaded = importlib.import_module(module)
+  if attribute is None or hasattr(loaded, attribute):
+    return
+  importlib.import_module(f"{module}.{attribute}")  # `from pkg import mod`
+
+
+@pytest.mark.parametrize("driver", sorted(
+    os.path.basename(p)[:-3]
+    for p in glob.glob(os.path.join(BENCHMARK, "drivers", "*.py"))
+    if not os.path.basename(p).startswith("_")))
+def test_driver_imports_resolve(driver):
+  imports = _program_imports(
+      os.path.join(BENCHMARK, "drivers", f"{driver}.py"))
+  assert imports, f"{driver} imports nothing of the program?"
+  for module, attribute in imports:
+    try:
+      _resolve(module, attribute)
+    except (ImportError, AttributeError) as e:
+      pytest.fail(f"benchmark/drivers/{driver}.py imports {attribute!r} "
+                  f"from {module}: {type(e).__name__}: {e}")
+
+
+@pytest.mark.parametrize("config", sorted(
+    os.path.basename(p)[:-5]
+    for p in glob.glob(os.path.join(BENCHMARK, "configs", "*.json"))))
+def test_configuration_names_a_model_the_program_has(config):
+  """`harness.build_model`: the class and the optimizer factory resolve
+  and take the arguments the configuration's file gives them."""
+  with open(os.path.join(BENCHMARK, "configs", f"{config}.json")) as f:
+    spec = json.load(f)
+  model, opt = spec["model"], spec["optimizer"]
+  cls = getattr(importlib.import_module(model["module"]), model["class"])
+  factory = getattr(importlib.import_module(opt["factory"][0]),
+                    opt["factory"][1])
+  inspect.signature(factory).bind(**opt["kwargs"])
+  inspect.signature(cls).bind(optimizer_fn=None, **model["kwargs"])
+
+
+# --- 4. documents name files that exist ----------------------------------------
+
+_IGNORED_DIRS = {".git", "build", "dist", "chiprun_out", "benchmark_out",
+                 "chip_smoke_out", ".jax_compile_cache", "__pycache__",
+                 ".pytest_cache", ".hypothesis"}
+_PATH = re.compile(r"`([\w./-]+\.(?:py|jsonl|json|md|cfg))(?::[\d,–-]+)?`")
+# Names of files that are not the tree's: a model's published config, the
+# driver's record outside the checkout, what a run writes into its logdir
+# or spool, and upstream tensor2robot's files in README's Layout table.
+_NOT_OURS = {
+    "config.json", "TESTS_LAST_RUN.json",
+    "metrics.jsonl", "fleet_trace.json", "acks.json", "heartbeat.json",
+    "utils/tensorspec_utils.py", "utils/train_eval.py",
+}
+# Sections that name files on purpose that are not in the tree: what an
+# earlier PR took away (PERF.md's Findings say "gone" of each) and what a
+# later one would add.
+_SKIPPED_SECTIONS = {
+    "PERF.md": ("## 6. Findings", "## 7. Open questions"),
+    "README.md": (),
+    ".claude/skills/verify/SKILL.md": (),
+    "docs/DESIGN.md": (),
+}
+
+
+def _tree_files():
+  paths = []
+  for directory, subdirs, files in os.walk(ROOT):
+    subdirs[:] = [d for d in subdirs if d not in _IGNORED_DIRS]
+    paths.extend(os.path.relpath(os.path.join(directory, name), ROOT)
+                 for name in files)
+  return paths
+
+
+def _sections(text, skipped):
+  """The document without the sections (heading to the next heading of the
+  same or a higher level) whose heading starts with one of `skipped`."""
+  kept, skipping = [], None
+  for line in text.splitlines():
+    if line.startswith("#"):
+      level = len(line) - len(line.lstrip("#"))
+      if skipping is not None and level <= skipping:
+        skipping = None
+      if skipping is None and line.startswith(tuple(skipped)):
+        skipping = level
+    if skipping is None:
+      kept.append(line)
+  return "\n".join(kept)
+
+
+@pytest.mark.parametrize("document", sorted(_SKIPPED_SECTIONS))
+def test_document_names_only_files_that_exist(document):
+  with open(os.path.join(ROOT, document)) as f:
+    text = _sections(f.read(), _SKIPPED_SECTIONS[document])
+  files = ["/" + path for path in _tree_files()]
+  missing = []
+  for token in sorted({m.group(1) for m in _PATH.finditer(text)}):
+    name = token.lstrip("./")
+    if token.startswith("/") or name in _NOT_OURS:
+      continue
+    # `serving/policy.py` names tensor2robot_tpu/serving/policy.py, a bare
+    # `cem.py` any file of that name: a path is there if some file of the
+    # tree ends with it at a directory boundary.
+    if not any(path.endswith("/" + name) for path in files):
+      missing.append(token)
+  assert not missing, (
+      f"{document} names files that are not in the tree: {missing}")
+
+
+# --- 5. the generators and their records can shrink, not grow (ROADMAP D2b) ----
+
+_D2B_GENERATORS = {
+    "obs/obs_bench.py",
+    "parallel/multihost_bench.py",
+    "replay/actor_bench.py",
+    "replay/anakin_bench.py",
+    "replay/anakin_multichip_bench.py",
+    "replay/learner_bench.py",
+    "replay/precision_bench.py",
+    "replay/tpquant_bench.py",
+    "serving/fault_bench.py",
+    "serving/fleet_bench.py",
+}
+_D2B_RECORDS = {
+    "FAULTS_r15.json", "FLEETOBS_r13.json", "FLEET_r11.json",
+    "MULTICHIP_r06.json", "MULTIHOST_r19.json", "OBS_r13.json",
+    "PRECISION_r14.json", "REPLAY_SMOKE_r07.json", "REPLAY_SMOKE_r08.json",
+    "REPLAY_SMOKE_r09.json", "REPLAY_SMOKE_r10.json", "TPQUANT_r17.json",
+}
+
+
+def _generators_in_the_tree():
+  return {os.path.relpath(path, PACKAGE) for path in _package_files()
+          if path.endswith("_bench.py")}
+
+
+def _records_at_the_root():
+  return {name for name in os.listdir(ROOT)
+          if re.fullmatch(r".*_r\d\d\w*\.jsonl?", name)}
+
+
+@pytest.mark.parametrize("found, allowed", [
+    pytest.param(_generators_in_the_tree, _D2B_GENERATORS, id="generators"),
+    pytest.param(_records_at_the_root, _D2B_RECORDS, id="records"),
+])
+def test_cpu_artifact_set_only_shrinks(found, allowed):
+  """A new measurement is a cell of BENCHMARK.json and a line of the
+  ledger, not another `*_bench.py` with a round-stamped record beside it."""
+  extra = found() - allowed
+  assert not extra, f"not in ROADMAP D2b's lists: {sorted(extra)}"

@@ -295,6 +295,26 @@ def device_smoke_results(tmp_path_factory):
   return results, logdir
 
 
+class TestSpread:
+  """`learner_bench._spread`: the {median, min, max, trials} shape the
+  smoke's learner-throughput block (below) and the actor block carry."""
+
+  @pytest.mark.parametrize("values, digits, expected", [
+      pytest.param([3.0, 1.0, 2.0], 3,
+                   {"median": 2.0, "min": 1.0, "max": 3.0, "trials": 3},
+                   id="shape_and_values"),
+      pytest.param([4.5], 3,
+                   {"median": 4.5, "min": 4.5, "max": 4.5, "trials": 1},
+                   id="single_value"),
+      pytest.param([1.23456], 2,
+                   {"median": 1.23, "min": 1.23, "max": 1.23, "trials": 1},
+                   id="rounding"),
+  ])
+  def test_spread(self, values, digits, expected):
+    from tensor2robot_tpu.replay.learner_bench import _spread
+    assert _spread(values, digits=digits) == expected
+
+
 class TestDeviceResidentSmoke:
   """ISSUE 4 acceptance: the fused learner holds PR 2's >= 30% eval TD
   bar, the ledger shows exactly ONE megastep executable, and the

@@ -203,7 +203,10 @@ class TestFlywheelIngest(unittest.TestCase):
     self.assertEqual(snap["transitions_ingested"], 0)
     dumps = [name for name in os.listdir(logdir)
              if "flywheel_ingest_rejected" in name]
-    self.assertGreaterEqual(len(dumps), 1)
+    # Refusals raise AND count AND dump, one file each: the dump names
+    # carry a per-process sequence, so back-to-back refusals cannot
+    # coalesce into one file.
+    self.assertEqual(len(dumps), len(cases), dumps)
 
   def test_mark_cutover_rebases_mix_fraction(self):
     queue = TransitionQueue(64)
@@ -387,9 +390,7 @@ _SMALL_HOST = (os.cpu_count() or 1) < 4
 
 @unittest.skipIf(_SMALL_HOST, "closed-loop lane wants >= 4 cpus")
 class TestFlywheelClosedLoop(unittest.TestCase):
-  """The reduced lane of the FLYWHEEL_r18 closed loop in tier-1: the
-  committed artifact's smoke protocol proves the full bars at
-  generation time; this trimmed run re-proves on every PR that the
+  """The closed loop in tier-1, trimmed: re-proves on every PR that the
   LOOP still closes — collectors retired at cutover, a live promote
   cycle completing mid-run, every ingested transition traceable to
   its serving request, counts reconciling against the router, and
@@ -417,6 +418,44 @@ class TestFlywheelClosedLoop(unittest.TestCase):
     self.assertTrue(result["ledger"]["exactly_once"],
                     result["ledger"])
     self.assertGreater(result["provenance"].get("served", 0), 0)
+
+
+class TestFlywheelStaleParamsControl(unittest.TestCase):
+  """The interlock's positive control, through the LOOP (the rule's own
+  test above feeds a monitor by hand): with the export path severed the
+  fleet serves the warm-start params while the learner advances, and the
+  staleness ceiling must breach, with its ``health_breach`` dump. A
+  guard that cannot see its own promote path stall is decoration. The
+  lag counts learner steps, not seconds, so no host is too small."""
+
+  def test_severed_exports_breach_the_staleness_ceiling(self):
+    import json
+
+    from tensor2robot_tpu.flywheel.loop import (FlywheelConfig,
+                                                FlywheelLoop)
+    healthy = FlywheelConfig(warm_steps=16, fleet_steps=30,
+                             export_every=15, seed=0)
+    # The healthy run's ceiling, resolved the same way: the control and
+    # the healthy run disagree ONLY on whether exports flow.
+    config = FlywheelConfig(
+        warm_steps=16, fleet_steps=60, export_every=15, seed=0,
+        promotes=False,
+        staleness_ceiling=healthy.resolved_staleness_ceiling())
+    result = FlywheelLoop(config).run()
+    self.assertIsNone(result["client"]["error"])
+    self.assertEqual(result["promotes"]["completed"], 0)
+    self.assertIn("flywheel_staleness_ceiling",
+                  result["health"]["breaches_per_rule"], result["health"])
+    self.assertFalse(result["health"]["ok"])
+    self.assertGreater(result["ingest"]["max_staleness_lag"],
+                       result["config"]["staleness_ceiling"])
+    dump_dir = os.path.join(result["workdir"], "flightrec")
+    rules = []
+    for name in sorted(os.listdir(dump_dir)):
+      if name.startswith("flightrec-") and "health_breach" in name:
+        with open(os.path.join(dump_dir, name)) as f:
+          rules.append(json.load(f)["trigger"]["rule"])
+    self.assertIn("flywheel_staleness_ceiling", rules)
 
 
 if __name__ == "__main__":

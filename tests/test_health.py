@@ -12,6 +12,8 @@ Covers the three tentpole layers plus the numeric fault kinds:
 - The injected-NaN-through-anakin detection path: a REAL fused loop,
   params poisoned at the seam, the in-program summary catches it, the
   loop halts, the dump carries the step.
+- The value_scale-through-the-host-loop path: finite, plausible, 50x
+  wrong targets; the EWMA drift rules name it on the very next step.
 - Healthy-control zero-false-positive runs (fused loop AND fleet).
 - The fleet Q-drift guard against a LIVE 2-device router: a
   corrupt_served_variables replica detected and named; the aggregate
@@ -351,6 +353,60 @@ class TestAnakinNaNDetection(unittest.TestCase):
     self.assertEqual(result["compile_counts"]["anakin_step"], 1)
 
 
+class TestValueScaleHostDetection(unittest.TestCase):
+  """A finite corruption through the HOST learner path: the Bellman
+  targets scaled 50x at the learner seam. Nothing is non-finite, so no
+  hard rule can see it; the drift rules must, within the steps that
+  follow, and the breach must be flight-recorded under its rule's name."""
+
+  def test_scaled_targets_trip_a_drift_rule_and_are_recorded(self):
+    import optax
+
+    from tensor2robot_tpu.replay.loop import (ReplayLoopConfig,
+                                              ReplayTrainLoop)
+    from tensor2robot_tpu.replay.smoke import TinyQCriticModel
+    inject_at = 15
+    logdir = tempfile.mkdtemp(prefix="health_scale_")
+    plan = faults_lib.FaultPlan([
+        faults_lib.FaultSpec(kind="value_scale", point="learner_step",
+                             site="learner", at=inject_at, scale=50.0)])
+    config = ReplayLoopConfig(
+        seed=0, eval_every=15, mesh_dp=1, mesh_tp=1, health=True,
+        health_halt=False, anakin=False, min_fill=96)
+    model = TinyQCriticModel(
+        image_size=config.image_size, action_size=config.action_size,
+        optimizer_fn=lambda: optax.adam(config.learning_rate))
+    # Every trigger dumps: at the default rate limit a queue-overflow dump
+    # a moment earlier would swallow this one (it would ride the ring only).
+    recorder = FlightRecorder(dump_dir=logdir, min_dump_interval_s=0.0)
+    loop = ReplayTrainLoop(config, logdir, model=model, fault_plan=plan,
+                           flight_recorder=recorder)
+    snapshot = loop.run(30)["health"]
+    self.assertEqual(plan.fired_counts().get("value_scale"), 1,
+                     plan.snapshot())
+    drift_rules = {"td_drift", "q_drift", "grad_norm_drift"}
+    self.assertTrue(drift_rules & set(snapshot["breaches_per_rule"]),
+                    snapshot["breaches_per_rule"])
+    # The fault fires at the END of step inject_at and corrupts the next
+    # step's targets: that step is where the drift rules must speak, and
+    # no rule may have spoken before it.
+    detected = sorted({b["step"] for b in snapshot["breaches"]})
+    self.assertGreaterEqual(detected[0], inject_at + 1)
+    self.assertLessEqual(detected[0], inject_at + 3)
+    triggers = []
+    for name in sorted(os.listdir(logdir)):
+      if name.startswith("flightrec-") and "health_breach" in name:
+        with open(os.path.join(logdir, name)) as f:
+          triggers.append(json.load(f)["trigger"])
+    self.assertTrue(triggers, os.listdir(logdir))
+    for trigger in triggers:
+      for field in health_lib.BREACH_FIELDS:
+        self.assertIn(field, trigger)
+    self.assertTrue(
+        any(t["step"] in detected and t["rule"] in drift_rules
+            for t in triggers), triggers)
+
+
 class TestQDriftRouterLive(unittest.TestCase):
   """The fleet Q-drift guard against a LIVE 2-device router."""
 
@@ -373,7 +429,8 @@ class TestQDriftRouterLive(unittest.TestCase):
                                site=str(devices[1]), at=0,
                                scale=16.0)], recorder=recorder)
     predictor = TinyQPredictor(seed=0)
-    stats = ServingStats(registry=MetricRegistry())
+    registry = MetricRegistry()
+    stats = ServingStats(registry=registry)
     router = FleetRouter(predictor, devices=devices,
                          ladder_sizes=(1, 2), seed=0, stats=stats,
                          fault_plan=plan, flight_recorder=recorder)
@@ -385,6 +442,7 @@ class TestQDriftRouterLive(unittest.TestCase):
       for future in futures:
         future.result(60)
       snapshot = router.health_snapshot()
+    registry.export_snapshot(os.path.join(dump_dir, "registry.json"))
     return snapshot, devices, dump_dir, plan, stats
 
   def test_corrupted_replica_detected_named_and_dumped(self):
@@ -396,9 +454,11 @@ class TestQDriftRouterLive(unittest.TestCase):
     self.assertEqual(snapshot["health"], "degraded")
     self.assertIn("replica_divergent",
                   [entry["event"] for entry in snapshot["timeline"]])
+    # One divergent TRANSITION fires one dump: the snapshot's single
+    # check_q_drift pass, no more, no less.
     dumps = [name for name in os.listdir(dump_dir)
              if "replica_divergent" in name]
-    self.assertTrue(dumps)
+    self.assertEqual(len(dumps), 1, dumps)
     # The injected fault's own dump carries the batch's request ids
     # (it fired inside the dispatch span) — the correlation contract.
     fired = plan.snapshot()["fired"]
@@ -407,6 +467,23 @@ class TestQDriftRouterLive(unittest.TestCase):
                         for record in fired), fired)
     # Per-replica sketches exported to the registry ride the snapshot.
     self.assertIn("q_sketches", stats.snapshot())
+
+  def test_aggregate_reaches_the_verdict_from_the_exported_registry(self):
+    """The cross-process rollup over what a LIVE router exported (the
+    rollup's own tests feed it hand-made reservoirs): divergent naming
+    the corrupted replica, ok on the healthy control."""
+    from tensor2robot_tpu.obs import aggregate as aggregate_lib
+    _, devices, dump_dir, _, _ = self._run_window(corrupt=True)
+    health = aggregate_lib.aggregate_logdir(
+        dump_dir, merged_trace=False)["health"]
+    self.assertEqual(health["verdict"], "divergent", health)
+    self.assertTrue(any(name.endswith("/" + str(devices[1]))
+                        for name in health["q_drift"]["divergent"]),
+                    health["q_drift"])
+    _, _, dump_dir, _, _ = self._run_window(corrupt=False)
+    health = aggregate_lib.aggregate_logdir(
+        dump_dir, merged_trace=False)["health"]
+    self.assertEqual(health["verdict"], "ok", health)
 
   def test_healthy_fleet_reads_ok_with_margin(self):
     snapshot, _, _, _, _ = self._run_window(corrupt=False)
@@ -468,33 +545,6 @@ class TestAggregateHealthRollup(unittest.TestCase):
     fleet = aggregate_lib.aggregate_logdir(logdir, merged_trace=False)
     self.assertEqual(fleet["health"]["verdict"], "breaching")
     self.assertEqual(fleet["health"]["breach_counters"]["td_drift"], 2)
-
-
-class TestCommittedHealthArtifact(unittest.TestCase):
-  """HEALTH_r16.json: the committed artifact meets its own bars."""
-
-  def test_committed_artifact_meets_bars(self):
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "HEALTH_r16.json")
-    self.assertTrue(os.path.exists(path),
-                    "HEALTH_r16.json not committed")
-    with open(path) as f:
-      artifact = json.loads(f.read().strip())
-    self.assertEqual(artifact["round"], 16)
-    self.assertTrue(artifact["virtual_mesh"])
-    self.assertTrue(artifact["ledger_stability"]["ledger_identical"])
-    self.assertLessEqual(
-        artifact["ledger_stability"]["host_blocked_fraction"],
-        artifact["ledger_stability"]["host_blocked_bar"])
-    for kind in ("nan_grads", "value_scale",
-                 "corrupt_served_variables"):
-      self.assertTrue(artifact["detection"][kind]["ok"], kind)
-    self.assertEqual(
-        artifact["healthy_control"]["anakin"]["breach_count"], 0)
-    self.assertEqual(
-        artifact["healthy_control"]["fleet"]["verdict"], "ok")
-    self.assertTrue(artifact["health_breach_detection_ok"])
-    self.assertTrue(artifact["fleet_q_drift_ok"])
 
 
 if __name__ == "__main__":

@@ -10,8 +10,8 @@ host extend, one shared exactly-once executable, ordering guards), and
 — marked slow — the live 2-process-actor run whose learner params must
 be BIT-identical to the serialized single-process oracle replaying the
 recorded manifest. The actor-crash quarantine protocol's bounded test
-lives in tests/test_actor.py (satellite 4); the CEM-actor overlap
-protocol runs at artifact generation (bin/bench_sebulba --smoke).
+lives in tests/test_actor.py (satellite 4), with the overlap
+instruments and the merged fleet view of that same live run.
 """
 
 import json

@@ -1166,11 +1166,14 @@ class TestFleetSmokeCLI:
   whole serving path chiplessly on every PR and must demonstrate the
   batching amortization the subsystem exists for."""
 
+  _FRAMES = 80
+
   def _run_smoke(self):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     res = subprocess.run(
         [sys.executable, "-m", "tensor2robot_tpu.bin.bench_serving",
-         "--fleet", "--smoke", "--clients", "16", "--frames", "80"],
+         "--fleet", "--smoke", "--clients", "1,16",
+         "--frames", str(self._FRAMES)],
         capture_output=True, text=True, timeout=300, env=env, cwd=ROOT)
     assert res.returncode == 0, res.stderr[-2000:]
     lines = [l for l in res.stdout.strip().splitlines() if l.strip()]
@@ -1186,36 +1189,31 @@ class TestFleetSmokeCLI:
     # Exactly one compiled executable per ladder bucket over the whole
     # run — warmup, partial deadline flushes, and full batches included.
     assert obj["compile_counts"] == {str(b): 1 for b in (1, 2, 4, 8, 16)}
-    (point,) = obj["fleet_sweep"]
-    assert point["clients"] == 16
+    single, point = obj["fleet_sweep"]
+    assert (single["clients"], point["clients"]) == (1, 16)
     # The artifact schema's fleet fields are present and sane.
     assert point["latency_p50_ms"] > 0
     assert point["latency_p99_ms"] >= point["latency_p50_ms"]
     assert 0 < point["batch_occupancy"] <= 1
     assert obj["single_client_closed_loop_hz"] > 0
 
-    def amortization(o):
-      return (o["fleet_sweep"][0]["aggregate_images_per_sec"]
-              / o["single_client_closed_loop_hz"])
+    # Batching amortization, from the run's own counts (ServingStats):
+    # what micro-batching amortizes is the per-flush dispatch, so 16
+    # concurrent closed-loop clients must be answered with at most a third
+    # of the flushes per request that one client costs (one each), in
+    # buckets that are mostly real rows. A count cannot depend on who
+    # shares the cores; the ratio of two host rates this asserted before
+    # did (the rates are still printed, and speed is the serving cell's to
+    # say: `qtopt_serve_closed64`).
+    def answered(p):
+      return round(p["flushes"] * p["mean_batch_size"])
 
-    # Batching amortization: 16 concurrent closed-loop clients clear
-    # >= 3x the single-client closed-loop rate (acceptance bar; the
-    # tiny smoke model makes per-flush dispatch, not conv math, the
-    # dominant cost — the regime batching amortizes). The bar is GATED
-    # on os.cpu_count() >= 4 (ISSUE 6 de-flake satellite, per the
-    # ROADMAP maintenance note): on a 2-core box the 16 client threads
-    # plus the server fight for two cores and the ratio sits at the
-    # noise floor — verified flaky at a clean HEAD — so below 4 cores
-    # the structural contract above (schema, one-executable-per-bucket
-    # ledger, sane latencies) is the tier-1 claim and the quantitative
-    # bar is carried by the committed SERVING artifact's quiet run.
-    if (os.cpu_count() or 1) < 4:
-      return
-    # Medians over 3 in-process trials already damp contention; one
-    # full re-run is allowed before declaring the property broken on a
-    # shared CI box.
-    ratio = amortization(obj)
-    if ratio < 3.0:
-      retry = self._run_smoke()
-      ratio = max(ratio, amortization(retry))
-    assert ratio >= 3.0, json.dumps(obj, indent=2)
+    for p in (single, point):
+      # Every request answered: the priming round + frames x repeats.
+      assert answered(p) == p["clients"] * (
+          1 + self._FRAMES * obj["repeats"]), p
+    assert single["flushes"] == answered(single), single  # one each
+    assert single["batch_occupancy"] == 1.0
+    flushes_per_request = point["flushes"] / answered(point)
+    assert flushes_per_request <= 1 / 3, json.dumps(obj, indent=2)
+    assert point["batch_occupancy"] >= 0.5, json.dumps(obj, indent=2)

@@ -196,7 +196,6 @@ class TestCompileCache:
 
   @pytest.mark.parametrize("relpath,function", [
       ("chip_smoke.py", "main"),
-      ("bench.py", "main"),
       ("tensor2robot_tpu/bin/run_t2r_trainer.py", "main"),
       ("tensor2robot_tpu/bin/run_qtopt_replay.py", "main"),
       ("tensor2robot_tpu/bin/bench_serving.py", "main"),
