@@ -11,7 +11,15 @@ Artifact layout (one versioned dir):
                        serve(variables, *features_in_key_order) -> {name: out}
     variables.npz      flat npz of the variables dict (export/variables_io.py;
                        numpy is the only robot-side dependency)
+    encode_fn.bin, q_from_code_fn.bin
+                       only where the model has a factored CEM pair
+                       (CriticModel.factored_cem_fns):
+                       encode(variables, image) -> code and
+                       q_from_code(variables, code, *other features)
+                       -> {name: out}, so a CEM policy encodes each
+                       frame once (predictors' factored_device_fns)
     t2r_assets.json    feature specs + feature key order + metadata
+                       (+ "factored_cem": the pair's two files)
     t2r_assets.pb      proto twin of the JSON assets (proto/t2r.proto)
 
 Batch dim is exported symbolically ("b") so serving batch size is free —
@@ -36,6 +44,8 @@ from tensor2robot_tpu.export.abstract_export_generator import (
 )
 
 SERVING_FN_NAME = "serving_fn.bin"
+ENCODE_FN_NAME = "encode_fn.bin"
+Q_FROM_CODE_FN_NAME = "q_from_code_fn.bin"
 VARIABLES_DIR = "variables"  # legacy orbax layout, still readable
 VARIABLES_NPZ = "variables.npz"
 
@@ -81,13 +91,16 @@ class NativeExportGenerator(AbstractExportGenerator):
     with dispatch.xla_only():
       # Multi-platform artifacts lower every branch for every platform;
       # compiled Pallas calls cannot lower for the CPU target.
-      exported = jax.export.export(
-          jax.jit(serve), platforms=self._platforms)(var_shapes, *arg_shapes)
+      export = lambda fn, *args: jax.export.export(
+          jax.jit(fn), platforms=self._platforms)(var_shapes, *args)
+      calls = {SERVING_FN_NAME: export(serve, *arg_shapes),
+               **self._export_factored_pair(export, keys, arg_shapes)}
 
     tmp_dir, final_dir = export_utils.versioned_export_dir(self.export_root)
     os.makedirs(tmp_dir, exist_ok=True)
-    with open(os.path.join(tmp_dir, SERVING_FN_NAME), "wb") as f:
-      f.write(exported.serialize())
+    for name, exported in calls.items():
+      with open(os.path.join(tmp_dir, name), "wb") as f:
+        f.write(exported.serialize())
     # Variables as one flat npz (variables_io): numpy-only on the robot
     # side, and no checkpoint-library global state in this (possibly
     # worker) thread while the trainer checkpoints concurrently.
@@ -99,6 +112,35 @@ class NativeExportGenerator(AbstractExportGenerator):
             "format": "jax_export_stablehlo",
             "feature_keys": keys,
             "platforms": list(self._platforms),
+            **({"factored_cem": {"encode_fn": ENCODE_FN_NAME,
+                                 "q_from_code_fn": Q_FROM_CODE_FN_NAME}}
+               if ENCODE_FN_NAME in calls else {}),
         },
         global_step=global_step)
     return export_utils.publish(tmp_dir, final_dir)
+
+  def _export_factored_pair(self, export, keys, arg_shapes):
+    """{file name: Exported} of the model's factored CEM pair, empty
+    where it has none over the `image` feature. The code's shape and
+    dtype are whatever `encode` gives at the serving signature;
+    `q_from_code` takes the code in the image's place and every other
+    feature as `serve` does."""
+    fns = self._model.factored_cem_fns()
+    if fns is None or "image" not in keys:
+      return {}
+    encode_fn, q_from_code_fn = fns
+    image = keys.index("image")
+
+    def encode(variables, image):
+      return encode_fn(variables, {"image": image})
+
+    def q_from_code(variables, *feature_arrays):
+      return export_utils.normalize_serving_outputs(
+          q_from_code_fn(variables, dict(zip(keys, feature_arrays))))
+
+    encode_call = export(encode, arg_shapes[image])
+    (code,) = encode_call.out_avals
+    code_args = list(arg_shapes)
+    code_args[image] = jax.ShapeDtypeStruct(code.shape, code.dtype)
+    return {ENCODE_FN_NAME: encode_call,
+            Q_FROM_CODE_FN_NAME: export(q_from_code, *code_args)}

@@ -242,6 +242,12 @@ class AbstractT2RModel(abc.ABC):
 
   # --- serving ------------------------------------------------------------
 
+  def factored_cem_fns(self):
+    """(encode_fn, q_from_code_fn) where a CEM search can encode each
+    state once and score candidate actions over the code, else None:
+    see `CriticModel.factored_cem_fns`, the one implementation."""
+    return None
+
   def predict_fn(
       self,
       variables: Variables,

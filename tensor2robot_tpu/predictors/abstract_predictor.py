@@ -144,6 +144,20 @@ class AbstractPredictor(abc.ABC):
     raise NotImplementedError(
         f"{type(self).__name__} has no device-resident serving path.")
 
+  def factored_device_fns(self):
+    """The model's factored scoring pair beside `device_fn`, or None.
+
+    ``(encode_fn, q_from_code_fn)``, both ``(variables, features)``
+    like `device_fn`'s fn and over the same variables:
+    ``encode_fn(variables, {"image"})`` gives the code of each frame,
+    ``q_from_code_fn(variables, {"image": code, "action"})`` the
+    outputs dict (`CriticModel.factored_cem_fns`). A CEM search then
+    encodes each frame once and scores candidate actions over the code
+    (`cem.make_cem_states_and_score`). None where the model, or the
+    artifact, has no such pair: callers score through `device_fn`.
+    """
+    return None
+
   def close(self) -> None:
     """Releases resources."""
 

@@ -175,6 +175,10 @@ class CheckpointPredictor(AbstractPredictor):
 
     return fn, self._variables
 
+  def factored_device_fns(self):
+    """See AbstractPredictor.factored_device_fns: the model's own pair."""
+    return self._model.factored_cem_fns()
+
   def get_feature_specification(self) -> ts.TensorSpecStruct:
     return ts.flatten_spec_structure(
         self._model.preprocessor.get_out_feature_specification(

@@ -40,7 +40,7 @@ def _row_batched(call):
 
   @jax.custom_batching.custom_vmap
   def serve(variables, *arrays):
-    return dict(call(variables, *arrays))
+    return call(variables, *arrays)
 
   @serve.def_vmap
   def _fold_into_rows(axis_size, in_batched, variables, *arrays):
@@ -68,6 +68,7 @@ class ExportedModelPredictor(AbstractPredictor):
     self._version = -1
     self._call = None
     self._device_serve = None
+    self._device_pair = None
     self._variables = None
     self._feature_spec: Optional[ts.TensorSpecStruct] = None
     self._feature_keys = None
@@ -83,8 +84,12 @@ class ExportedModelPredictor(AbstractPredictor):
           f"a native export under {self._export_root}", timeout_s,
           raise_on_timeout)
     export_dir = os.path.join(self._export_root, str(newest))
-    with open(os.path.join(export_dir, SERVING_FN_NAME), "rb") as f:
-      exported = jax.export.deserialize(bytearray(f.read()))
+
+    def load(name):
+      with open(os.path.join(export_dir, name), "rb") as f:
+        return jax.export.deserialize(bytearray(f.read()))
+
+    exported = load(SERVING_FN_NAME)
     npz_path = os.path.join(export_dir, VARIABLES_NPZ)
     if os.path.exists(npz_path):
       variables = variables_io.load_variables(npz_path)
@@ -94,6 +99,12 @@ class ExportedModelPredictor(AbstractPredictor):
           os.path.abspath(os.path.join(export_dir, VARIABLES_DIR)))
     feature_spec, _, extra = export_utils.read_spec_assets(export_dir)
     self._device_serve = _row_batched(exported.call)
+    # An artifact of a model without the pair, or from before it was
+    # exported, records none and serves through `serving_fn.bin` alone.
+    pair = extra.get("factored_cem")
+    self._device_pair = pair and tuple(
+        _row_batched(load(pair[name]).call)
+        for name in ("encode_fn", "q_from_code_fn"))
     self._call = jax.jit(exported.call)
     self._variables = jax.tree_util.tree_map(jnp.asarray, variables)
     self._feature_spec = feature_spec
@@ -150,6 +161,23 @@ class ExportedModelPredictor(AbstractPredictor):
 
     return fn, self._variables
 
+  def factored_device_fns(self):
+    """See AbstractPredictor.factored_device_fns: the artifact's two
+    further calls, where it carries them."""
+    self.assert_is_loaded()
+    if not self._device_pair:
+      return None
+    encode, q_from_code = self._device_pair
+    keys = tuple(self._feature_keys)
+
+    def encode_fn(variables, features):
+      return encode(variables, features["image"])
+
+    def q_from_code_fn(variables, features):
+      return q_from_code(variables, *[features[key] for key in keys])
+
+    return encode_fn, q_from_code_fn
+
   def get_feature_specification(self) -> ts.TensorSpecStruct:
     self.assert_is_loaded()
     return self._feature_spec
@@ -161,6 +189,7 @@ class ExportedModelPredictor(AbstractPredictor):
   def close(self) -> None:
     self._call = None
     self._device_serve = None
+    self._device_pair = None
     self._variables = None
     self._example_parser = None
     self._version = -1  # assert_is_loaded fails cleanly after close()

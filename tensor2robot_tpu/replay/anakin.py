@@ -87,8 +87,7 @@ from tensor2robot_tpu.obs import trace as trace_lib
 from tensor2robot_tpu.parallel import distributed as dist_lib
 from tensor2robot_tpu.parallel import mesh as mesh_lib
 from tensor2robot_tpu.replay.bellman import (TargetNetwork,
-                                             make_bellman_targets_fn,
-                                             make_cem_states_and_score)
+                                             make_bellman_targets_fn)
 from tensor2robot_tpu.replay.device_buffer import (DeviceReplayBuffer,
                                                    make_learn_iteration_fn)
 from tensor2robot_tpu.research.qtopt import cem
@@ -306,7 +305,7 @@ class AnakinLoop(TargetNetwork):
     extend = self._buffer.extend_fn()
     sample = self._buffer.sample_fn()
     update_priorities = self._buffer.update_priorities_fn()
-    factored = getattr(model, "factored_cem_fns", lambda: None)()
+    factored = model.factored_cem_fns()
     # The label stage's CEM max runs at the scoring tier; the learn
     # body's grads/optimizer/TD-priority forward stay f32 (the targets
     # come back f32 from q_value_from_logits — see
@@ -370,9 +369,9 @@ class AnakinLoop(TargetNetwork):
           lambda j: jax.random.fold_in(
               jax.random.fold_in(act_base, tick), j))(
                   jnp.arange(n, dtype=jnp.uint32))
-      states, score = make_cem_states_and_score(model, factored,
-                                                online_variables, obs,
-                                                precision=precision)
+      states, score = cem.make_cem_states_and_score(
+          model.predict_fn, factored, online_variables, obs,
+          precision=precision)
       best, _ = cem.fleet_cem_optimize(score, states, keys, action_size,
                                        precision=precision, **cem_kwargs)
       # The collectors' exploration recipe (actor.py VectorActor

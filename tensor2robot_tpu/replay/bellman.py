@@ -47,46 +47,6 @@ def q_value_from_logits(logits: jnp.ndarray,
   return jax.nn.sigmoid(logits) if clip_targets else logits
 
 
-def make_cem_states_and_score(model, fns, variables, images,
-                              precision: str = "f32"):
-  """The ONE CEM scoring recipe: (states, score_fn) for
-  fleet_cem_optimize, tiled or factored.
-
-  Acting (replay/anakin.py) and Bellman labeling (targets_fn below)
-  both build their search through this helper, so the
-  encode-once-then-score-the-code factored form can never drift from
-  the tiled contract in one consumer but not the other. `fns` is the
-  model's `factored_cem_fns()` result (None → tiled: score full images
-  through predict_fn; (encode_fn, q_from_code_fn) → encode each image
-  once and score codes).
-
-  `precision` is the scoring tier (cem.SCORING_PRECISIONS). "f32"
-  returns the exact pre-tier recipe. "bf16" runs the whole score path —
-  the factored encode included, so the hoisted image tower enjoys the
-  same low-precision matmuls the tiled path gets — at bfloat16, with
-  the per-candidate scores cast back to float32 before elite selection
-  (cem.make_tiled_q_score_fn's contract)."""
-  if fns is None:
-    return images, cem.make_tiled_q_score_fn(model.predict_fn, variables,
-                                             precision=precision)
-  encode_fn, q_from_code_fn = fns
-  if cem.validate_precision(precision) != "f32":
-    # Encode once at the scoring dtype: the code then rides the tiled
-    # score's "image" key already in bf16 (its floating-input cast is a
-    # no-op), identical Q function and search to the tiled bf16 form.
-    # scoring_weights_view keeps the encode DENSE under every tier —
-    # int8's view is the quantize→dequantize round trip, so the hoisted
-    # tower sees exactly the weights the serving executables score with.
-    lp_variables = cem.scoring_weights_view(variables, precision)
-    states = encode_fn(
-        lp_variables,
-        {"image": images.astype(cem.scoring_dtype(precision))})
-    return states, cem.make_tiled_q_score_fn(q_from_code_fn, variables,
-                                             precision=precision)
-  return (encode_fn(variables, {"image": images}),
-          cem.make_tiled_q_score_fn(q_from_code_fn, variables))
-
-
 def make_bellman_targets_fn(model, action_size: int, gamma: float,
                             num_samples: int, num_elites: int,
                             iterations: int, clip_targets: bool,
@@ -126,10 +86,9 @@ def make_bellman_targets_fn(model, action_size: int, gamma: float,
         "(factored_cem_fns() returned None); use factored=False")
 
   def targets_fn(target_variables, next_images, rewards, dones, keys):
-    states, score = make_cem_states_and_score(model, fns,
-                                              target_variables,
-                                              next_images,
-                                              precision=precision)
+    states, score = cem.make_cem_states_and_score(
+        model.predict_fn, fns, target_variables, next_images,
+        precision=precision)
     _, best_logits = cem.fleet_cem_optimize(
         score, states, keys, action_size,
         num_samples=num_samples, num_elites=num_elites,

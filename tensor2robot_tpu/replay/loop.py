@@ -158,6 +158,9 @@ class _HotReloadPredictor(AbstractPredictor):
   def device_fn(self):
     return self._model.predict_fn, self._variables
 
+  def factored_device_fns(self):
+    return self._model.factored_cem_fns()
+
   def get_feature_specification(self) -> ts.TensorSpecStruct:
     return ts.flatten_spec_structure(
         self._model.get_feature_specification("predict"))

@@ -322,6 +322,49 @@ def make_tiled_q_score_fn(fn, variables, precision: str = "f32"):
   return score_lp
 
 
+def make_cem_states_and_score(fn, fns, variables, images,
+                              precision: str = "f32"):
+  """The ONE CEM scoring recipe: (states, score_fn) for
+  fleet_cem_optimize, tiled or factored.
+
+  Serving (serving/policy.py), acting (replay/anakin.py) and Bellman
+  labeling (replay/bellman.py) all build their search through this
+  helper, so the encode-once-then-score-the-code factored form can
+  never drift from the tiled contract in one consumer but not the
+  others. `fn` is the whole ``(variables, features) -> {"q_predicted"}``
+  forward; `fns` is the factored pair where the model or predictor
+  offers one (`CriticModel.factored_cem_fns`,
+  `AbstractPredictor.factored_device_fns`): None → tiled, score full
+  images through `fn`; (encode_fn, q_from_code_fn) → encode the whole
+  `images` batch once, here, outside the per-state vmap and the CEM
+  loop, and score codes.
+
+  `precision` is the scoring tier (SCORING_PRECISIONS). "f32" returns
+  the exact pre-tier recipe. "bf16" runs the whole score path — the
+  factored encode included, so the hoisted image tower enjoys the same
+  low-precision matmuls the tiled path gets — at bfloat16, with the
+  per-candidate scores cast back to float32 before elite selection
+  (make_tiled_q_score_fn's contract)."""
+  if fns is None:
+    return images, make_tiled_q_score_fn(fn, variables,
+                                         precision=precision)
+  encode_fn, q_from_code_fn = fns
+  if validate_precision(precision) != "f32":
+    # Encode once at the scoring dtype: the code then rides the tiled
+    # score's "image" key already in bf16 (its floating-input cast is a
+    # no-op), identical Q function and search to the tiled bf16 form.
+    # scoring_weights_view keeps the encode DENSE under every tier —
+    # int8's view is the quantize→dequantize round trip, so the hoisted
+    # tower sees exactly the weights the serving executables score with.
+    lp_variables = scoring_weights_view(variables, precision)
+    states = encode_fn(
+        lp_variables, {"image": images.astype(scoring_dtype(precision))})
+    return states, make_tiled_q_score_fn(q_from_code_fn, variables,
+                                         precision=precision)
+  return (encode_fn(variables, {"image": images}),
+          make_tiled_q_score_fn(q_from_code_fn, variables))
+
+
 def fleet_cem_optimize(
     score_fn: Callable[[jnp.ndarray, jnp.ndarray], jnp.ndarray],
     states: jnp.ndarray,

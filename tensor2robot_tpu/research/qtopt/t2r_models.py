@@ -75,36 +75,70 @@ class _GraspingQModule(nn.Module):
   # default awaits one paired chip run (ROADMAP S3b).
   impl: str = "parity"
 
-  @nn.compact
-  def __call__(self, features, mode: str):
-    train = mode == modes.TRAIN
+  def setup(self):
+    """setup()-structured so that the frame's half and the action's half
+    are callable apart (`encode`, `q_from_code`): attribute names are
+    the parameter names, the same as the one-method form had, so
+    checkpoints interchange."""
     dtype = self.compute_dtype
     if self.norm_kind == "batch":
-      norm = lambda name: nn.BatchNorm(
-          use_running_average=not train, dtype=dtype, name=name)
+      # use_running_average is given at the call: it follows the mode.
+      norm = lambda: nn.BatchNorm(dtype=dtype)
     elif self.norm_kind == "group":
-      norm = lambda name: nn.GroupNorm(num_groups=8, dtype=dtype, name=name)
+      norm = lambda: nn.GroupNorm(num_groups=8, dtype=dtype)
     else:
       raise ValueError(f"Unknown norm_kind {self.norm_kind!r}")
+    if self.stem_kind == "conv":
+      self.stem = nn.Conv(64, (6, 6), strides=(4, 4), dtype=dtype)
+    elif self.stem_kind == "space_to_depth":
+      # 3: the image spec's channels (setup sees no input to ask).
+      self.stem_s2d_kernel = self.param(
+          "stem_s2d_kernel",
+          lambda key: stem_conv.init_folded_stem_weights(key, 3, 64))
+      self.stem_s2d_bias = self.param(
+          "stem_s2d_bias", nn.initializers.zeros, (64,))
+    else:
+      raise ValueError(f"Unknown stem_kind {self.stem_kind!r}")
+    self.stem_bn = norm()
+    for i in range(3):
+      setattr(self, f"pre_conv{i}", nn.Conv(64, (3, 3), dtype=dtype))
+      setattr(self, f"pre_bn{i}", norm())
+      if self.impl == "fast":
+        post = strided_conv.FoldedStridedConv3x3(features=64, dtype=dtype)
+      else:
+        post = nn.Conv(64, (3, 3), strides=(2, 2), dtype=dtype)
+      setattr(self, f"post_conv{i}", post)
+      setattr(self, f"post_bn{i}", norm())
+    self.action_fc1 = nn.Dense(64, dtype=dtype)
+    self.action_fc2 = nn.Dense(64, dtype=dtype)
+    self.fc1 = nn.Dense(64, dtype=dtype)
+    self.q_head = nn.Dense(1, dtype=jnp.float32)
 
+  @nn.nowrap  # a helper, not a scope of its own in the compiled HLO
+  def _norm(self, name: str, x, mode: str):
+    layer = getattr(self, name)
+    if self.norm_kind == "batch":
+      return layer(x, use_running_average=mode != modes.TRAIN)
+    return layer(x)
+
+  def encode(self, features, mode: str = modes.PREDICT):
+    """{"image"} → the (B, 59, 59, 64) compute-dtype code at 472²:
+    everything that depends on the frame alone (92% of a row's FLOPs).
+    In PREDICT mode BatchNorm reads running statistics, so rows are
+    independent and a CEM search encodes each frame once
+    (`CriticModel.factored_cem_fns`)."""
+    dtype = self.compute_dtype
     # Scopes for what is no flax module (those have their own): every
     # device op of the step then has a stable path in the compiled HLO.
     with jax.named_scope("normalize_image"):
       x = normalize_image(features["image"], dtype)
     # Stem: 472 -> 118 -> 59.
     if self.stem_kind == "conv":
-      x = nn.Conv(64, (6, 6), strides=(4, 4), dtype=dtype, name="stem")(x)
-    elif self.stem_kind == "space_to_depth":
-      c = x.shape[-1]
-      w_folded = self.param(
-          "stem_s2d_kernel",
-          lambda key: stem_conv.init_folded_stem_weights(key, c, 64))
-      bias = self.param("stem_s2d_bias", nn.initializers.zeros, (64,))
-      x = (stem_conv.folded_s2d_stem(x, w_folded.astype(dtype))
-           + bias.astype(dtype))
+      x = self.stem(x)
     else:
-      raise ValueError(f"Unknown stem_kind {self.stem_kind!r}")
-    x = nn.relu(norm("stem_bn")(x))
+      x = (stem_conv.folded_s2d_stem(x, self.stem_s2d_kernel.astype(dtype))
+           + self.stem_s2d_bias.astype(dtype))
+    x = nn.relu(self._norm("stem_bn", x, mode))
     with jax.named_scope("stem_pool"):
       if (self.impl == "fast" and x.shape[1] % 2 == 0
           and x.shape[2] % 2 == 0):
@@ -112,9 +146,16 @@ class _GraspingQModule(nn.Module):
       else:
         x = nn.max_pool(x, (2, 2), strides=(2, 2))
     for i in range(3):
-      x = nn.relu(norm(f"pre_bn{i}")(nn.Conv(
-          64, (3, 3), dtype=dtype, name=f"pre_conv{i}")(x)))
+      x = nn.relu(self._norm(
+          f"pre_bn{i}", getattr(self, f"pre_conv{i}")(x), mode))
+    return x
 
+  def q_from_code(self, features, mode: str = modes.PREDICT):
+    """{"image": the code, "action"[, "state"]} → the Q logit. The code
+    rides the `image` key, so the tiled score's broadcast applies to it
+    unchanged."""
+    dtype = self.compute_dtype
+    x = features["image"]
     # Action (and optional state vector) merge.
     with jax.named_scope("wire_cast"):
       action = features["action"].astype(dtype)
@@ -127,25 +168,26 @@ class _GraspingQModule(nn.Module):
       with jax.named_scope("wire_cast"):
         merge_inputs.append(features["state"].astype(dtype))
     embedding = jnp.concatenate(merge_inputs, axis=-1)
-    embedding = nn.relu(nn.Dense(64, dtype=dtype, name="action_fc1")(
-        embedding))
-    embedding = nn.Dense(64, dtype=dtype, name="action_fc2")(embedding)
+    embedding = nn.relu(self.action_fc1(embedding))
+    embedding = self.action_fc2(embedding)
     x = nn.relu(x + embedding[:, None, None, :])
 
     # Post-merge tower: 59 -> 30 -> 15 -> 8 (SAME/2 each).
     for i in range(3):
-      if self.impl == "fast":
-        conv = strided_conv.FoldedStridedConv3x3(
-            features=64, dtype=dtype, name=f"post_conv{i}")(x)
-      else:
-        conv = nn.Conv(64, (3, 3), strides=(2, 2), dtype=dtype,
-                       name=f"post_conv{i}")(x)
-      x = nn.relu(norm(f"post_bn{i}")(conv))
+      x = nn.relu(self._norm(
+          f"post_bn{i}", getattr(self, f"post_conv{i}")(x), mode))
 
     x = jnp.mean(x, axis=(1, 2))  # global pool → (B, 64)
-    x = nn.relu(nn.Dense(64, dtype=dtype, name="fc1")(x))
-    q_logit = nn.Dense(1, dtype=jnp.float32, name="q_head")(x)[:, 0]
+    x = nn.relu(self.fc1(x))
+    q_logit = self.q_head(x)[:, 0]
     return ts.TensorSpecStruct({"q_predicted": q_logit})
+
+  def __call__(self, features, mode: str):
+    # The pair composed: the same ops in the same order in every mode.
+    rest = {key: features[key] for key in ("action", "state")
+            if key in features}
+    return self.q_from_code(
+        {"image": self.encode(features, mode), **rest}, mode)
 
 
 @configurable

@@ -1,13 +1,16 @@
 """CEMFleetPolicy: the QT-Opt control step batched across clients.
 
 One compiled program per ladder bucket runs the whole fleet control
-step — on-device image tiling, all CEM iterations, scoring through the
-Q-function, elite refitting — for up to ``bucket`` clients at once
-(PAPER.md §3.3 ran the reference's robot fleets through exactly such a
-batched session.run). Executables are AOT-compiled once per bucket and
-keyed on the bucket size only: model hot-reloads swap the variables
-*argument*, never the executable, so serving a fleet for days compiles
-``len(ladder)`` programs total.
+step — all CEM iterations, scoring through the Q-function, elite
+refitting — for up to ``bucket`` clients at once (PAPER.md §3.3 ran the
+reference's robot fleets through exactly such a batched session.run).
+Where the predictor offers the model's factored pair
+(``factored_device_fns``) each frame is encoded once and the search
+runs over its code; where it does not, each frame is tiled across its
+candidate actions on the device. Executables are AOT-compiled once per
+bucket and keyed on the bucket size only: model hot-reloads swap the
+variables *argument*, never the executable, so serving a fleet for days
+compiles ``len(ladder)`` programs total.
 
 Per-request determinism: every request carries a uint32 seed; its CEM
 key is ``fold_in(key(policy_seed), seed)`` inside the compiled program,
@@ -103,6 +106,9 @@ class CEMFleetPolicy:
     # bucket -> number of compilations; the serving invariant tests
     # assert every value stays exactly 1 for the life of the policy.
     self.compile_counts = {}
+    # bucket -> whether its executable encodes each frame once (the
+    # predictor offered the factored pair when the bucket compiled).
+    self.encode_once = {}
     # Separate locks: a first-time bucket compile holds _compile_lock
     # for seconds — clients assigning request seeds in submit() must
     # not stall fleet-wide behind it.
@@ -198,7 +204,8 @@ class CEMFleetPolicy:
       self._turn.acquire()
     try:
       # Returns at enqueue.
-      with trace_lib.span("serve/execute", bucket=bucket):
+      with trace_lib.span("serve/execute", bucket=bucket,
+                          encode_once=int(self.encode_once[bucket])):
         actions, scores = compiled(variables, device_images, device_seeds)
       # The wait for that transfer and for the device, then D2H.
       with trace_lib.span("serve/readback") as readback:
@@ -291,30 +298,34 @@ class CEMFleetPolicy:
 
   # -- compiled path -------------------------------------------------------
 
-  def _build_control(self, fn):
+  def _build_control(self, fn, fns):
     """(variables, (B,...) images, (B,) seeds) → ((B, A) actions,
     (B,) selected-action Q-scores). The scores are CEM's own final
     readout — already computed inside the search — returned so the
     serving layer's per-replica Q sketches (the fleet drift guard,
-    ISSUE 15) ride the same dispatch instead of a second forward."""
+    ISSUE 15) ride the same dispatch instead of a second forward.
+    `fns` is the predictor's factored pair or None."""
     num_samples = self._num_samples
 
     def control(variables, images, seeds):
       base = jax.random.key(self._seed)
       keys = jax.vmap(lambda s: jax.random.fold_in(base, s))(seeds)
 
-      # Tile ONE client's image across its candidate actions; under
-      # the fleet vmap this becomes one (B*num_samples) Q call per
-      # CEM iteration — the Podracer-style batched on-device step.
-      # Shared with the Bellman updater's target max (same wire
-      # contract, by construction). The scoring tier is part of the
-      # compiled program (params quantize inside the executable), so a
-      # hot reload stays one device_put, zero recompiles, any tier.
-      score = cem.make_tiled_q_score_fn(fn, variables,
-                                        precision=self.precision)
+      # One client's state is its frame or, with the pair, the frame's
+      # code, encoded here for the whole bucket: outside the fleet vmap
+      # and the CEM loop. The score tiles that state across the
+      # client's candidate actions; under the fleet vmap this becomes
+      # one (B*num_samples) Q call per CEM iteration — the
+      # Podracer-style batched on-device step. Shared with the Bellman
+      # updater's target max (same wire contract, by construction).
+      # The scoring tier is part of the compiled program (params
+      # quantize inside the executable), so a hot reload stays one
+      # device_put, zero recompiles, any tier.
+      states, score = cem.make_cem_states_and_score(
+          fn, fns, variables, images, precision=self.precision)
 
       best, best_scores = cem.fleet_cem_optimize(
-          score, images, keys, self._action_size,
+          score, states, keys, self._action_size,
           num_samples=num_samples, num_elites=self._num_elites,
           iterations=self._iterations, precision=self.precision)
       return best, best_scores
@@ -325,11 +336,14 @@ class CEMFleetPolicy:
     with self._compile_lock:
       compiled = self._executables.get(bucket)
       if compiled is None:
-        with trace_lib.span("serve/compile", bucket=bucket):
-          lowered = jax.jit(self._build_control(fn)).lower(
+        fns = self._predictor.factored_device_fns()
+        with trace_lib.span("serve/compile", bucket=bucket,
+                            encode_once=int(fns is not None)):
+          lowered = jax.jit(self._build_control(fn, fns)).lower(
               variables, self._put(padded), self._put(padded_seeds))
           compiled = lowered.compile()
         self._executables[bucket] = compiled
+        self.encode_once[bucket] = fns is not None
         self.compile_counts[bucket] = (
             self.compile_counts.get(bucket, 0) + 1)
         if self._ledger is not None:

@@ -133,14 +133,18 @@ class PolicyReplica:
             self._corrupt_cache = None
       override = (self._corrupted_variables()
                   if self._corrupt_scale is not None else None)
-      actions, scores = self.policy(images, seeds, variables=override,
-                                    return_scores=True)
+      policy = self.policy  # one read: use_policy may swap it meanwhile
+      actions, scores = policy(images, seeds, variables=override,
+                               return_scores=True)
       if scores is not None:
         # Served-Q sketch feed (ISSUE 15): free scores off the same
-        # dispatch; exception-isolated — diagnostics never fail a
-        # flush (the listener contract).
+        # dispatch, and whether its program encoded each frame once;
+        # exception-isolated — diagnostics never fail a flush (the
+        # listener contract).
         try:
           self.stats.record_q_values(str(self.device), scores)
+          if policy.encode_once.get(policy.ladder.bucket_for(len(items))):
+            self.stats.record_encode_once_flush()
         except Exception:
           pass
       if self._episode_recorder is not None:

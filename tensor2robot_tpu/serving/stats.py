@@ -153,6 +153,7 @@ class ServingStats:
     self._padded_slots = 0     # sum of compiled bucket sizes over flushes
     self._deadline_flushes = 0  # flushed by deadline, not by a full batch
     self._overlapped_flushes = 0  # popped while another flush was open
+    self._encode_once_flushes = 0  # dispatched to an encode-once program
     self._queue_depth_sum = 0   # queue depth left behind at flush time
     self._per_class: Dict[str, _ClassStats] = {}
     self._q_sketches: Dict[str, QSketch] = {}
@@ -259,6 +260,13 @@ class ServingStats:
     with self._lock:
       self._overlapped_flushes += 1
 
+  def record_encode_once_flush(self) -> None:
+    """One replica dispatch whose bucket program encoded each frame
+    once and searched over the code (`CEMFleetPolicy.encode_once`),
+    not over tiled copies of the frame."""
+    with self._lock:
+      self._encode_once_flushes += 1
+
   def record_latency_ms(self, latency_ms: float,
                         class_name: Optional[str] = None) -> None:
     self.latency.record(latency_ms)
@@ -292,6 +300,7 @@ class ServingStats:
           "flushes": flushes,
           "deadline_flushes": self._deadline_flushes,
           "overlapped_flushes": self._overlapped_flushes,
+          "encode_once_flushes": self._encode_once_flushes,
           "flush_overlap_share": round(
               self._overlapped_flushes / flushes, 4) if flushes else None,
           "batch_occupancy": round(

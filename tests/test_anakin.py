@@ -236,10 +236,25 @@ class TestFactoredScore:
   def test_unfactored_model_falls_back(self):
     """Models without a factored form return None (generic tiled path
     stays the contract) and factored=True refuses loudly."""
+    import flax.linen as nn
     from tensor2robot_tpu.replay.bellman import make_bellman_targets_fn
-    from tensor2robot_tpu.research.qtopt.t2r_models import (
-        QTOptGraspingModel)
-    model = QTOptGraspingModel(image_size=16)
+
+    class WholeQ(nn.Module):
+      """Frame and action mixed from the first layer on: no pair."""
+
+      @nn.compact
+      def __call__(self, features, mode):
+        image = features["image"].astype(jnp.float32) / 255.0
+        x = jnp.concatenate([image.reshape((image.shape[0], -1)),
+                             features["action"]], axis=-1)
+        return {"q_predicted": nn.Dense(1)(x)[:, 0]}
+
+    class WholeQModel(TinyQCriticModel):
+
+      def build_module(self):
+        return WholeQ()
+
+    model = WholeQModel(image_size=IMG)
     assert model.factored_cem_fns() is None
     with pytest.raises(ValueError, match="no factored CEM form"):
       make_bellman_targets_fn(model, 4, 0.9, 8, 2, 2, True,

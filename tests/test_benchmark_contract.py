@@ -65,14 +65,45 @@ def _span_names_the_benchmark_reads():
   return sorted(names)
 
 
+def _string_constants(tree):
+  """{NAME: "value"} of a module's top-level `NAME = "value"` lines."""
+  return {node.targets[0].id: node.value.value for node in tree.body
+          if isinstance(node, ast.Assign)
+          and isinstance(node.value, ast.Constant)
+          and isinstance(node.value.value, str)}
+
+
+def _spans_filtered_by_name(tree, shared):
+  """Span names a reader compares `s["name"]` with: a literal, a constant
+  of its own, or `program_spans.<CONSTANT>` (`shared`)."""
+  own = _string_constants(tree)
+  names = set()
+  for node in ast.walk(tree):
+    if not (isinstance(node, ast.Compare)
+            and isinstance(node.left, ast.Subscript)
+            and isinstance(node.left.value, ast.Name)
+            and node.left.value.id == "s"
+            and isinstance(node.left.slice, ast.Constant)
+            and node.left.slice.value == "name"):
+      continue
+    other = node.comparators[0]
+    if isinstance(other, ast.Constant):
+      names.add(other.value)
+    elif isinstance(other, ast.Name):
+      names.add(own[other.id])
+    elif isinstance(other, ast.Attribute):
+      names.add(shared[other.attr])
+  return names
+
+
 def _attrs_read_off(span_name):
   """Keys the readers subscript or test on a span dict `s`, in the reader
-  files that name `span_name` (or its constant in program_spans)."""
-  constants = {  # FLUSH = "serve/flush" and the like
-      node.value.value: node.targets[0].id
-      for node in _parse(
-          os.path.join(BENCHMARK, "trace", "program_spans.py")).body
-      if isinstance(node, ast.Assign) and isinstance(node.value, ast.Constant)}
+  files that name `span_name` (or its constant in program_spans). A
+  reader that picks its spans by `s["name"] == ...` reads attrs off those
+  alone: the span that only closes its window owes it none."""
+  shared = _string_constants(_parse(  # FLUSH = "serve/flush" and the like
+      os.path.join(BENCHMARK, "trace", "program_spans.py")))
+  constants = {value: name for name, value in shared.items()}
   span_keys = {"name", "ts_s", "dur_s"}
   attrs = set()
   for path in glob.glob(os.path.join(BENCHMARK, "layer_metrics", "*.py")):
@@ -80,6 +111,9 @@ def _attrs_read_off(span_name):
       source = f.read()
     if (span_name not in source
         and f"program_spans.{constants.get(span_name)}" not in source):
+      continue
+    picked = _spans_filtered_by_name(ast.parse(source), shared)
+    if picked and span_name not in picked:
       continue
     for node in ast.walk(ast.parse(source)):
       key = None
@@ -136,6 +170,8 @@ def test_span_the_benchmark_reads_is_opened_by_the_program(span_name):
   wanted = _attrs_read_off(span_name)
   if span_name == "serve/flush":
     assert {"batch", "queue_wait_ms_sum", "in_flight"} <= wanted, wanted
+  if span_name == "serve/execute":  # encode_once_share.serve
+    assert {"encode_once"} <= wanted, wanted
   for path, lineno, keywords in sites:
     assert wanted <= keywords, (
         f"{path}:{lineno} opens {span_name!r} without the attrs "

@@ -185,8 +185,9 @@ class TestFleetRouter:
     policy = replica.policy
 
     class Meeting:
-      device, ladder, _predictor = (policy.device, policy.ladder,
-                                    policy._predictor)
+      device, ladder, _predictor, encode_once = (
+          policy.device, policy.ladder, policy._predictor,
+          policy.encode_once)
 
       def __call__(self, *args, **kwargs):
         both_open.wait(timeout=10)
