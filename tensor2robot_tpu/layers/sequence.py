@@ -1,12 +1,16 @@
-"""Decoder blocks of a sparse-expert sequence model: RMSNorm, rotary
-positions, multi-head latent attention (MLA, training form), gated MLP,
-the expert layer (routing and grouping in `parallel/expert_parallel`),
-a depth-1 multi-token-prediction module and a chunked next-token loss.
+"""Decoder blocks of a sparse-expert sequence model: RMSNorm (plain or
+zero-centred), rotary positions (interleaved over the whole width, or
+half-split over the head's first part), three token mixers (multi-head
+latent attention, gated grouped-query attention, the gated delta net;
+training forms), gated MLP, the expert layer (routing and grouping in
+`parallel/expert_parallel`) and a chunked next-token loss.
 
-Layer equations (DeepSeek-V2/V3's, which the JoyAI-LLM-Flash config
-follows): see `SequenceConfig`'s fields and each module. Activations run
-in `dtype` (bfloat16), parameters are float32, norms, rotary angles, the
-router and the softmax statistics are float32.
+Layer equations: DeepSeek-V2/V3's, which the JoyAI-LLM-Flash config
+follows, and Qwen3-Next's (gated attention and Gated Delta Networks,
+arXiv:2412.06464); see `SequenceConfig`'s fields and each module.
+Activations run in `dtype` (bfloat16), parameters are float32; norms,
+rotary angles, the router, the softmax statistics, and the delta net's
+decays g, write strengths b, L2 norms and state are float32.
 """
 
 from __future__ import annotations
@@ -19,7 +23,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tensor2robot_tpu.ops import dispatch
 from tensor2robot_tpu.ops.flash_attention import flash_attention
+from tensor2robot_tpu.ops.gated_delta_rule import gated_delta_rule
 from tensor2robot_tpu.parallel import expert_parallel
 
 
@@ -50,20 +56,56 @@ class SequenceConfig:
   num_hidden_layers: int = 40
   num_nextn_predict_layers: int = 1
   mtp_loss_weight: float = 0.3
+  scoring_func: str = "sigmoid"    # or "softmax": over all, no bias
+  # > 0: the shared expert is this wide and gated by sigmoid(x · w_s)
+  # (Qwen3-Next); 0: n_shared_experts · moe_intermediate_size, ungated.
+  shared_expert_intermediate_size: int = 0
+  # The blocks' norms and gated attention's q/k norms: ⊙ (1 + w), w from 0.
+  zero_centered_norm: bool = False
+  # > 0: the hybrid layout. Layer i (from 0) is gated attention where
+  # (i + 1) % full_attention_interval == 0, else the gated delta net.
+  # 0: every layer MLA. The hybrid layout's own sizes have no default: a
+  # configuration of it states them.
+  full_attention_interval: int = 0
+  num_key_value_heads: int = 0
+  head_dim: int = 0
+  partial_rotary_factor: float = 0.0
+  linear_conv_kernel_dim: int = 0
+  linear_key_head_dim: int = 0
+  linear_value_head_dim: int = 0
+  linear_num_key_heads: int = 0
+  linear_num_value_heads: int = 0
 
   @property
   def num_expert_layers(self) -> int:
     return self.num_hidden_layers - self.first_k_dense_replace
 
+  @property
+  def hybrid(self) -> bool:
+    return self.full_attention_interval > 0
+
+  def layer_kind(self, layer: int) -> str:
+    """"mla", or in the hybrid layout "full" | "linear"."""
+    if not self.hybrid:
+      return "mla"
+    return ("full" if (layer + 1) % self.full_attention_interval == 0
+            else "linear")
+
 
 class RMSNorm(nn.Module):
+  """x · rsqrt(mean(x²) + eps) ⊙ w, w from 1; `zero_centered`: ⊙ (1 + w),
+  w from 0."""
   eps: float = 1e-6
   dtype: Any = jnp.bfloat16
+  zero_centered: bool = False
 
   @nn.compact
   def __call__(self, x):
-    scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
-                       jnp.float32)
+    init = (nn.initializers.zeros if self.zero_centered
+            else nn.initializers.ones)
+    scale = self.param("scale", init, (x.shape[-1],), jnp.float32)
+    if self.zero_centered:
+      scale = 1.0 + scale
     x = x.astype(jnp.float32)
     y = x * jax.lax.rsqrt(
         jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps)
@@ -83,6 +125,21 @@ def rotary(x, theta: float):
   turned = jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
                      axis=-1)
   return turned.reshape(x.shape).astype(x.dtype)
+
+
+def rotary_half_split(x, theta: float, width: int):
+  """Rotary positions over the first `width` dims of (B, T, H, D), pairs
+  half-split: (x[i], x[i + width/2]) turns by t · theta^(-2i/width); the
+  rest pass. Float32."""
+  t, half = x.shape[1], width // 2
+  inv_freq = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+  angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq
+  cos, sin = (f(angle)[None, :, None, :] for f in (jnp.cos, jnp.sin))
+  x32 = x.astype(jnp.float32)
+  first, second = x32[..., :half], x32[..., half:width]
+  return jnp.concatenate(
+      [first * cos - second * sin, second * cos + first * sin,
+       x32[..., width:]], axis=-1).astype(x.dtype)
 
 
 def _dense(features: int, dtype, name: str) -> nn.Dense:
@@ -126,6 +183,115 @@ class MLAttention(nn.Module):
           out.reshape(b, t, heads * vdim))
 
 
+class GatedAttention(nn.Module):
+  """Gated grouped-query attention, training form: `num_attention_heads`
+  query heads on `num_key_value_heads` key/value heads of `head_dim`, q
+  and k normed per head (one weight for all heads), rotary
+  over the head's first `partial_rotary_factor`, causal; each head's
+  output times sigmoid(gate_h), the gate the other half of q's
+  projection."""
+  config: SequenceConfig
+  dtype: Any = jnp.bfloat16
+
+  @nn.compact
+  def __call__(self, x):
+    c = self.config
+    b, t, _ = x.shape
+    heads, kv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    # Float32 out of the norm and through the turn: one rounding, after.
+    norm = lambda name: RMSNorm(c.rms_norm_eps, jnp.float32,
+                                c.zero_centered_norm, name=name)
+    turn = lambda a: rotary_half_split(
+        a, c.rope_theta, int(c.partial_rotary_factor * hd)).astype(self.dtype)
+    with jax.named_scope("gqa"):
+      q_gate = _dense(heads * 2 * hd, self.dtype, "q_proj")(x).reshape(
+          b, t, heads, 2 * hd)
+      q, gate = q_gate[..., :hd], q_gate[..., hd:]
+      k = _dense(kv * hd, self.dtype, "k_proj")(x).reshape(b, t, kv, hd)
+      v = _dense(kv * hd, self.dtype, "v_proj")(x).reshape(b, t, kv, hd)
+      # On a TPU the kernel or an error, never the (H, T, T) scores.
+      on_tpu = (jax.default_backend() == "tpu"
+                and not dispatch.use_xla_only())
+      out = flash_attention(
+          turn(norm("q_norm")(q)), turn(norm("k_norm")(k)), v, causal=True,
+          scale=1.0 / math.sqrt(hd),
+          implementation="pallas" if on_tpu else "auto")
+      out = (out.astype(jnp.float32)
+             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype)
+      return _dense(c.hidden_size, self.dtype, "o_proj")(
+          out.reshape(b, t, heads * hd))
+
+
+def causal_conv(x, kernel):
+  """Depthwise convolution over time of (B, T, C) with (taps, C): y_t =
+  Σ_j kernel[j] · x[t − (taps − 1) + j]; nothing ahead of t is read, and
+  before the start there are zeros. Float32 sums."""
+  taps, t = kernel.shape[0], x.shape[1]
+  padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
+  return sum(kernel[j] * padded[:, j:j + t].astype(jnp.float32)
+             for j in range(taps))
+
+
+def _l2_normalized(x, eps: float = 1e-6):
+  x = x.astype(jnp.float32)
+  return x * jax.lax.rsqrt(
+      jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+class GatedDeltaNet(nn.Module):
+  """The gated delta net, training form: q, k (`linear_num_key_heads`
+  of `linear_key_head_dim`), v, z (`linear_num_value_heads` of
+  `linear_value_head_dim`) and the per-head b, a from two projections;
+  [q; k; v] through a causal depthwise convolution and silu; β =
+  sigmoid(b), g = −exp(A_log) ⊙ softplus(a + dt_bias); q, k L2-normed,
+  q over √Dk; the gated delta rule; RMSNorm(o) ⊙ silu(z); the output
+  projection. `in_proj_qkvz`'s columns are [q | k | v | z], `in_proj_ba`'s
+  [b | a]. Returns (y, {"gdn/decay_mean": mean exp(g), "gdn/beta_mean"})."""
+  config: SequenceConfig
+  dtype: Any = jnp.bfloat16
+
+  @nn.compact
+  def __call__(self, x):
+    c = self.config
+    b, t, _ = x.shape
+    kh, dk = c.linear_num_key_heads, c.linear_key_head_dim
+    vh, dv = c.linear_num_value_heads, c.linear_value_head_dim
+    key, value = kh * dk, vh * dv
+    with jax.named_scope("gdn/proj"):
+      qkvz = _dense(2 * key + 2 * value, self.dtype, "in_proj_qkvz")(x)
+      ba = _dense(2 * vh, jnp.float32, "in_proj_ba")(x)
+    with jax.named_scope("gdn/conv"):
+      taps = c.linear_conv_kernel_dim
+      kernel = self.param(
+          "conv_kernel", nn.initializers.variance_scaling(
+              1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+          (taps, 2 * key + value), jnp.float32)
+      mixed = nn.silu(causal_conv(qkvz[..., :2 * key + value], kernel))
+    with jax.named_scope("gdn/rule"):
+      a_log = self.param(
+          "A_log", lambda rng, shape: jnp.log(jax.random.uniform(
+              rng, shape, jnp.float32, 1e-3, 16.0)), (vh,))
+      dt_bias = self.param("dt_bias", nn.initializers.zeros, (vh,),
+                           jnp.float32)
+      beta = jax.nn.sigmoid(ba[..., :vh])
+      g = -jnp.exp(a_log) * jax.nn.softplus(ba[..., vh:] + dt_bias)
+      q = (_l2_normalized(mixed[..., :key].reshape(b, t, kh, dk))
+           / math.sqrt(dk)).astype(self.dtype)
+      k = _l2_normalized(
+          mixed[..., key:2 * key].reshape(b, t, kh, dk)).astype(self.dtype)
+      v = mixed[..., 2 * key:].reshape(b, t, vh, dv).astype(self.dtype)
+      out = gated_delta_rule(q, k, v, g, beta)
+    with jax.named_scope("gdn/out"):
+      z = qkvz[..., 2 * key + value:].reshape(b, t, vh, dv)
+      out = RMSNorm(c.rms_norm_eps, self.dtype, name="norm")(out)
+      out = (out.astype(jnp.float32)
+             * nn.silu(z.astype(jnp.float32))).astype(self.dtype)
+      y = _dense(c.hidden_size, self.dtype, "out_proj")(
+          out.reshape(b, t, value))
+    return y, {"gdn/decay_mean": jnp.mean(jnp.exp(g)),
+               "gdn/beta_mean": jnp.mean(beta)}
+
+
 class GatedMLP(nn.Module):
   """down(silu(gate x) ⊙ up x), no biases."""
   width: int
@@ -154,8 +320,9 @@ class ExpertLayer(nn.Module):
     params = expert_parallel.MoEParams(
         router=self.param("router", nn.initializers.lecun_normal(),
                           (d, c.n_routed_experts), jnp.float32),
-        bias=self.param("correction_bias", nn.initializers.zeros,
-                        (c.n_routed_experts,), jnp.float32),
+        bias=(self.param("correction_bias", nn.initializers.zeros,
+                         (c.n_routed_experts,), jnp.float32)
+              if c.scoring_func == "sigmoid" else None),
         gate=self.param("experts_gate", fan_in, (held, d, width),
                         jnp.float32),
         up=self.param("experts_up", fan_in, (held, d, width), jnp.float32),
@@ -164,9 +331,17 @@ class ExpertLayer(nn.Module):
     y, counters = expert_parallel.moe_share(
         x.reshape(b * t, d), params, first_expert=c.first_expert,
         top_k=c.num_experts_per_tok, scale=c.routed_scaling_factor,
-        compute_dtype=self.dtype)
+        compute_dtype=self.dtype, scoring=c.scoring_func)
     y = y.reshape(b, t, d)
-    if c.n_shared_experts:
+    if c.shared_expert_intermediate_size:
+      with jax.named_scope("moe/shared"):
+        shared = GatedMLP(c.shared_expert_intermediate_size, self.dtype,
+                          name="shared")(x)
+      with jax.named_scope("moe/shared_gate"):
+        gate = jax.nn.sigmoid(
+            _dense(1, jnp.float32, "shared_gate")(x))
+        y = y + (shared.astype(jnp.float32) * gate).astype(self.dtype)
+    elif c.n_shared_experts:
       with jax.named_scope("moe/shared"):
         y = y + GatedMLP(c.n_shared_experts * width, self.dtype,
                          name="shared")(x)
@@ -174,20 +349,32 @@ class ExpertLayer(nn.Module):
 
 
 class DecoderBlock(nn.Module):
-  """h = x + MLA(norm(x)); y = h + FFN(norm(h)); FFN the dense gated
-  MLP or the expert layer. Returns (y, the expert layer's counters)."""
+  """h = x + Mixer(norm(x)); y = h + FFN(norm(h)); the mixer by the
+  layer's `kind` ("mla", "full": gated attention, "linear": the gated
+  delta net), FFN the dense gated MLP or the expert layer. Returns (y,
+  the expert layer's counters, with a delta net's `gdn/*` beside them)."""
   config: SequenceConfig
   experts: bool
   dtype: Any = jnp.bfloat16
+  kind: str = "mla"
 
   @nn.compact
   def __call__(self, x, _=None):
     c = self.config
-    norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype, name=name)
-    h = x + MLAttention(c, self.dtype, name="attn")(norm("attn_norm")(x))
+    norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype,
+                                c.zero_centered_norm, name=name)
+    inner, gates = norm("attn_norm")(x), {}
+    if self.kind == "linear":
+      mixed, gates = GatedDeltaNet(c, self.dtype, name="attn")(inner)
+    elif self.kind == "full":
+      mixed = GatedAttention(c, self.dtype, name="attn")(inner)
+    else:
+      mixed = MLAttention(c, self.dtype, name="attn")(inner)
+    h = x + mixed
     inner = norm("ffn_norm")(h)
     if self.experts:
       y, counters = ExpertLayer(c, self.dtype, name="moe")(inner)
+      counters = dict(counters, **gates)
     else:
       y, counters = GatedMLP(c.intermediate_size, self.dtype,
                              name="mlp")(inner), None

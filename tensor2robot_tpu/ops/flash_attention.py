@@ -52,10 +52,10 @@ _MAX_SINGLE_BLOCK_T = 1024
 # footprint is 2× their size. Each call asks for the scoped VMEM it
 # reckons (_compiler_params: the staged blocks and the tiles it works
 # on); the rows are bounded here so that the sum stays a small part of
-# the chip's VMEM (T=8192 at widths 192/128 in bf16 stages 12 MiB of
-# rows and asks for 20-21 MiB). Longer sequences belong to
-# ring_attention.
-_MAX_KV_VMEM_BYTES = 14 * 1024 * 1024
+# the chip's 128 MiB of VMEM (T=8192 in bf16: widths 192/128 stage
+# 10 MiB of rows and ask for 20-21 MiB, widths 256/256 stage 16 MiB and
+# ask for 27-28 MiB). Longer sequences belong to ring_attention.
+_MAX_KV_VMEM_BYTES = 16 * 1024 * 1024
 _PIPELINE_BUFFERS = 2
 _MIN_VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 
@@ -67,9 +67,13 @@ _NT = (((1,), (1,)), ((), ()))
 def flash_attention_reference(q, k, v, causal: bool = False,
                               scale: Optional[float] = None):
   """XLA reference: materializes (B, H, T, T) scores. (B, T, H, D) in/out;
-  v (and so the output) may have a head width of its own."""
+  v (and so the output) may have a head width of its own, and k and v
+  fewer heads than q, each serving a group of consecutive query heads."""
   if scale is None:
     scale = 1.0 / math.sqrt(q.shape[-1])
+  group = q.shape[2] // k.shape[2]
+  if group > 1:
+    k, v = (jnp.repeat(x, group, axis=2) for x in (k, v))
   scores = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32),
                       k.astype(jnp.float32)) * scale
   if causal:
@@ -173,6 +177,9 @@ def _block_sizes(t: int):
 def _supported(q, k, v) -> Optional[str]:
   """None if the Pallas path can run, else the reason it cannot."""
   t = q.shape[1]
+  if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
+    return (f"query heads must be a multiple of the key/value heads; got "
+            f"{q.shape[2]} on {k.shape[2]}/{v.shape[2]}")
   if _block_sizes(t) is None:
     return (f"T must be divisible by {_BLOCKS[-1]} or <= "
             f"{_MAX_SINGLE_BLOCK_T}; got T={t}")
@@ -221,10 +228,13 @@ def _pallas_forward(q, k, v, causal: bool, scale: float,
   dv = v.shape[3]
   block_q, block_k = _block_sizes(t)
   # (B, T, H, D) → (B·H, T, D): heads become independent grid rows.
+  # Grouped queries: query row i reads key/value row i // group, which
+  # stays staged while the group's rows go by.
+  group = h // k.shape[2]
   qr, kr, vr = _to_rows(q), _to_rows(k), _to_rows(v)
   grid = (b * h, t // block_q)
   tile = lambda i, qi: (i, qi, 0)
-  full = lambda i, qi: (i, 0, 0)
+  full = lambda i, qi: (i // group, 0, 0)
   out, lse = pl.pallas_call(
       functools.partial(_kernel, scale=scale, causal=causal,
                         block_q=block_q, block_k=block_k, seq_len=t),
@@ -338,6 +348,8 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
   """
   b, t, h, d = q.shape
   dv = v.shape[3]
+  kv_heads = k.shape[2]
+  group = h // kv_heads
   block_q, block_k = _block_sizes(t)
   qr, kr, vr, dor = _to_rows(q), _to_rows(k), _to_rows(v), _to_rows(do)
   # Δ_i = Σ_d dO_id · O_id — cheap elementwise reduction, XLA fuses it.
@@ -351,7 +363,13 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
   interpret = jax.default_backend() != "tpu"
   tile_q = lambda i, qi: (i, qi, 0)
   tile_k = lambda i, kj: (i, kj, 0)
+  tile_kv = lambda i, kj: (i // group, kj, 0)
   full = lambda i, _: (i, 0, 0)
+  full_kv = lambda i, _: (i // group, 0, 0)
+  # Grouped queries: the dk/dv program runs a row per QUERY head and
+  # writes that head's part, float32; the group's parts are summed after.
+  dk_dtype, dv_dtype = ((jnp.float32, jnp.float32) if group > 1
+                        else (k.dtype, v.dtype))
   kwargs = dict(scale=scale, causal=causal, block_q=block_q,
                 block_k=block_k, seq_len=t)
   dq = pl.pallas_call(
@@ -360,8 +378,8 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
       grid=(b * h, t // block_q),
       in_specs=[
           pl.BlockSpec((1, block_q, d), tile_q, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, t, d), full, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, t, dv), full, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, t, d), full_kv, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, t, dv), full_kv, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, block_q, dv), tile_q, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, block_q, 1), tile_q, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, block_q, 1), tile_q, memory_space=pltpu.VMEM),
@@ -378,13 +396,13 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
   )(qr, kr, vr, dor, lse, delta)
   dk, dv = pl.pallas_call(
       functools.partial(_kernel_dkv, **kwargs),
-      out_shape=(jax.ShapeDtypeStruct((b * h, t, d), k.dtype),
-                 jax.ShapeDtypeStruct((b * h, t, dv), v.dtype)),
+      out_shape=(jax.ShapeDtypeStruct((b * h, t, d), dk_dtype),
+                 jax.ShapeDtypeStruct((b * h, t, dv), dv_dtype)),
       grid=(b * h, t // block_k),
       in_specs=[
           pl.BlockSpec((1, t, d), full, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, block_k, d), tile_k, memory_space=pltpu.VMEM),
-          pl.BlockSpec((1, block_k, dv), tile_k, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, block_k, d), tile_kv, memory_space=pltpu.VMEM),
+          pl.BlockSpec((1, block_k, dv), tile_kv, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, t, dv), full, memory_space=pltpu.VMEM),
           pl.BlockSpec((1, t // block_q, block_q), full,
                        memory_space=pltpu.VMEM),
@@ -400,12 +418,15 @@ def _pallas_backward(q, k, v, out, lse, do, causal: bool,
           (t, d, q.dtype), (block_k, d, k.dtype), (block_k, dv, v.dtype),
           (t, dv, do.dtype), (t // block_q, block_q, jnp.float32),
           (t // block_q, block_q, jnp.float32),
-          (block_k, d, k.dtype), (block_k, dv, v.dtype)),
+          (block_k, d, dk_dtype), (block_k, dv, dv_dtype)),
       interpret=interpret,
       name=KERNEL_NAMES[2],
   )(qr, kr, vr, dor, lse_rows, delta_rows)
-  return (_from_rows(dq, b, h), _from_rows(dk, b, h),
-          _from_rows(dv, b, h))
+  if group > 1:
+    dk, dv = (x.reshape(b * kv_heads, group, t, -1).sum(axis=1).astype(
+        like.dtype) for x, like in ((dk, k), (dv, v)))
+  return (_from_rows(dq, b, h), _from_rows(dk, b, kv_heads),
+          _from_rows(dv, b, kv_heads))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -434,7 +455,9 @@ def flash_attention(q, k, v, causal: bool = False,
   Args:
     q, k, v: (B, T, H, D) arrays (same layout as ring_attention); q and
       k share one head width, v may have its own (MLA: 192 and 128),
-      which is then the output's.
+      which is then the output's. k and v may have fewer heads than q
+      (grouped queries): each serves H / Hkv consecutive query heads,
+      and is neither repeated in HBM nor staged again for each.
     causal: apply a causal mask.
     scale: attention scale; default 1/sqrt(D).
     implementation: "pallas", "xla", or "auto" (pallas when T is

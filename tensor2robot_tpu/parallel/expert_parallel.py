@@ -1,9 +1,10 @@
 """Expert parallelism: one drop-free mixture-of-experts layer that is
 told which experts it holds.
 
-Every holder routes over ALL the layer's experts (sigmoid scores, the
-top k of score + correction bias, weights the scores of the chosen k
-over their sum, DeepSeek-V3's `noaux_tc`), and computes the part of the
+Every holder routes over ALL the layer's experts (`route`: sigmoid
+scores, the top k of score + correction bias, weights the scores of the
+chosen k over their sum, DeepSeek-V3's `noaux_tc`; or a softmax over all
+the experts, its top k renormalized, no bias), and computes the part of the
 result its own experts give: tokens are sorted by expert, the held
 experts' gated MLPs run as grouped matrix products over the sorted rows
 (`jax.lax.ragged_dot`: on TPU a grouped kernel that visits only the
@@ -40,7 +41,8 @@ class MoEParams(NamedTuple):
   """Router over all E experts + the H held experts' stacked gated MLPs.
 
   router: (D, E). bias: (E,), the score-correction bias: it moves the
-  choice and not the weights, and the loss gives it no gradient.
+  choice and not the weights, and the loss gives it no gradient; None
+  under softmax scoring, which has none.
   gate/up: (H, D, F). down: (H, F, D): the leading axis is what the
   `expert` mesh axis shards (then H = E).
   """
@@ -67,16 +69,29 @@ def init_moe_params(rng: jax.Array, num_experts: int, d_model: int,
   )
 
 
-def route(tokens: jnp.ndarray, router: jnp.ndarray, bias: jnp.ndarray,
-          top_k: int, scale: float = 1.0
-          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+SCORINGS = ("sigmoid", "softmax")
+
+
+def route(tokens: jnp.ndarray, router: jnp.ndarray,
+          bias: Optional[jnp.ndarray], top_k: int, scale: float = 1.0,
+          scoring: str = "sigmoid") -> Tuple[jnp.ndarray, jnp.ndarray]:
   """(N, D) tokens → ((N, k) expert ids, (N, k) float32 weights).
 
-  Float32 throughout, the product at full precision: a choice that
-  flipped on rounding would move a token's whole result."""
-  scores = jax.nn.sigmoid(jnp.dot(
+  `scoring` "sigmoid": each expert's own sigmoid, the choice by score +
+  `bias`; "softmax": a softmax over all E experts, the choice by score,
+  `bias` not read. Either way the weights are the chosen k scores over
+  their sum, times `scale`. Float32 throughout, the product at full
+  precision: a choice that flipped on rounding would move a token's
+  whole result."""
+  if scoring not in SCORINGS:
+    raise ValueError(f"scoring must be one of {SCORINGS}; got {scoring!r}")
+  logits = jnp.dot(
       tokens.astype(jnp.float32), router.astype(jnp.float32),
-      precision=jax.lax.Precision.HIGHEST))                    # (N, E)
+      precision=jax.lax.Precision.HIGHEST)                     # (N, E)
+  if scoring == "softmax":
+    chosen, index = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    return index, chosen / jnp.sum(chosen, axis=-1, keepdims=True) * scale
+  scores = jax.nn.sigmoid(logits)
   _, index = jax.lax.top_k(
       scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
   chosen = jnp.take_along_axis(scores, index, axis=-1)
@@ -148,14 +163,16 @@ def _counters(sizes, held, top_k: int) -> Dict[str, jnp.ndarray]:
 
 
 def moe_share(tokens: jnp.ndarray, params: MoEParams, first_expert: int,
-              top_k: int, scale: float = 1.0, compute_dtype=None):
+              top_k: int, scale: float = 1.0, compute_dtype=None,
+              scoring: str = "sigmoid"):
   """One holder's part of the layer: Σ over top-k ∩ held of w_i E_i(x).
 
   Args:
     tokens: (N, D).
     params: router over all E experts, H held experts' weights; the
       held experts are `first_expert .. first_expert + H - 1`.
-    top_k, scale: experts per token; factor on the normalized weights.
+    top_k, scale, scoring: experts per token; factor on the normalized
+      weights; how `route` scores.
     compute_dtype: dtype of the expert products' operands (float32
       accumulation); default the tokens'.
 
@@ -167,7 +184,8 @@ def moe_share(tokens: jnp.ndarray, params: MoEParams, first_expert: int,
   num_held = params.gate.shape[0]
   compute_dtype = compute_dtype or tokens.dtype
   with jax.named_scope("moe/route"):
-    index, weight = route(tokens, params.router, params.bias, top_k, scale)
+    index, weight = route(tokens, params.router, params.bias, top_k, scale,
+                          scoring)
     local = index - first_expert
     held = (local >= 0) & (local < num_held)                   # (N, k)
   with jax.named_scope("moe/dispatch"):

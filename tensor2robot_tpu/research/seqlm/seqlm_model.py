@@ -1,10 +1,14 @@
 """SequenceMoEModel: a decoder-only sparse-expert language model on the
 `AbstractT2RModel` contract, trained through `Trainer.train_steps`.
 
-The architecture is JoyAI-LLM-Flash's (DeepSeek-V3 layout: MLA
-attention, `first_k_dense_replace` dense blocks, then expert blocks of
-routed top-k experts plus a shared expert, a depth-1 multi-token
-prediction module), its sizes under their published names. The model
+Two layouts, by the configuration's sizes under their published names:
+JoyAI-LLM-Flash's (DeepSeek-V3: MLA attention, `first_k_dense_replace`
+dense blocks, then equal expert blocks of routed top-k experts plus a
+shared expert, a depth-1 multi-token prediction module), and, where
+`full_attention_interval` is set, Qwen3-Next's hybrid (periods of that
+many expert blocks, gated delta nets and then one gated grouped-query
+attention block; no dense lead). The MTP module is built where
+`num_nextn_predict_layers` says so. The model
 is told its share of a deployment: which `experts_held` of the
 `n_routed_experts` it computes (the router keeps its full width) and how
 many rows of the vocabulary it holds; ids, logits and loss are over that
@@ -12,8 +16,8 @@ slice.
 
 Features: `tokens`, int32 (B, T); no labels, no `batch_stats`. The
 module computes in `compute_dtype` with float32 parameters, recomputes
-each block on the backward pass and scans the equal expert blocks, so
-the program holds one of them.
+each block on the backward pass and scans the equal expert blocks (or
+the equal periods), so the program holds one of them.
 """
 
 from __future__ import annotations
@@ -30,6 +34,7 @@ from tensor2robot_tpu import modes
 from tensor2robot_tpu.config import configurable
 from tensor2robot_tpu.layers import sequence
 from tensor2robot_tpu.models.abstract_model import AbstractT2RModel, Metrics
+from tensor2robot_tpu.ops import gated_delta_rule
 from tensor2robot_tpu.specs import tensorspec_utils as ts
 
 _CONFIG_FIELDS = frozenset(
@@ -59,6 +64,38 @@ class _MTPModule(nn.Module):
     return norm("final_norm")(h), counters
 
 
+class _Period(nn.Module):
+  """`full_attention_interval` expert blocks, each recomputed on the
+  backward pass, their mixers by position in the period. Returns (x,
+  {"moe": the blocks' counters stacked, "gdn": the delta nets'})."""
+  config: sequence.SequenceConfig
+  dtype: Any
+
+  @nn.compact
+  def __call__(self, x, _=None):
+    c = self.config
+    moe, gdn = [], []
+    for i in range(c.full_attention_interval):
+      # CSE prevented: a scan of one period is unrolled, and the blocks'
+      # recomputation would then be merged with their first run. Of a
+      # block only the delta rule's chunk inverses are kept from the
+      # first run (67 MB a layer at T = 8,192): ten dependent products
+      # each, more than the rest of the chunk-local part together.
+      x, counters = nn.remat(
+          sequence.DecoderBlock,
+          policy=jax.checkpoint_policies.save_only_these_names(
+              gated_delta_rule.INVERSE_NAME))(
+                  c, True, self.dtype, c.layer_kind(i), name=f"block{i}")(x)
+      gates = {k: counters.pop(k) for k in list(counters)
+               if k.startswith("gdn/")}
+      moe.append(counters)
+      if gates:
+        gdn.append(gates)
+    stack = lambda rows: jax.tree_util.tree_map(
+        lambda *leaves: jnp.stack(leaves), *rows)
+    return x, {"moe": stack(moe), "gdn": stack(gdn)}
+
+
 class _SequenceModule(nn.Module):
   config: sequence.SequenceConfig
   dtype: Any = jnp.bfloat16
@@ -75,18 +112,30 @@ class _SequenceModule(nn.Module):
     for i in range(c.first_k_dense_replace):
       x, _ = nn.remat(sequence.DecoderBlock)(
           c, False, self.dtype, name=f"dense_block{i}")(x)
-    stack = nn.scan(
-        nn.remat(sequence.DecoderBlock, prevent_cse=False),
-        variable_axes={"params": 0}, split_rngs={"params": True},
-        length=c.num_expert_layers)
-    x, counters = stack(c, True, self.dtype, name="expert_blocks")(x, None)
-    final = sequence.RMSNorm(c.rms_norm_eps, self.dtype, name="final_norm")
+    outputs = {}
+    if c.hybrid:
+      stack = nn.scan(
+          _Period, variable_axes={"params": 0}, split_rngs={"params": True},
+          length=c.num_hidden_layers // c.full_attention_interval)
+      x, counters = stack(c, self.dtype, name="periods")(x, None)
+      # (periods, blocks a period, ...) -> (layers, ...), in layer order.
+      counters = jax.tree_util.tree_map(
+          lambda a: a.reshape((-1,) + a.shape[2:]), counters)
+      outputs["gdn_counters"], counters = counters["gdn"], counters["moe"]
+    else:
+      stack = nn.scan(
+          nn.remat(sequence.DecoderBlock, prevent_cse=False),
+          variable_axes={"params": 0}, split_rngs={"params": True},
+          length=c.num_expert_layers)
+      x, counters = stack(c, True, self.dtype, name="expert_blocks")(x, None)
+    final = sequence.RMSNorm(c.rms_norm_eps, self.dtype,
+                             c.zero_centered_norm, name="final_norm")
     if mode == modes.PREDICT:
       with jax.named_scope("lm_head"):
         return {"logits": jnp.dot(final(x), head.astype(self.dtype),
                                   preferred_element_type=jnp.float32)}
-    outputs = {"token_loss_main": sequence.token_losses(
-        final(x), head, jnp.roll(tokens, -1, axis=1))}
+    outputs["token_loss_main"] = sequence.token_losses(
+        final(x), head, jnp.roll(tokens, -1, axis=1))
     if c.num_nextn_predict_layers:
       with jax.named_scope("mtp"):
         h, extra = _MTPModule(c, self.dtype, name="mtp")(
@@ -101,7 +150,8 @@ class _SequenceModule(nn.Module):
 
 @configurable
 class SequenceMoEModel(AbstractT2RModel):
-  """Next-token (+ MTP) training of one chip's share of the model."""
+  """Next-token (+ MTP where configured) training of one chip's share
+  of the model."""
 
   def __init__(self, sequence_length: int = 8192, **kwargs):
     """`sequence_length` tokens a sequence; every field of
@@ -154,4 +204,8 @@ class SequenceMoEModel(AbstractT2RModel):
         "moe/max_expert_tokens": jnp.max(per_expert),
         "moe/min_expert_tokens": jnp.min(per_expert),
     })
+    # (linear layers,): the mean of exp(g) and of β over tokens and heads;
+    # a decay stuck at 0 or 1 is a layer that forgets everything or
+    # nothing.
+    metrics.update(outputs.get("gdn_counters", {}))
     return loss, metrics

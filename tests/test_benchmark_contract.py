@@ -181,14 +181,16 @@ def test_span_the_benchmark_reads_is_opened_by_the_program(span_name):
 # --- 2. kernel names -----------------------------------------------------------
 
 
-def test_kernel_names_are_the_pallas_calls_names():
-  """`mla_attention_roofline.train` finds the kernel's device events by
-  `ops/flash_attention.KERNEL_NAMES`; a program named otherwise, or a
-  fourth one the tuple lacks, makes it read None."""
+@pytest.mark.parametrize("kernel", ["flash_attention", "gated_delta_rule"])
+def test_kernel_names_are_the_pallas_calls_names(kernel):
+  """`mla_attention_roofline.train` finds the flash kernel's device events
+  by `ops/flash_attention.KERNEL_NAMES`, `gated_delta_rule_roofline.train`
+  and `linear_attention_time_share.train` the delta rule's by
+  `ops/gated_delta_rule.KERNEL_NAMES`; a program named otherwise, or one
+  more the tuple lacks, makes them read None."""
   # `ops/__init__.py` re-exports the function under the module's name.
-  flash_attention = importlib.import_module(
-      "tensor2robot_tpu.ops.flash_attention")
-  tree = _parse(os.path.join(PACKAGE, "ops", "flash_attention.py"))
+  module = importlib.import_module(f"tensor2robot_tpu.ops.{kernel}")
+  tree = _parse(os.path.join(PACKAGE, "ops", f"{kernel}.py"))
   named = []
   for node in ast.walk(tree):
     if not (isinstance(node, ast.Call)
@@ -200,9 +202,38 @@ def test_kernel_names_are_the_pallas_calls_names():
     else:  # KERNEL_NAMES[i]
       assert (isinstance(name, ast.Subscript)
               and name.value.id == "KERNEL_NAMES"), ast.dump(name)
-      named.append(flash_attention.KERNEL_NAMES[name.slice.value])
-  assert sorted(named) == sorted(flash_attention.KERNEL_NAMES)
+      named.append(module.KERNEL_NAMES[name.slice.value])
+  assert sorted(named) == sorted(module.KERNEL_NAMES)
   assert len(set(named)) == len(named)
+
+
+def test_the_drivers_read_the_kernels_by_the_program_s_names():
+  """The drivers' trace readers import the tuples, they do not spell the
+  names: `train_resident_tokens` the flash kernel's,
+  `train_resident_hybrid` the delta rule's."""
+  for driver, module in (("train_resident_tokens", "flash_attention"),
+                         ("train_resident_hybrid", "gated_delta_rule")):
+    imports = _program_imports(
+        os.path.join(BENCHMARK, "drivers", f"{driver}.py"))
+    assert (f"tensor2robot_tpu.ops.{module}", "KERNEL_NAMES") in imports
+
+
+@pytest.mark.parametrize("metric", sorted(
+    os.path.basename(p)[:-3]
+    for p in glob.glob(os.path.join(BENCHMARK, "layer_metrics", "*.py"))))
+def test_layer_metric_reader_loads_and_reads_nothing_from_an_empty_run(
+    metric):
+  """Every reader BENCHMARK.json can name resolves to a `read(run)`, and
+  a kernel's reader leaves its metric out (None, no raise) where the run
+  has no record of that kernel: an untraced run, or a program without
+  it."""
+  from benchmark import harness
+  read = harness._load_module("layer_metrics", metric).read
+  assert callable(read)
+  if "roofline" in metric or "linear_attention" in metric:
+    run = {"window": {}, "trace": None, "peaks": {}, "chips": 1,
+           "cell": harness.load_cell("joyai_flash_train_seq8k")}
+    assert read(run) is None
 
 
 # --- 3. what the drivers and configurations import -----------------------------

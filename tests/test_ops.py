@@ -350,11 +350,11 @@ class TestDispatch:
       flash_attention(big, big, big, implementation="pallas")
 
   def test_flash_attention_vmem_guard_counts_k_and_v_at_own_widths(self):
-    # T = 10240: K at 192 and V at 128 are 13.1 MB double-buffered,
-    # inside the 14 MB guard; both counted at q's 192 would be 15.7 MB.
+    # T = 12288: K at 192 and V at 128 are 15.7 MB double-buffered,
+    # inside the 16 MiB guard; both counted at q's 192 would be 18.9 MB.
     from tensor2robot_tpu.ops.flash_attention import _supported
-    wide = jax.ShapeDtypeStruct((1, 10240, 1, 192), jnp.bfloat16)
-    narrow = jax.ShapeDtypeStruct((1, 10240, 1, 128), jnp.bfloat16)
+    wide = jax.ShapeDtypeStruct((1, 12288, 1, 192), jnp.bfloat16)
+    narrow = jax.ShapeDtypeStruct((1, 12288, 1, 128), jnp.bfloat16)
     assert _supported(wide, wide, narrow) is None
     assert "VMEM" in _supported(wide, wide, wide)
 

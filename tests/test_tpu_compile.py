@@ -62,3 +62,50 @@ def test_flash_attention_compiles_at_8k_forward_and_backward(
                "flash_attention_dkv"):
     assert name in text
   assert text.count("tpu_custom_call") >= 3
+
+
+def test_flash_attention_compiles_at_8k_grouped_256_wide(one_chip, as_on_tpu):
+  """The hybrid model's full-attention shapes: 16 query heads on 2
+  key/value heads, 256-wide, T = 8,192, bf16. K and V rows are staged
+  whole, 16 MiB double-buffered, and the dk/dv program writes the
+  group's parts in float32."""
+  from tensor2robot_tpu.ops.flash_attention import flash_attention
+  shape = lambda heads: jax.ShapeDtypeStruct(
+      (1, 8192, heads, 256), jnp.bfloat16, sharding=one_chip)
+
+  def loss(q, k, v):
+    out = flash_attention(q, k, v, causal=True, implementation="pallas")
+    return jnp.sum(out.astype(jnp.float32))
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+      shape(16), shape(2), shape(2)).compile()
+  text = compiled.as_text()
+  for name in ("flash_attention_fwd", "flash_attention_dq",
+               "flash_attention_dkv"):
+    assert name in text
+  assert text.count("tpu_custom_call") >= 3
+
+
+def test_gated_delta_rule_compiles_at_8k_forward_and_backward(one_chip,
+                                                              as_on_tpu):
+  """The delta net's shapes: 16 q/k heads and 32 value heads of 128,
+  T = 8,192 in chunks of 64, bf16 operands with float32 decays: both
+  walks contract over the chunk's rows (a transposed left operand),
+  which interpret mode takes whatever Mosaic makes of it."""
+  import importlib
+  rule = importlib.import_module("tensor2robot_tpu.ops.gated_delta_rule")
+  shape = lambda heads, dtype=jnp.bfloat16, width=(128,): (
+      jax.ShapeDtypeStruct((1, 8192, heads) + width, dtype,
+                           sharding=one_chip))
+  gate = shape(32, jnp.float32, ())
+
+  def loss(q, k, v, g, beta):
+    out = rule.gated_delta_rule(q, k, v, g, beta, implementation="pallas")
+    return jnp.sum(out.astype(jnp.float32))
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+      shape(16), shape(16), shape(32), gate, gate).compile()
+  text = compiled.as_text()
+  for name in rule.KERNEL_NAMES:
+    assert name in text
+  assert text.count("tpu_custom_call") >= 2
