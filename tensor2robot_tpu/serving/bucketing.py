@@ -55,11 +55,3 @@ class BucketLadder:
       raise ValueError(
           f"batch size {n} outside ladder (1..{self.max_batch})")
     return self.sizes[bisect.bisect_left(self.sizes, n)]
-
-  def pad_batch(self, batch: np.ndarray) -> Tuple[np.ndarray, int]:
-    """Pads (n, ...) up to its bucket on axis 0; returns (padded, bucket).
-
-    See pad_to for the shared padding strategy.
-    """
-    bucket = self.bucket_for(batch.shape[0])
-    return pad_to(batch, bucket), bucket

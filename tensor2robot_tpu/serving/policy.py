@@ -34,6 +34,43 @@ from tensor2robot_tpu.serving import bucketing
 from tensor2robot_tpu.serving.bucketing import BucketLadder
 
 
+class _StagingPool:
+  """Host arrays a flush's frames are stacked into, kept between calls.
+
+  A rung-32 batch of 472x472x3 float32 frames is 85 MB: an array of
+  that size made new per flush is mapped, faulted in page by page while
+  it is copied into, and unmapped after, on the process's address-space
+  lock and beside another dispatcher doing the same. `take` hands out a
+  free array of the key or makes one; `give` takes it back. An array
+  that is out belongs to its caller alone, so the pool holds as many
+  per key as callers were ever inside the policy at once. A caller that
+  fails keeps its array from the pool: it is dropped with the call.
+  """
+
+  def __init__(self):
+    self._lock = threading.Lock()
+    # ((bucket, *row shape), dtype) -> [arrays not in use]
+    self._free = {}
+
+  def take(self, shape, dtype):
+    """(array of `shape` and `dtype`, whether the pool already held
+    it)."""
+    with self._lock:
+      free = self._free.get((shape, dtype))
+      if free:
+        return free.pop(), True
+    return np.empty(shape, dtype), False
+
+  def give(self, array: np.ndarray) -> None:
+    with self._lock:
+      self._free.setdefault((array.shape, array.dtype), []).append(array)
+
+  def sizes(self):
+    """{key: arrays free now}: with no call open, all the pool holds."""
+    with self._lock:
+      return {key: len(free) for key, free in self._free.items()}
+
+
 class CEMFleetPolicy:
   """Batched CEM serving policy over any predictor with ``q_predicted``.
 
@@ -123,6 +160,12 @@ class CEMFleetPolicy:
     # caller at a time.
     self._turn = threading.Lock()
     self._next_seed = 0
+    # Where each flush's frames are stacked (see _StagingPool), and
+    # whether this thread's last call found its array there: the
+    # replica's stats feed reads it back after its own call
+    # (router.PolicyReplica._flush), beside the other dispatcher's.
+    self._staging = _StagingPool()
+    self._last_call = threading.local()
 
   @property
   def executable_buckets(self) -> Sequence[int]:
@@ -165,9 +208,22 @@ class CEMFleetPolicy:
     has no per-call score readout and returns ``(actions, None)``."""
     n = len(images)
     bucket = self.ladder.bucket_for(n)
-    with trace_lib.span("serve/stack", rows=n,
-                        bytes=n * np.asarray(images[0]).nbytes):
-      batch = np.stack([np.asarray(image) for image in images])
+    frames = [np.asarray(image) for image in images]
+    first = frames[0]
+    # Out of the pool until this call's program has consumed it: the
+    # runtime reads the array after device_put has returned, and on the
+    # CPU backend the device array may be the host memory itself. A
+    # call that raises never gives it back.
+    staged, reused = self._staging.take((bucket,) + first.shape,
+                                        first.dtype)
+    self._last_call.reused = reused
+    with trace_lib.span("serve/stack", rows=n, bytes=n * first.nbytes,
+                        reused=int(reused)):
+      if any(frame.dtype != first.dtype for frame in frames):
+        raise ValueError(
+            "all input arrays must have the same dtype, got "
+            f"{sorted({str(frame.dtype) for frame in frames})}")
+      np.stack(frames, out=staged[:n])
       seeds = (self.assign_seeds(n) if seeds is None
                else np.asarray(seeds, np.uint32))
       if seeds.shape != (n,):
@@ -180,23 +236,29 @@ class CEMFleetPolicy:
             "variables override requires the predictor's device path "
             "(the host fallback scores through predictor.predict, whose "
             "params cannot be swapped per call).")
-      with self._turn:  # predictor.predict was never called concurrently
-        actions = self._host_call(batch, seeds)
-      return (actions, None) if return_scores else actions
-    variables = self._place(
-        live_variables if variables is None else variables)
+      fn = None  # the host fallback, once the rows are padded
+    else:
+      variables = self._place(
+          live_variables if variables is None else variables)
     with trace_lib.span("serve/pad", bucket=bucket):
-      padded, _ = self.ladder.pad_batch(batch)
-      padded_seeds, _ = self.ladder.pad_batch(seeds)
+      padded_seeds = seeds
+      if n < bucket:  # bucketing.pad_to's rule, the frames' in place
+        staged[n:] = staged[n - 1]
+        padded_seeds = bucketing.pad_to(seeds, bucket)
+    if fn is None:
+      with self._turn:  # predictor.predict was never called concurrently
+        actions = self._host_call(staged, padded_seeds)[:n]
+      self._staging.give(staged)
+      return (actions, None) if return_scores else actions
     # A compile or a weight upload (above) lies outside serve/put: it
     # must not read as H2D.
-    compiled = self._executable_for(bucket, fn, variables, padded,
+    compiled = self._executable_for(bucket, fn, variables, staged,
                                     padded_seeds)
     # Returns once the runtime has the transfer: its threads re-lay the
     # frames out and copy them while this thread goes on.
     with trace_lib.span("serve/put",
-                        bytes=padded.nbytes + padded_seeds.nbytes) as put:
-      device_images = self._put(padded)
+                        bytes=staged.nbytes + padded_seeds.nbytes) as put:
+      device_images = self._put(staged)
       device_seeds = self._put(padded_seeds)
     # The wait for another caller's program, if one is on the device:
     # the transfer above goes on beside it.
@@ -213,12 +275,21 @@ class CEMFleetPolicy:
         scores = np.asarray(scores)[:n]
     finally:
       self._turn.release()
+    # The program has run, so the transfer it waited for is over.
+    self._staging.give(staged)
     if self._ledger is not None:
       # Dispatch through completion, on the spans' own clock reads.
       self._ledger.record_dispatch(
           self._ledger_key(bucket),
           readback["ts_s"] + readback["dur_s"] - put["ts_s"])
     return (actions, scores) if return_scores else actions
+
+  @property
+  def last_call_reused_staging(self) -> bool:
+    """Whether the calling thread's last call stacked its frames into
+    an array the pool already held (its `serve/stack` span's
+    `reused`)."""
+    return getattr(self._last_call, "reused", False)
 
   @property
   def device_label(self) -> Optional[str]:
@@ -362,12 +433,13 @@ class CEMFleetPolicy:
     state (same fold_in sequence), so host and device paths agree the
     way CEMPolicy's do.
 
-    Shape discipline (ISSUE 5 satellite): the request batch is padded
-    to its ladder bucket ONCE, before the CEM loop — an exact-fit batch
-    (n already a ladder rung) is passed through with ZERO padding work
-    — and every per-iteration scoring call then carries the same
-    (bucket * num_samples) flat shape, so predict() sees exactly one
-    flat shape per bucket (the executable count stays ladder-bounded).
+    Shape discipline (ISSUE 5 satellite): `batch` and `seeds` come
+    padded to their ladder bucket ONCE, before the CEM loop (__call__'s
+    staging array; an exact-fit batch, n already a ladder rung, with
+    ZERO padding work), and the bucket's rows are returned: every
+    per-iteration scoring call carries the same (bucket * num_samples)
+    flat shape, so predict() sees exactly one flat shape per bucket
+    (the executable count stays ladder-bounded).
     The old path re-derived a power-of-two bucket for the flat batch
     inside predict_batched on EVERY CEM iteration, re-padding and
     re-slicing the tiled image stack each time even when the request
@@ -385,12 +457,7 @@ class CEMFleetPolicy:
           f"{cem.SCORING_PRECISIONS} only 'f32' can serve host-side — "
           "serve the f32 tier, or use a device-resident predictor.")
     num = self._num_samples
-    n = batch.shape[0]
-    bucket = self.ladder.bucket_for(n)
-    if bucket != n:
-      batch = bucketing.pad_to(batch, bucket)
-      seeds = bucketing.pad_to(seeds, bucket)
-    b = bucket
+    b = batch.shape[0]
     base = jax.random.key(self._seed)
     keys = jax.vmap(lambda s: jax.random.fold_in(base, s))(
         jnp.asarray(seeds))
@@ -409,4 +476,4 @@ class CEMFleetPolicy:
           "action": np.asarray(samples, np.float32).reshape(b * num, -1)})
       scores = jnp.asarray(outputs["q_predicted"]).reshape(b, num)
       mean, std = refit(samples, scores, self._num_elites)
-    return np.asarray(jnp.clip(mean, -1.0, 1.0))[:n]
+    return np.asarray(jnp.clip(mean, -1.0, 1.0))

@@ -138,13 +138,16 @@ class PolicyReplica:
                                return_scores=True)
       if scores is not None:
         # Served-Q sketch feed (ISSUE 15): free scores off the same
-        # dispatch, and whether its program encoded each frame once;
-        # exception-isolated — diagnostics never fail a flush (the
-        # listener contract).
+        # dispatch, whether its program encoded each frame once and
+        # whether its frames were stacked into a staging array the
+        # policy had kept; exception-isolated — diagnostics never fail
+        # a flush (the listener contract).
         try:
           self.stats.record_q_values(str(self.device), scores)
           if policy.encode_once.get(policy.ladder.bucket_for(len(items))):
             self.stats.record_encode_once_flush()
+          if policy.last_call_reused_staging:
+            self.stats.record_staged_flush()
         except Exception:
           pass
       if self._episode_recorder is not None:

@@ -154,6 +154,7 @@ class ServingStats:
     self._deadline_flushes = 0  # flushed by deadline, not by a full batch
     self._overlapped_flushes = 0  # popped while another flush was open
     self._encode_once_flushes = 0  # dispatched to an encode-once program
+    self._staged_flushes = 0  # stacked into a staging array that was kept
     self._queue_depth_sum = 0   # queue depth left behind at flush time
     self._per_class: Dict[str, _ClassStats] = {}
     self._q_sketches: Dict[str, QSketch] = {}
@@ -267,6 +268,13 @@ class ServingStats:
     with self._lock:
       self._encode_once_flushes += 1
 
+  def record_staged_flush(self) -> None:
+    """One replica dispatch whose frames were stacked into a host
+    staging array the policy already held (the `serve/stack` span's
+    `reused`), not into one mapped and faulted in for this flush."""
+    with self._lock:
+      self._staged_flushes += 1
+
   def record_latency_ms(self, latency_ms: float,
                         class_name: Optional[str] = None) -> None:
     self.latency.record(latency_ms)
@@ -301,6 +309,7 @@ class ServingStats:
           "deadline_flushes": self._deadline_flushes,
           "overlapped_flushes": self._overlapped_flushes,
           "encode_once_flushes": self._encode_once_flushes,
+          "staged_flushes": self._staged_flushes,
           "flush_overlap_share": round(
               self._overlapped_flushes / flushes, 4) if flushes else None,
           "batch_occupancy": round(

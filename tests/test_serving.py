@@ -34,16 +34,17 @@ class TestBucketLadder:
     with pytest.raises(ValueError):
       ladder.bucket_for(17)
 
-  def test_pad_batch_repeats_last_row(self):
-    from tensor2robot_tpu.serving.bucketing import BucketLadder
+  def test_pad_to_repeats_last_row(self):
+    from tensor2robot_tpu.serving.bucketing import BucketLadder, pad_to
     ladder = BucketLadder((1, 2, 4))
     batch = np.arange(6, dtype=np.float32).reshape(3, 2)
-    padded, bucket = ladder.pad_batch(batch)
+    bucket = ladder.bucket_for(len(batch))
+    padded = pad_to(batch, bucket)
     assert bucket == 4 and padded.shape == (4, 2)
     np.testing.assert_array_equal(padded[:3], batch)
     np.testing.assert_array_equal(padded[3], batch[2])
-    exact, bucket = ladder.pad_batch(batch[:2])
-    assert bucket == 2 and exact.shape == (2, 2)
+    exact = batch[:2]  # an exact fit is handed back as it came
+    assert ladder.bucket_for(2) == 2 and pad_to(exact, 2) is exact
 
   def test_invalid_ladder(self):
     from tensor2robot_tpu.serving.bucketing import BucketLadder
@@ -870,12 +871,13 @@ class TestCEMFleetPolicy:
     # One flat scoring shape (one executable's worth of work), one
     # call per CEM iteration — nothing extra.
     assert flat_sizes == [4 * num] * iterations
-    # Non-exact fit pads ONCE up front (batch + seeds at the request
-    # level), never per iteration, and scores the same bucket shape.
+    # Non-exact fit pads ONCE up front (the seeds through pad_to, the
+    # frames in place in the staging array), never per iteration, and
+    # scores the same bucket shape.
     pad_sizes.clear()
     flat_sizes.clear()
     assert policy(images[:3]).shape == (3, 4)
-    assert pad_sizes == [4, 4]
+    assert pad_sizes == [4]
     assert flat_sizes == [4 * num] * iterations
 
   def test_host_fallback_matches_device_path(self, tiny_predictor):
@@ -1053,6 +1055,236 @@ class TestCEMFleetPolicy:
     (row,) = ledger.attribution()["executables"]
     assert row["compiles"] == 1 and row["dispatches"] == 1
     assert 0.0 < row["seconds_total"] < 60.0
+
+  # -- staging buffers (ISSUE 37) -------------------------------------------
+
+  _STAGED = dict(action_size=4, num_samples=32, num_elites=4, iterations=2,
+                 seed=3)
+
+  @staticmethod
+  def _round(predictor, caller, r):
+    """Caller `caller`'s round `r`: its own frames and seeds, 4 rows or
+    3 (both rung 4, so both callers share one key of the pool)."""
+    n = 4 - (r + caller) % 2
+    base = 1000 * (caller + 1) + 10 * r
+    return ([predictor.make_image(base + i) for i in range(n)],
+            np.arange(base, base + n, dtype=np.uint32))
+
+  def _two_callers(self, policy, predictor, rounds):
+    """Both callers' rounds from two threads at once: [[(actions,
+    scores)] per round] per caller."""
+    results, barrier = [[], []], threading.Barrier(2)
+
+    def call(caller):
+      barrier.wait(timeout=10)
+      for r in range(rounds):
+        results[caller].append(policy(*self._round(predictor, caller, r),
+                                      return_scores=True))
+
+    threads = [threading.Thread(target=call, args=(k,)) for k in (0, 1)]
+    for thread in threads:
+      thread.start()
+    for thread in threads:
+      thread.join(timeout=120)
+      assert not thread.is_alive()
+    return results
+
+  def test_two_callers_side_by_side_get_a_lone_callers_answers(
+      self, tiny_predictor):
+    """Two threads calling one policy with different frames for some
+    dozens of rounds get, row for row, what one caller alone gets for
+    the same frames and seeds: no staging array is written while
+    another flush's program still reads it (on a backend whose device
+    array is the host memory, or whose transfer is still under way)."""
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+
+    rounds = 36
+    alone = CEMFleetPolicy(tiny_predictor, **self._STAGED)
+    expected = [[alone(*self._round(tiny_predictor, k, r), return_scores=True)
+                 for r in range(rounds)] for k in (0, 1)]
+    policy = CEMFleetPolicy(tiny_predictor, **self._STAGED)
+    results = self._two_callers(policy, tiny_predictor, rounds)
+    for got_rounds, want_rounds in zip(results, expected):
+      assert len(got_rounds) == rounds
+      for (actions, scores), (want_actions, want_scores) in zip(
+          got_rounds, want_rounds):
+        np.testing.assert_array_equal(actions, want_actions)
+        np.testing.assert_array_equal(scores, want_scores)
+    assert policy.compile_counts == {4: 1}
+
+  def test_pool_grows_to_the_callers_inside_at_once_and_no_further(
+      self, tiny_predictor):
+    """A sequential caller keeps one array per (bucket and row shape,
+    dtype), two callers side by side at most two; while a call's
+    program runs, its array is out of the pool."""
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+
+    policy = CEMFleetPolicy(tiny_predictor, **self._STAGED)
+    key = ((4, 8, 8, 3), np.dtype(np.float32))
+    for r in range(6):
+      policy(*self._round(tiny_predictor, 0, r))
+    assert policy._staging.sizes() == {key: 1}
+    policy([tiny_predictor.make_image(0)])
+    assert policy._staging.sizes() == {
+        key: 1, ((1, 8, 8, 3), np.dtype(np.float32)): 1}
+    real, free_inside = policy._executables[4], []
+
+    def watched(*args):
+      free_inside.append(policy._staging.sizes()[key])
+      return real(*args)
+
+    policy._executables[4] = watched
+    policy(*self._round(tiny_predictor, 0, 0))
+    assert free_inside == [0] and policy._staging.sizes()[key] == 1
+    policy._executables[4] = real
+    self._two_callers(policy, tiny_predictor, rounds=24)
+    assert 1 <= policy._staging.sizes()[key] <= 2
+    # A swapped-in policy (use_policy, a tier change) brings its own.
+    assert CEMFleetPolicy(tiny_predictor, **self._STAGED)._staging.sizes() == {}
+
+  def test_partial_flush_is_padded_in_place_with_its_last_row(
+      self, tiny_predictor):
+    """3 frames on rung 4: each scores as it does alone, and the
+    staging array's fourth row is its third (bucketing.pad_to's rule),
+    whatever an earlier, fuller flush left there."""
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+
+    policy = CEMFleetPolicy(tiny_predictor, **self._STAGED)
+    images, seeds = self._round(tiny_predictor, 0, 0)
+    assert len(images) == 4
+    full_actions, full_scores = policy(images, seeds, return_scores=True)
+    actions, scores = policy(images[:3], seeds[:3], return_scores=True)
+    np.testing.assert_array_equal(actions, full_actions[:3])
+    np.testing.assert_array_equal(scores, full_scores[:3])
+    for i in range(3):
+      lone_action, lone_score = policy(images[i:i + 1], seeds[i:i + 1],
+                                       return_scores=True)
+      np.testing.assert_allclose(actions[i], lone_action[0], atol=1e-5)
+      np.testing.assert_allclose(scores[i], lone_score[0], atol=1e-5)
+    (staged,) = policy._staging._free[((4, 8, 8, 3), np.dtype(np.float32))]
+    np.testing.assert_array_equal(staged[:3], np.stack(images[:3]))
+    np.testing.assert_array_equal(staged[3], staged[2])
+
+  def test_stack_span_says_reused_and_the_stats_count_it(
+      self, tiny_predictor):
+    """`serve/stack` carries `reused` 0 on a key's first call and 1
+    after; `snapshot()["staged_flushes"]` counts the replica's flushes
+    that found their array in the pool."""
+    import jax
+
+    from tensor2robot_tpu.obs import trace as trace_lib
+    from tensor2robot_tpu.obs.registry import MetricRegistry
+    from tensor2robot_tpu.serving.router import FleetRouter
+    from tensor2robot_tpu.serving.stats import ServingStats
+
+    stats = ServingStats(registry=MetricRegistry())
+    router = FleetRouter(
+        tiny_predictor, devices=jax.devices()[:1], num_samples=16,
+        num_elites=4, iterations=2, seed=0, ladder_sizes=(1, 4),
+        stats=stats)
+    assert stats.snapshot()["staged_flushes"] == 0
+    reused = []
+    with router:  # not warmed: the first flush of a rung makes its array
+      for flush, n in enumerate((3, 4, 2, 1, 1)):
+        ids = [f"staged-{flush}-{i}" for i in range(n)]
+        with router.replicas[0].batcher.hold_flushes():
+          futures = [router.submit(tiny_predictor.make_image(i),
+                                   request_id=rid)
+                     for i, rid in enumerate(ids)]
+        [f.result(timeout=30) for f in futures]
+        (stack,) = [s for s in trace_lib.get_tracer().spans()
+                    if s["name"] == "serve/stack"
+                    and str(s.get("request_ids", "")) == ",".join(ids)]
+        assert stack["rows"] == n
+        reused.append(stack["reused"])
+    assert reused == [0, 1, 1, 0, 1]
+    snapshot = stats.snapshot()
+    assert snapshot["flushes"] == 5 and snapshot["staged_flushes"] == 3
+
+  def test_a_call_that_raises_drops_its_array(self, tiny_predictor):
+    """A program that fails leaves the pool without that call's array
+    (nobody knows what still reads it), and the next call sound."""
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+
+    policy = CEMFleetPolicy(tiny_predictor, **self._STAGED)
+    key = ((4, 8, 8, 3), np.dtype(np.float32))
+    images, seeds = self._round(tiny_predictor, 0, 0)
+    want_actions, want_scores = policy(images, seeds, return_scores=True)
+    real = policy._executables[4]
+
+    def failing(*args):
+      raise RuntimeError("device lost")
+
+    policy._executables[4] = failing
+    with pytest.raises(RuntimeError, match="device lost"):
+      policy(images, seeds)
+    assert policy._staging.sizes()[key] == 0
+    assert not policy._turn.locked()
+    policy._executables[4] = real
+    actions, scores = policy(*self._round(tiny_predictor, 1, 0),
+                             return_scores=True)  # other frames between
+    assert not policy.last_call_reused_staging
+    actions, scores = policy(images, seeds, return_scores=True)
+    assert policy.last_call_reused_staging
+    np.testing.assert_array_equal(actions, want_actions)
+    np.testing.assert_array_equal(scores, want_scores)
+    assert policy._staging.sizes()[key] == 1
+
+  @pytest.mark.parametrize("path", ["host_fallback", "variables_override"])
+  def test_every_path_stacks_into_the_same_arrays(self, tiny_predictor, path):
+    """The host fallback (a predictor without device_fn) and a shadow
+    call with `variables=` take their array from the policy's pool and
+    give it back, as a live flush does."""
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+
+    class HostOnly:
+      def __init__(self, inner):
+        self._inner = inner
+
+      def device_fn(self):
+        raise NotImplementedError
+
+      def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    key = ((4, 8, 8, 3), np.dtype(np.float32))
+    images, seeds = self._round(tiny_predictor, 0, 1)
+    assert len(images) == 3
+    want = CEMFleetPolicy(tiny_predictor, **self._STAGED)(images, seeds)
+    if path == "host_fallback":
+      policy = CEMFleetPolicy(HostOnly(tiny_predictor), **self._STAGED)
+      call = lambda: policy(images, seeds)
+      atol = 1e-4  # test_host_fallback_matches_device_path's
+    else:
+      policy = CEMFleetPolicy(tiny_predictor, **self._STAGED)
+      healthy = tiny_predictor.make_candidate_variables()  # bit-equal Q
+      call = lambda: policy(images, seeds, variables=healthy)
+      atol = 0.0
+    policy(*self._round(tiny_predictor, 1, 0))  # a live flush of 4 before
+    assert not policy.last_call_reused_staging
+    for _ in range(2):
+      np.testing.assert_allclose(call(), want, atol=atol)
+      assert policy.last_call_reused_staging
+      assert policy._staging.sizes() == {key: 1}
+    (staged,) = policy._staging._free[key]
+    np.testing.assert_array_equal(staged[:3], np.stack(images))
+    np.testing.assert_array_equal(staged[3], staged[2])
+
+  @pytest.mark.parametrize("odd", ["shape", "dtype"])
+  def test_a_frame_unlike_the_first_is_refused(self, tiny_predictor, odd):
+    """A frame of another shape or dtype than the flush's first raises
+    ValueError before any program runs; the flush after it is sound."""
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+
+    policy = CEMFleetPolicy(tiny_predictor, **self._STAGED)
+    images, seeds = self._round(tiny_predictor, 0, 0)
+    want = policy(images, seeds)
+    bad = list(images)
+    bad[2] = (np.zeros((4, 8, 3), np.float32) if odd == "shape"
+              else images[2].astype(np.float64))
+    with pytest.raises(ValueError, match=f"same {odd}"):
+      policy(bad, seeds)
+    np.testing.assert_array_equal(policy(images, seeds), want)
 
 
 class TestPredictBatched:
