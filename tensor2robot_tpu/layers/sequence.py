@@ -1,23 +1,28 @@
 """Decoder blocks of a sparse-expert sequence model: RMSNorm (plain or
-zero-centred), rotary positions (interleaved over the whole width, or
-half-split over the head's first part), three token mixers (multi-head
-latent attention, gated grouped-query attention, the gated delta net;
-training forms), gated MLP, the expert layer (routing and grouping in
-`parallel/expert_parallel`) and a chunked next-token loss.
+zero-centred), rotary positions (interleaved over the whole width, plain
+or under YaRN, or half-split over the head's first part), three token
+mixers (multi-head latent attention, gated grouped-query attention, the
+gated delta net; training forms), gated MLP, the expert layer (routing
+and grouping in `parallel/expert_parallel`), the residual rule (plain,
+or manifold-constrained hyper-connections over `hc_mult` streams) and a
+chunked next-token loss.
 
-Layer equations: DeepSeek-V2/V3's, which the JoyAI-LLM-Flash config
-follows, and Qwen3-Next's (gated attention and Gated Delta Networks,
-arXiv:2412.06464); see `SequenceConfig`'s fields and each module.
+Layer equations: DeepSeek-V2/V3's, which the JoyAI-LLM-Flash and
+Xing4.0 configs follow, Qwen3-Next's (gated attention and Gated Delta
+Networks, arXiv:2412.06464) and mHC's (arXiv:2512.24880); see
+`SequenceConfig`'s fields and each module.
 Activations run in `dtype` (bfloat16), parameters are float32; norms,
-rotary angles, the router, the softmax statistics, and the delta net's
-decays g, write strengths b, L2 norms and state are float32.
+rotary angles, the router, the softmax statistics, the delta net's
+decays g, write strengths b, L2 norms and state, and the
+hyper-connections' stream RMS, maps, Sinkhorn and weighted sums'
+accumulation are float32.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any
+from typing import Any, Optional
 
 import flax.linen as nn
 import jax
@@ -26,6 +31,8 @@ import jax.numpy as jnp
 from tensor2robot_tpu.ops import dispatch
 from tensor2robot_tpu.ops.flash_attention import flash_attention
 from tensor2robot_tpu.ops.gated_delta_rule import gated_delta_rule
+from tensor2robot_tpu.ops.hyper_connection import (
+    MapConfig, hyper_connection_post, hyper_connection_pre)
 from tensor2robot_tpu.parallel import expert_parallel
 
 
@@ -75,6 +82,23 @@ class SequenceConfig:
   linear_value_head_dim: int = 0
   linear_num_key_heads: int = 0
   linear_num_value_heads: int = 0
+  # YaRN, as the published `rope_scaling` group (factor,
+  # original_max_position_embeddings, beta_fast, beta_slow, mscale,
+  # mscale_all_dim); given as a dict, kept as its sorted items. None:
+  # plain rotary and a softmax scale of 1/sqrt(head width).
+  rope_scaling: Optional[Any] = None
+  # > 0: the residual is this many streams wide, read, written and
+  # mixed through `HyperConnection`'s maps (mHC). 0: h = x + F(N(x)).
+  hc_mult: int = 0
+  hc_sinkhorn_iters: int = 20
+  hc_eps: float = 1e-6
+  mhc_h_res_clamp_min: float = -30.0
+  mhc_h_res_clamp_max: float = 30.0
+
+  def __post_init__(self):
+    if isinstance(self.rope_scaling, dict):
+      object.__setattr__(self, "rope_scaling",
+                         tuple(sorted(self.rope_scaling.items())))
 
   @property
   def num_expert_layers(self) -> int:
@@ -112,11 +136,51 @@ class RMSNorm(nn.Module):
     return (y * scale).astype(self.dtype)
 
 
-def rotary(x, theta: float):
+def rotary_frequencies(width: int, theta: float, scaling=None):
+  """(width / 2,) float32: theta^(-2i/width); under YaRN (`scaling`, the
+  published `rope_scaling` items) blended with the same over `factor`:
+  pairs that turn more than `beta_fast` times over the original context
+  stay plain, those that turn less than `beta_slow` times are slowed by
+  `factor`, a linear ramp over the pairs between."""
+  plain = theta ** (-jnp.arange(0, width, 2, dtype=jnp.float32) / width)
+  if scaling is None:
+    return plain
+  s = dict(scaling)
+  pair_of = lambda turns: (
+      width * math.log(s["original_max_position_embeddings"]
+                       / (2 * math.pi * turns)) / (2 * math.log(theta)))
+  low = max(math.floor(pair_of(s["beta_fast"])), 0)
+  high = min(math.ceil(pair_of(s["beta_slow"])), width - 1)
+  if low == high:
+    high += 0.001
+  ramp = jnp.clip((jnp.arange(width // 2, dtype=jnp.float32) - low)
+                  / (high - low), 0.0, 1.0)
+  return plain * (1.0 - ramp) + plain / s["factor"] * ramp
+
+
+def yarn_softmax_mscale(scaling) -> float:
+  """m of YaRN's softmax scale m² / sqrt(head width): 0.1 ·
+  mscale_all_dim · ln(factor) + 1; 1 without scaling. cos and sin would
+  be scaled by the ratio of that at `mscale` and at `mscale_all_dim`;
+  only a ratio of 1 is built."""
+  if scaling is None:
+    return 1.0
+  s = dict(scaling)
+  if s["factor"] <= 1:
+    return 1.0
+  at = lambda mscale: 0.1 * mscale * math.log(s["factor"]) + 1.0
+  if at(s["mscale"]) != at(s["mscale_all_dim"]):
+    raise NotImplementedError(
+        "rope_scaling with mscale != mscale_all_dim scales cos and sin")
+  return at(s["mscale_all_dim"])
+
+
+def rotary(x, theta: float, scaling=None):
   """Rotary positions over the last axis of (B, T, ..., R), pairs
-  interleaved: (x[2i], x[2i+1]) turns by t · theta^(-2i/R). Float32."""
+  interleaved: (x[2i], x[2i+1]) turns by t · theta^(-2i/R), or by t ·
+  YaRN's blended frequency under `scaling`. Float32."""
   t, r = x.shape[1], x.shape[-1]
-  inv_freq = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+  inv_freq = rotary_frequencies(r, theta, scaling)
   angle = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq  # (T, R/2)
   angle = angle.reshape((1, t) + (1,) * (x.ndim - 3) + (r // 2,))
   cos, sin = jnp.cos(angle), jnp.sin(angle)
@@ -151,7 +215,8 @@ class MLAttention(nn.Module):
   """Multi-head latent attention, training form (no cache): queries
   and keys/values through low-rank latents with a norm each, one rotary
   key head shared by all heads, q/k heads `nope + rope` wide and v heads
-  `v_head_dim` wide, causal."""
+  `v_head_dim` wide, causal; under `rope_scaling` YaRN's frequencies and
+  softmax scale. `num_attention_heads` are the heads held here."""
   config: SequenceConfig
   dtype: Any = jnp.bfloat16
 
@@ -168,17 +233,20 @@ class MLAttention(nn.Module):
       q = q.reshape(b, t, heads, nope + rope)
       kv = _dense(c.kv_lora_rank + rope, self.dtype, "kv_a")(x)
       c_kv = norm("kv_a_norm")(kv[..., :c.kv_lora_rank])
-      k_rope = rotary(kv[..., None, c.kv_lora_rank:], c.rope_theta)
+      k_rope = rotary(kv[..., None, c.kv_lora_rank:], c.rope_theta,
+                      c.rope_scaling)
       kv = _dense(heads * (nope + vdim), self.dtype, "kv_b")(c_kv)
       kv = kv.reshape(b, t, heads, nope + vdim)
       q = jnp.concatenate(
-          [q[..., :nope], rotary(q[..., nope:], c.rope_theta)], axis=-1)
+          [q[..., :nope],
+           rotary(q[..., nope:], c.rope_theta, c.rope_scaling)], axis=-1)
       k = jnp.concatenate(
           [kv[..., :nope],
            jnp.broadcast_to(k_rope, (b, t, heads, rope))], axis=-1)
       out = flash_attention(
           q, k, kv[..., nope:], causal=True,
-          scale=1.0 / math.sqrt(nope + rope))
+          scale=(yarn_softmax_mscale(c.rope_scaling) ** 2
+                 / math.sqrt(nope + rope)))
       return _dense(c.hidden_size, self.dtype, "o")(
           out.reshape(b, t, heads * vdim))
 
@@ -348,11 +416,71 @@ class ExpertLayer(nn.Module):
     return y, counters
 
 
+class HyperConnection(nn.Module):
+  """One sublayer's read, write and mixing of the `hc_mult`-stream
+  residual (mHC): `pre` reads the streams, side by side in (B, T, n·D),
+  into the sublayer's input u through H_pre and makes H_post and H_res,
+  the latter doubly stochastic by Sinkhorn; `post` writes the sublayer's
+  output back through H_post beside the streams mixed by H_res. `phi`
+  (nD, n² + 2n), `alpha` (3,) and `base` (n² + 2n,) are float32, columns
+  [pre | post | res]. Fresh, the maps are near a plain residual on every
+  stream: H_res about I, H_post 1, H_pre 1/2."""
+  config: SequenceConfig
+  dtype: Any = jnp.bfloat16
+
+  def setup(self):
+    c = self.config
+    n = c.hc_mult
+    maps = n * n + 2 * n
+
+    def base_init(rng, shape, dtype):
+      del rng
+      off_diagonal = -8.0 * (1.0 - jnp.eye(n, dtype=dtype))
+      return jnp.concatenate(
+          [jnp.zeros((2 * n,), dtype), off_diagonal.reshape(-1)]
+      ).reshape(shape)
+
+    self.phi = self.param("phi", nn.initializers.lecun_normal(),
+                          (n * c.hidden_size, maps), jnp.float32)
+    self.alpha = self.param("alpha", nn.initializers.constant(0.01), (3,),
+                            jnp.float32)
+    self.base = self.param("base", base_init, (maps,), jnp.float32)
+
+  def pre(self, x):
+    """(B, T, n·D) -> (u (B, T, D), (H_post, H_res), the step's
+    counters (4,): mean diagonal of H_res, mean H_pre, mean H_post, the
+    largest |row sum − 1| Sinkhorn left)."""
+    c = self.config
+    with jax.named_scope("mhc"), jax.named_scope("pre"):
+      u, h_pre, h_post, h_res = hyper_connection_pre(
+          x, self.phi, self.alpha, self.base, MapConfig(
+              c.hc_sinkhorn_iters, c.hc_eps, c.mhc_h_res_clamp_min,
+              c.mhc_h_res_clamp_max, c.rms_norm_eps))
+      n = c.hc_mult
+      counters = jax.lax.stop_gradient(jnp.stack([
+          jnp.mean(h_res * jnp.eye(n, dtype=h_res.dtype)) * n,
+          jnp.mean(h_pre), jnp.mean(h_post),
+          jnp.max(jnp.abs(jnp.sum(h_res, axis=-1) - 1.0))]))
+    return u, (h_post, h_res), counters
+
+  def post(self, x, y, maps):
+    with jax.named_scope("mhc"), jax.named_scope("post"):
+      return hyper_connection_post(x, y.astype(self.dtype), *maps)
+
+
+MHC_COUNTERS = ("mhc/res_diag_mean", "mhc/pre_mean", "mhc/post_mean",
+                "mhc/sinkhorn_gap")
+
+
 class DecoderBlock(nn.Module):
   """h = x + Mixer(norm(x)); y = h + FFN(norm(h)); the mixer by the
   layer's `kind` ("mla", "full": gated attention, "linear": the gated
-  delta net), FFN the dense gated MLP or the expert layer. Returns (y,
-  the expert layer's counters, with a delta net's `gdn/*` beside them)."""
+  delta net), FFN the dense gated MLP or the expert layer. With
+  `hc_mult` streams x is (B, T, n·D) and each of the two sublayers
+  reads, writes and mixes it through its own `HyperConnection`. Returns
+  (y, the expert layer's counters, with a delta net's `gdn/*` and the
+  hyper-connections' `mhc/*` (each (2,), by sublayer) beside them; None
+  where the block has none)."""
   config: SequenceConfig
   experts: bool
   dtype: Any = jnp.bfloat16
@@ -363,22 +491,37 @@ class DecoderBlock(nn.Module):
     c = self.config
     norm = lambda name: RMSNorm(c.rms_norm_eps, self.dtype,
                                 c.zero_centered_norm, name=name)
-    inner, gates = norm("attn_norm")(x), {}
-    if self.kind == "linear":
-      mixed, gates = GatedDeltaNet(c, self.dtype, name="attn")(inner)
-    elif self.kind == "full":
-      mixed = GatedAttention(c, self.dtype, name="attn")(inner)
-    else:
-      mixed = MLAttention(c, self.dtype, name="attn")(inner)
-    h = x + mixed
-    inner = norm("ffn_norm")(h)
-    if self.experts:
+    extra, maps = {}, []
+
+    def sublayer(x, name, inner):
+      """`inner` under the block's residual rule."""
+      if not c.hc_mult:
+        return x + inner(norm(name + "_norm")(x))
+      hc = HyperConnection(c, self.dtype, name=name + "_hc")
+      u, h, counters = hc.pre(x)
+      maps.append(counters)
+      return hc.post(x, inner(norm(name + "_norm")(u)), h)
+
+    def mixer(inner):
+      if self.kind == "linear":
+        mixed, gates = GatedDeltaNet(c, self.dtype, name="attn")(inner)
+        extra.update(gates)
+        return mixed
+      if self.kind == "full":
+        return GatedAttention(c, self.dtype, name="attn")(inner)
+      return MLAttention(c, self.dtype, name="attn")(inner)
+
+    def feed_forward(inner):
+      if not self.experts:
+        return GatedMLP(c.intermediate_size, self.dtype, name="mlp")(inner)
       y, counters = ExpertLayer(c, self.dtype, name="moe")(inner)
-      counters = dict(counters, **gates)
-    else:
-      y, counters = GatedMLP(c.intermediate_size, self.dtype,
-                             name="mlp")(inner), None
-    return h + y, counters
+      extra.update(counters)
+      return y
+
+    x = sublayer(sublayer(x, "attn", mixer), "ffn", feed_forward)
+    if maps:
+      extra.update(zip(MHC_COUNTERS, jnp.stack(maps).T))
+    return x, extra or None
 
 
 _LOSS_CHUNKS = 8

@@ -7,8 +7,12 @@ dense blocks, then equal expert blocks of routed top-k experts plus a
 shared expert, a depth-1 multi-token prediction module), and, where
 `full_attention_interval` is set, Qwen3-Next's hybrid (periods of that
 many expert blocks, gated delta nets and then one gated grouped-query
-attention block; no dense lead). The MTP module is built where
-`num_nextn_predict_layers` says so. The model
+attention block; no dense lead). Where `hc_mult` is set the residual is
+that many streams wide (Xing4.0's manifold-constrained
+hyper-connections): the embedding fans out to the streams, the blocks
+carry them side by side, (B, T, n·D), and the final norm reads their
+sum. The MTP module is built where `num_nextn_predict_layers` says so.
+The model
 is told its share of a deployment: which `experts_held` of the
 `n_routed_experts` it computes (the router keeps its full width) and how
 many rows of the vocabulary it holds; ids, logits and loss are over that
@@ -41,11 +45,36 @@ _CONFIG_FIELDS = frozenset(
     f.name for f in dataclasses.fields(sequence.SequenceConfig))
 
 
+def _fan_out(x, config):
+  """(B, T, D) -> the residual a block takes: itself, or with `hc_mult`
+  a copy for each stream side by side, (B, T, n·D)."""
+  if not config.hc_mult:
+    return x
+  return jnp.tile(x, (1, 1, config.hc_mult))
+
+
+def _collapse(x, config):
+  """The blocks' residual -> (B, T, D): itself, or the streams' sum
+  (float32 accumulation)."""
+  if not config.hc_mult:
+    return x
+  d = x.shape[-1] // config.hc_mult
+  return sum(x[..., j * d:(j + 1) * d].astype(jnp.float32)
+             for j in range(config.hc_mult)).astype(x.dtype)
+
+
+def _split_counters(counters, prefix):
+  """({those named `prefix`...}, the rest) of a block's counters."""
+  named = {k: v for k, v in counters.items() if k.startswith(prefix)}
+  return named, {k: v for k, v in counters.items() if k not in named}
+
+
 class _MTPModule(nn.Module):
   """Depth-1 multi-token prediction (DeepSeek-V3 §2.2): position i's
   hidden state and the embedding of token i+1, a norm each, joined by a
-  projection, through one expert block and a final norm; the shared head
-  then predicts token i+2."""
+  projection, through one expert block (over streams of its own where
+  the residual has them) and a final norm; the shared head then predicts
+  token i+2."""
   config: sequence.SequenceConfig
   dtype: Any
 
@@ -60,8 +89,8 @@ class _MTPModule(nn.Module):
     h = nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
                  param_dtype=jnp.float32, name="eh_proj")(joined)
     h, counters = nn.remat(sequence.DecoderBlock)(
-        c, True, self.dtype, name="block")(h)
-    return norm("final_norm")(h), counters
+        c, True, self.dtype, name="block")(_fan_out(h, c))
+    return norm("final_norm")(_collapse(h, c)), counters
 
 
 class _Period(nn.Module):
@@ -108,10 +137,13 @@ class _SequenceModule(nn.Module):
                      param_dtype=jnp.float32, name="embed")
     head = self.param("head", nn.initializers.lecun_normal(),
                       (c.hidden_size, c.vocab_size), jnp.float32)
-    x = embed(tokens)
+    x = _fan_out(embed(tokens), c)
+    mhc = []  # each block's `mhc/*` counters, in layer order
     for i in range(c.first_k_dense_replace):
-      x, _ = nn.remat(sequence.DecoderBlock)(
+      x, counters = nn.remat(sequence.DecoderBlock)(
           c, False, self.dtype, name=f"dense_block{i}")(x)
+      if c.hc_mult:
+        mhc.append(jax.tree_util.tree_map(lambda a: a[None], counters))
     outputs = {}
     if c.hybrid:
       stack = nn.scan(
@@ -128,6 +160,10 @@ class _SequenceModule(nn.Module):
           variable_axes={"params": 0}, split_rngs={"params": True},
           length=c.num_expert_layers)
       x, counters = stack(c, True, self.dtype, name="expert_blocks")(x, None)
+    if c.hc_mult:
+      maps, counters = _split_counters(counters, "mhc/")
+      mhc.append(maps)
+    x = _collapse(x, c)
     final = sequence.RMSNorm(c.rms_norm_eps, self.dtype,
                              c.zero_centered_norm, name="final_norm")
     if mode == modes.PREDICT:
@@ -142,9 +178,15 @@ class _SequenceModule(nn.Module):
             embed(jnp.roll(tokens, -1, axis=1)), x)
         outputs["token_loss_mtp"] = sequence.token_losses(
             h, head, jnp.roll(tokens, -2, axis=1))
+      if c.hc_mult:
+        maps, extra = _split_counters(extra, "mhc/")
+        mhc.append(jax.tree_util.tree_map(lambda a: a[None], maps))
       counters = jax.tree_util.tree_map(
           lambda a, b: jnp.concatenate([a, b[None]]), counters, extra)
     outputs["moe_counters"] = counters
+    if mhc:
+      outputs["mhc_counters"] = jax.tree_util.tree_map(
+          lambda *rows: jnp.concatenate(rows), *mhc)
     return outputs
 
 
@@ -208,4 +250,8 @@ class SequenceMoEModel(AbstractT2RModel):
     # a decay stuck at 0 or 1 is a layer that forgets everything or
     # nothing.
     metrics.update(outputs.get("gdn_counters", {}))
+    # (layers, 2), by sublayer: the mean diagonal of H_res over tokens (1
+    # is a plain residual, 1/n full mixing), the mean H_pre and H_post,
+    # and the largest |row sum − 1| Sinkhorn's last iteration left.
+    metrics.update(outputs.get("mhc_counters", {}))
     return loss, metrics

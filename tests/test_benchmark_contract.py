@@ -181,13 +181,15 @@ def test_span_the_benchmark_reads_is_opened_by_the_program(span_name):
 # --- 2. kernel names -----------------------------------------------------------
 
 
-@pytest.mark.parametrize("kernel", ["flash_attention", "gated_delta_rule"])
+@pytest.mark.parametrize("kernel", ["flash_attention", "gated_delta_rule",
+                                    "hyper_connection"])
 def test_kernel_names_are_the_pallas_calls_names(kernel):
   """`mla_attention_roofline.train` finds the flash kernel's device events
   by `ops/flash_attention.KERNEL_NAMES`, `gated_delta_rule_roofline.train`
   and `linear_attention_time_share.train` the delta rule's by
-  `ops/gated_delta_rule.KERNEL_NAMES`; a program named otherwise, or one
-  more the tuple lacks, makes them read None."""
+  `ops/gated_delta_rule.KERNEL_NAMES`, `hyper_connection_roofline.train`
+  the stream passes' by `ops/hyper_connection.KERNEL_NAMES`; a program
+  named otherwise, or one more the tuple lacks, makes them read None."""
   # `ops/__init__.py` re-exports the function under the module's name.
   module = importlib.import_module(f"tensor2robot_tpu.ops.{kernel}")
   tree = _parse(os.path.join(PACKAGE, "ops", f"{kernel}.py"))
@@ -210,9 +212,11 @@ def test_kernel_names_are_the_pallas_calls_names(kernel):
 def test_the_drivers_read_the_kernels_by_the_program_s_names():
   """The drivers' trace readers import the tuples, they do not spell the
   names: `train_resident_tokens` the flash kernel's,
-  `train_resident_hybrid` the delta rule's."""
+  `train_resident_hybrid` the delta rule's, `train_resident_mhc` the
+  hyper-connections'."""
   for driver, module in (("train_resident_tokens", "flash_attention"),
-                         ("train_resident_hybrid", "gated_delta_rule")):
+                         ("train_resident_hybrid", "gated_delta_rule"),
+                         ("train_resident_mhc", "hyper_connection")):
     imports = _program_imports(
         os.path.join(BENCHMARK, "drivers", f"{driver}.py"))
     assert (f"tensor2robot_tpu.ops.{module}", "KERNEL_NAMES") in imports
@@ -230,7 +234,8 @@ def test_layer_metric_reader_loads_and_reads_nothing_from_an_empty_run(
   from benchmark import harness
   read = harness._load_module("layer_metrics", metric).read
   assert callable(read)
-  if "roofline" in metric or "linear_attention" in metric:
+  if ("roofline" in metric or "linear_attention" in metric
+      or "hyper_connection" in metric):
     run = {"window": {}, "trace": None, "peaks": {}, "chips": 1,
            "cell": harness.load_cell("joyai_flash_train_seq8k")}
     assert read(run) is None

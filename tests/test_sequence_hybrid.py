@@ -558,6 +558,7 @@ class TestModel:
 @pytest.mark.parametrize("cfg, benchmark_config", [
     ("qwen3_next_ep16_train.cfg", "qwen3_next_80b_a3b_ep16"),
     ("joyai_flash_ep16_train.cfg", "joyai_llm_flash_ep16"),
+    ("xing4_tp8ep8_train.cfg", "xing4_0_29b_a4b_tp8ep8"),
 ])
 def test_trainer_config_builds_the_benchmark_s_model(cfg, benchmark_config):
   """The `.cfg` that `bin/run_t2r_trainer` takes and the configuration

@@ -64,6 +64,28 @@ def test_flash_attention_compiles_at_8k_forward_and_backward(
   assert text.count("tpu_custom_call") >= 3
 
 
+def test_flash_attention_compiles_at_4k_with_a_share_of_the_heads(one_chip,
+                                                                  as_on_tpu):
+  """MLA's widths at the head-sharded model's shapes: 4 of 32 heads over
+  all of T = 4,096, bf16; the three programs at a head count no other
+  configuration runs."""
+  from tensor2robot_tpu.ops.flash_attention import flash_attention
+  shape = lambda d: jax.ShapeDtypeStruct((1, 4096, 4, d), jnp.bfloat16,
+                                         sharding=one_chip)
+
+  def loss(q, k, v):
+    out = flash_attention(q, k, v, causal=True, implementation="pallas")
+    return jnp.sum(out.astype(jnp.float32))
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+      shape(192), shape(192), shape(128)).compile()
+  text = compiled.as_text()
+  for name in ("flash_attention_fwd", "flash_attention_dq",
+               "flash_attention_dkv"):
+    assert name in text
+  assert text.count("tpu_custom_call") >= 3
+
+
 def test_flash_attention_compiles_at_8k_grouped_256_wide(one_chip, as_on_tpu):
   """The hybrid model's full-attention shapes: 16 query heads on 2
   key/value heads, 256-wide, T = 8,192, bf16. K and V rows are staged
@@ -109,3 +131,36 @@ def test_gated_delta_rule_compiles_at_8k_forward_and_backward(one_chip,
   for name in rule.KERNEL_NAMES:
     assert name in text
   assert text.count("tpu_custom_call") >= 2
+
+
+def test_hyper_connection_compiles_at_4k_forward_and_backward(one_chip,
+                                                              as_on_tpu):
+  """The four-stream residual's shapes: T = 4,096 tokens of 4 x 3,584 side
+  by side in bf16, float32 maps: tiles of 128 tokens with a token's whole
+  14,336-wide stream in VMEM (past Mosaic's 16 MiB default with `phi`'s two parts and
+  dphi's sums beside it), two (128, 128) transposes a tile between the
+  token-major and the map-major layout, and products that contract over
+  the tokens (a transposed left operand), which interpret mode takes
+  whatever Mosaic makes of them."""
+  import importlib
+  hc = importlib.import_module("tensor2robot_tpu.ops.hyper_connection")
+  streams, width, tokens = 4, 3584, 4096
+  maps = streams * streams + 2 * streams
+  shape = lambda dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+      dims, dtype, sharding=one_chip)
+
+  def loss(x, y, phi, alpha, base):
+    u, h_pre, h_post, h_res = hc.hyper_connection_pre(
+        x, phi, alpha, base, implementation="pallas")
+    out = hc.hyper_connection_post(x, y + u, h_post, h_res,
+                                   implementation="pallas")
+    return jnp.sum(out.astype(jnp.float32)) + jnp.sum(h_pre)
+
+  compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+      shape((1, tokens, streams * width)), shape((1, tokens, width)),
+      shape((streams * width, maps), jnp.float32),
+      shape((3,), jnp.float32), shape((maps,), jnp.float32)).compile()
+  text = compiled.as_text()
+  for name in hc.KERNEL_NAMES:
+    assert name in text
+  assert text.count("tpu_custom_call") >= 4
