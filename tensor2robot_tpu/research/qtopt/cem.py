@@ -30,7 +30,6 @@ bar).
 
 from __future__ import annotations
 
-import functools
 from typing import Callable, Optional, Tuple
 
 import jax
@@ -190,46 +189,25 @@ def cem_optimize(
     score_fn: Callable[[jnp.ndarray], jnp.ndarray],
     rng: jax.Array,
     action_size: int,
-    num_samples: int = 64,
-    num_elites: int = 6,
-    iterations: int = 3,
-    initial_mean: Optional[jnp.ndarray] = None,
-    initial_std: float = 0.5,
-    action_low: float = -1.0,
-    action_high: float = 1.0,
+    **kwargs,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-  """Maximizes score_fn over a single state's action.
+  """Maximizes score_fn over a single state's action: the batch search
+  (`fleet_cem_optimize`, whose CEM hyperparameters `kwargs` are) over a
+  batch of one.
 
   Args:
     score_fn: (num_samples, action_size) → (num_samples,) scores; must be
       jittable (e.g. a batched Q-function with the state closed over).
     rng: PRNG key.
     action_size: action dimensionality.
-    num_samples/num_elites/iterations: CEM hyperparameters (reference
-      defaults: 64 / ~10% / 2-3).
-    initial_mean: optional warm-start mean (e.g. previous control step).
-    initial_std: initial per-dim std.
-    action_low/high: clipping box.
 
   Returns:
     (best_action, best_score): the final elite mean and its score.
   """
-  if initial_mean is None:
-    initial_mean = jnp.zeros((action_size,), jnp.float32)
-  initial_std_vec = jnp.full((action_size,), initial_std, jnp.float32)
-
-  def body(i, carry):
-    mean, std = carry
-    step_rng = jax.random.fold_in(rng, i)
-    samples = mean + std * jax.random.normal(
-        step_rng, (num_samples, action_size))
-    samples = jnp.clip(samples, action_low, action_high)
-    return _refit(samples, score_fn(samples), num_elites)
-
-  mean, _ = jax.lax.fori_loop(
-      0, iterations, body, (initial_mean, initial_std_vec))
-  mean = jnp.clip(mean, action_low, action_high)
-  return mean, score_fn(mean[None])[0]
+  best, scores = fleet_cem_optimize(
+      lambda _, actions: score_fn(actions[0])[None], None, rng[None],
+      action_size, **kwargs)
+  return best[0], scores[0]
 
 
 def _refit(samples: jnp.ndarray, scores: jnp.ndarray,
@@ -259,12 +237,13 @@ def batched_cem_optimize(
   """
   batch = jax.tree_util.tree_leaves(states)[0].shape[0]
   return fleet_cem_optimize(
-      score_fn, states, jax.random.split(rng, batch), action_size,
-      **kwargs)
+      jax.vmap(score_fn), states, jax.random.split(rng, batch),
+      action_size, **kwargs)
 
 
 def make_tiled_q_score_fn(fn, variables, precision: str = "f32"):
-  """The canonical per-state Q score_fn for `fleet_cem_optimize`.
+  """The canonical per-state Q score_fn (`fleet_cem_optimize` takes it
+  under a `jax.vmap` over the states).
 
   Tiles ONE state's image across its candidate actions and scores the
   batch through a ``(variables, features) -> {"q_predicted"}`` device
@@ -296,30 +275,103 @@ def make_tiled_q_score_fn(fn, variables, precision: str = "f32"):
   returns follow the bf16 contract exactly — activation numerics are
   the proven tier's, only the weights ride the int8 grid.
   """
+  cast, score_rows = _row_scorer(fn, variables, precision)
+
+  def score(image, actions):
+    image = cast(image)
+    tiled = jnp.broadcast_to(image[None],
+                             (actions.shape[0],) + image.shape)
+    return score_rows(tiled, actions)
+
+  return score
+
+
+def _row_scorer(fn, variables, precision: str):
+  """The tier's cast boundary, apart from how a state reaches its rows:
+  (cast, score_rows). `cast` brings ONE state (or a batch of them) to the
+  tier's dtype before it is expanded; `score_rows` scores (R, ...)
+  expanded states against (R, A) actions through `fn`, one row each."""
   if validate_precision(precision) == "f32":
-    def score(image, actions):
-      tiled = jnp.broadcast_to(image[None],
-                               (actions.shape[0],) + image.shape)
-      outputs = fn(variables, {"image": tiled,
+    def score_rows(states, actions):
+      outputs = fn(variables, {"image": states,
                                "action": actions.astype(jnp.float32)})
       return jnp.reshape(outputs["q_predicted"], (-1,))
 
-    return score
+    return (lambda state: state), score_rows
 
   dtype = _SCORING_DTYPES[precision]
   lp_variables = cast_scoring_variables(variables, precision)
 
-  def score_lp(image, actions):
+  def score_rows_lp(states, actions):
     weights = (dequantize_scoring_variables(lp_variables, dtype)
                if precision == "int8" else lp_variables)
-    image = image.astype(dtype)
-    tiled = jnp.broadcast_to(image[None],
-                             (actions.shape[0],) + image.shape)
-    outputs = fn(weights, {"image": tiled,
+    outputs = fn(weights, {"image": states,
                            "action": actions.astype(dtype)})
     return jnp.reshape(outputs["q_predicted"], (-1,)).astype(jnp.float32)
 
-  return score_lp
+  return (lambda state: state.astype(dtype)), score_rows_lp
+
+
+# One MXU contraction depth: the most states whose codes the recipe
+# expands by one product (MergedRowScore), whose FLOPs grow with the
+# square of the states. Serving's buckets (1, 8, 32) lie under it and
+# a cell measures them; Bellman labeling was measured at 64 and 128
+# states, on one chip and sharded over four (PERF.md, PR 39). A batch
+# above it keeps the per-state form.
+_MAX_MERGED_STATES = 128
+
+
+class MergedRowScore:
+  """The factored recipe's score, over a whole batch of codes at once:
+  (`states_of(codes)`, (B, N, A) actions) -> (B, N) scores in ONE Q
+  call of B*N rows, same tiers and same Q function as
+  `make_tiled_q_score_fn`.
+
+  Row r of the call is code r // N, written as a product with a one-hot
+  matrix over the merged (state, candidate) row axis. A per-state
+  `broadcast_to` under a vmap over the states reaches the same rows as
+  a (B, N, ...) tensor whose candidates the TPU pads to its 128 lanes,
+  and merging (B, N) into the convolution's row axis is then a second
+  pass over it: written and re-laid in every CEM iteration, 76% of a
+  rung-32 serving program (PERF.md, PR 39). The product XLA lowers to
+  a convolution that writes the first post convolution's own layout,
+  and fuses into it: the expanded rows never reach HBM.
+
+  The product is exact: each output is 1 * x plus zeros, summed in
+  float32. A bfloat16 code (the flagship's) passes the MXU as it is;
+  any wider one asks for `Precision.HIGHEST`, or the TPU would round
+  it to bfloat16 on the way. But it mixes rows, and 0 * NaN is NaN, so
+  a state whose code is not finite must not reach it: `states_of`
+  zeroes such entries, once, and the scores of that state are made NaN
+  after each call, which is what its own rows alone would have read.
+  """
+
+  def __init__(self, fn, variables, precision: str = "f32"):
+    self._cast, self._score_rows = _row_scorer(fn, variables, precision)
+
+  @staticmethod
+  def states_of(codes):
+    """(B, ...) codes -> the search's states: the codes with what is
+    not finite zeroed, and (B,) whether a state's code was finite."""
+    finite = jnp.isfinite(codes)
+    return (jnp.where(finite, codes, jnp.zeros_like(codes)),
+            jnp.all(jnp.reshape(finite, (codes.shape[0], -1)), axis=1))
+
+  def __call__(self, states, actions):
+    codes, finite = states
+    batch, num = actions.shape[:2]
+    codes = self._cast(codes)
+    if num > 1:
+      owner = jax.nn.one_hot(jnp.arange(batch * num) // num, batch,
+                             dtype=codes.dtype)
+      codes = jnp.einsum(
+          "nf,f...->n...", owner, codes,
+          precision=(None if codes.dtype == jnp.bfloat16
+                     else jax.lax.Precision.HIGHEST))
+    scores = self._score_rows(
+        codes, jnp.reshape(actions, (batch * num, -1)))
+    return jnp.where(finite[:, None], jnp.reshape(scores, (batch, num)),
+                     jnp.nan)
 
 
 def make_cem_states_and_score(fn, fns, variables, images,
@@ -335,9 +387,11 @@ def make_cem_states_and_score(fn, fns, variables, images,
   forward; `fns` is the factored pair where the model or predictor
   offers one (`CriticModel.factored_cem_fns`,
   `AbstractPredictor.factored_device_fns`): None → tiled, score full
-  images through `fn`; (encode_fn, q_from_code_fn) → encode the whole
-  `images` batch once, here, outside the per-state vmap and the CEM
-  loop, and score codes.
+  images through `fn`, each state's own rows; (encode_fn,
+  q_from_code_fn) → encode the whole `images` batch once, here, outside
+  the CEM loop, and score the codes: of up to _MAX_MERGED_STATES
+  states in one call on the merged row axis (`MergedRowScore`), of more
+  each state's own rows, as the tiled form does.
 
   `precision` is the scoring tier (SCORING_PRECISIONS). "f32" returns
   the exact pre-tier recipe. "bf16" runs the whole score path — the
@@ -346,8 +400,8 @@ def make_cem_states_and_score(fn, fns, variables, images,
   per-candidate scores cast back to float32 before elite selection
   (make_tiled_q_score_fn's contract)."""
   if fns is None:
-    return images, make_tiled_q_score_fn(fn, variables,
-                                         precision=precision)
+    return images, jax.vmap(
+        make_tiled_q_score_fn(fn, variables, precision=precision))
   encode_fn, q_from_code_fn = fns
   if validate_precision(precision) != "f32":
     # Encode once at the scoring dtype: the code then rides the tiled
@@ -356,13 +410,17 @@ def make_cem_states_and_score(fn, fns, variables, images,
     # scoring_weights_view keeps the encode DENSE under every tier —
     # int8's view is the quantize→dequantize round trip, so the hoisted
     # tower sees exactly the weights the serving executables score with.
-    lp_variables = scoring_weights_view(variables, precision)
-    states = encode_fn(
-        lp_variables, {"image": images.astype(scoring_dtype(precision))})
-    return states, make_tiled_q_score_fn(q_from_code_fn, variables,
-                                         precision=precision)
-  return (encode_fn(variables, {"image": images}),
-          make_tiled_q_score_fn(q_from_code_fn, variables))
+    codes = encode_fn(
+        scoring_weights_view(variables, precision),
+        {"image": images.astype(scoring_dtype(precision))})
+  else:
+    codes = encode_fn(variables, {"image": images})
+  if codes.shape[0] > _MAX_MERGED_STATES:
+    return codes, jax.vmap(
+        make_tiled_q_score_fn(q_from_code_fn, variables,
+                              precision=precision))
+  return (MergedRowScore.states_of(codes),
+          MergedRowScore(q_from_code_fn, variables, precision=precision))
 
 
 def fleet_cem_optimize(
@@ -371,9 +429,16 @@ def fleet_cem_optimize(
     keys: jax.Array,
     action_size: int,
     precision: str = "f32",
-    **kwargs,
+    num_samples: int = 64,
+    num_elites: int = 6,
+    iterations: int = 3,
+    initial_mean: Optional[jnp.ndarray] = None,
+    initial_std: float = 0.5,
+    action_low: float = -1.0,
+    action_high: float = 1.0,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-  """CEM over a batch of states with CALLER-supplied per-state keys.
+  """CEM over a batch of states with CALLER-supplied per-state keys: the
+  one search loop (`cem_optimize` is this over a batch of one).
 
   The serving micro-batcher's determinism contract hangs on this
   variant: each fleet request carries its own key, so its action
@@ -384,8 +449,13 @@ def fleet_cem_optimize(
   or identical requests would change answers across flush compositions.
 
   Args:
-    score_fn: (state, (N, A) actions) → (N,) scores for ONE state.
-    states: (B, ...) batch of states (pytree leaves batched on axis 0).
+    score_fn: ((B, ...) states, (B, N, A) actions) → (B, N) scores, each
+      state's from its own row of `states` and `actions` alone: a
+      per-state score (`make_tiled_q_score_fn`) under `jax.vmap`, or
+      `MergedRowScore`. `make_cem_states_and_score` gives either with
+      its states.
+    states: what `score_fn` takes: (B, ...), pytree leaves batched on
+      axis 0.
     keys: (B,) PRNG keys, one per state.
     precision: the scoring tier the caller built `score_fn` at
       (SCORING_PRECISIONS). Validated here so one `precision` value threads
@@ -395,17 +465,35 @@ def fleet_cem_optimize(
       the final mean) is float32 under every tier by the
       low-precision-matmuls / f32-updates convention, so candidate
       actions and the selected action never lose precision.
+    num_samples/num_elites/iterations: CEM hyperparameters (reference
+      defaults: 64 / ~10% / 2-3).
+    initial_mean: optional warm-start mean (e.g. previous control step).
+    initial_std: initial per-dim std.
+    action_low/high: clipping box.
 
   Returns:
     (B, A) best actions, (B,) their scores.
   """
   validate_precision(precision)
+  batch = keys.shape[0]
+  if initial_mean is None:
+    initial_mean = jnp.zeros((action_size,), jnp.float32)
+  mean = jnp.broadcast_to(initial_mean, (batch, action_size))
+  std = jnp.full((batch, action_size), initial_std, jnp.float32)
 
-  def single(state, key):
-    return cem_optimize(
-        functools.partial(score_fn, state), key, action_size, **kwargs)
+  def body(i, carry):
+    def draw(key, mean, std):
+      samples = mean + std * jax.random.normal(
+          jax.random.fold_in(key, i), (num_samples, action_size))
+      return jnp.clip(samples, action_low, action_high)
 
-  return jax.vmap(single)(states, keys)
+    samples = jax.vmap(draw)(keys, *carry)
+    return jax.vmap(_refit, in_axes=(0, 0, None))(
+        samples, score_fn(states, samples), num_elites)
+
+  mean, _ = jax.lax.fori_loop(0, iterations, body, (mean, std))
+  mean = jnp.clip(mean, action_low, action_high)
+  return mean, score_fn(states, mean[:, None])[:, 0]
 
 
 class CEMPolicy:

@@ -6,11 +6,12 @@ refitting — for up to ``bucket`` clients at once (PAPER.md §3.3 ran the
 reference's robot fleets through exactly such a batched session.run).
 Where the predictor offers the model's factored pair
 (``factored_device_fns``) each frame is encoded once and the search
-runs over its code; where it does not, each frame is tiled across its
-candidate actions on the device. Executables are AOT-compiled once per
-bucket and keyed on the bucket size only: model hot-reloads swap the
-variables *argument*, never the executable, so serving a fleet for days
-compiles ``len(ladder)`` programs total.
+runs over its code, expanded across its candidate actions inside the
+Q function's first convolution over it; where it does not, each frame
+is tiled across its candidate actions on the device. Executables are
+AOT-compiled once per bucket and keyed on the bucket size only: model
+hot-reloads swap the variables *argument*, never the executable, so
+serving a fleet for days compiles ``len(ladder)`` programs total.
 
 Per-request determinism: every request carries a uint32 seed; its CEM
 key is ``fold_in(key(policy_seed), seed)`` inside the compiled program,
@@ -146,6 +147,10 @@ class CEMFleetPolicy:
     # bucket -> whether its executable encodes each frame once (the
     # predictor offered the factored pair when the bucket compiled).
     self.encode_once = {}
+    # bucket -> whether its executable expands each code across its
+    # candidates inside the first post convolution: the score the
+    # recipe returned when the bucket was traced (cem.MergedRowScore).
+    self.expand_in_conv = {}
     # Separate locks: a first-time bucket compile holds _compile_lock
     # for seconds — clients assigning request seeds in submit() must
     # not stall fleet-wide behind it.
@@ -267,7 +272,8 @@ class CEMFleetPolicy:
     try:
       # Returns at enqueue.
       with trace_lib.span("serve/execute", bucket=bucket,
-                          encode_once=int(self.encode_once[bucket])):
+                          encode_once=int(self.encode_once[bucket]),
+                          expand_in_conv=int(self.expand_in_conv[bucket])):
         actions, scores = compiled(variables, device_images, device_seeds)
       # The wait for that transfer and for the device, then D2H.
       with trace_lib.span("serve/readback") as readback:
@@ -383,17 +389,22 @@ class CEMFleetPolicy:
       keys = jax.vmap(lambda s: jax.random.fold_in(base, s))(seeds)
 
       # One client's state is its frame or, with the pair, the frame's
-      # code, encoded here for the whole bucket: outside the fleet vmap
-      # and the CEM loop. The score tiles that state across the
-      # client's candidate actions; under the fleet vmap this becomes
-      # one (B*num_samples) Q call per CEM iteration — the
-      # Podracer-style batched on-device step. Shared with the Bellman
-      # updater's target max (same wire contract, by construction).
+      # code, encoded here for the whole bucket: outside the CEM loop.
+      # The score expands that state across the client's candidate
+      # actions: one (B*num_samples) Q call per CEM iteration — the
+      # Podracer-style batched on-device step — reached through a
+      # vmap over the clients where frames are tiled, written on the
+      # merged row axis where codes are (cem.MergedRowScore), which
+      # is noted as the bucket is traced. Shared with the
+      # Bellman updater's target max (same wire contract, by
+      # construction).
       # The scoring tier is part of the compiled program (params
       # quantize inside the executable), so a hot reload stays one
       # device_put, zero recompiles, any tier.
       states, score = cem.make_cem_states_and_score(
           fn, fns, variables, images, precision=self.precision)
+      self.expand_in_conv[images.shape[0]] = isinstance(
+          score, cem.MergedRowScore)
 
       best, best_scores = cem.fleet_cem_optimize(
           score, states, keys, self._action_size,

@@ -170,12 +170,40 @@ def test_span_the_benchmark_reads_is_opened_by_the_program(span_name):
   wanted = _attrs_read_off(span_name)
   if span_name == "serve/flush":
     assert {"batch", "queue_wait_ms_sum", "in_flight"} <= wanted, wanted
-  if span_name == "serve/execute":  # encode_once_share.serve
-    assert {"encode_once"} <= wanted, wanted
+  if span_name == "serve/execute":  # encode_once_ and expand_in_conv_share
+    assert {"encode_once", "expand_in_conv"} <= wanted, wanted
   for path, lineno, keywords in sites:
     assert wanted <= keywords, (
         f"{path}:{lineno} opens {span_name!r} without the attrs "
         f"{sorted(wanted - keywords)} that benchmark/layer_metrics reads")
+
+
+@pytest.mark.parametrize("attrs, share", [
+    ([1, 1, 1], 100.0), ([0, 0], 0.0), ([1, 0, None, 1], 200.0 / 3),
+    ([None, None], None), ([], None)])
+def test_expand_in_conv_share_over_a_ring_of_execute_spans(attrs, share):
+  """Of the window's `serve/execute` spans that carry `expand_in_conv`
+  the share with 1; nothing to read where none does (the parent
+  commit's program, which the driver runs under this reader too)."""
+  from benchmark import harness
+  from tensor2robot_tpu.obs import trace as trace_lib
+  tracer = trace_lib.get_tracer()
+  tracer.clear()
+  for value in attrs:
+    with tracer.span("serve/flush", batch=32, expand_in_conv=1):
+      kwargs = {} if value is None else {"expand_in_conv": value}
+      with tracer.span("serve/execute", bucket=32, encode_once=1, **kwargs):
+        pass
+  read = harness._load_module(
+      "layer_metrics", "expand_in_conv_share.serve").read
+  value = read({"window": {"window_s": 5.0}, "chips": 1, "trace": None})
+  assert value == (None if share is None else pytest.approx(share))
+  declared = {m["name"]: m for m in harness.load_cell(
+      "qtopt_serve_closed64").spec["per_layer"]}["expand_in_conv_share.serve"]
+  assert (declared["layer"], declared["source"], declared["moves"],
+          declared["workloads"]) == (
+              "CEM policy", "program_span", "serve_actions_per_s",
+              ["qtopt_serve_closed64"])
 
 
 # --- 2. kernel names -----------------------------------------------------------

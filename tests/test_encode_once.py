@@ -8,6 +8,11 @@ per-robot vmap and the CEM loop, and searches over the code. Pinned here
 on the CPU: the split is the same function and the same parameter tree
 as the one-method module, serving answers are the tiled path's, the
 hoist is in the compiled program, and the counter says when it engages.
+
+Since ISSUE 39 the recipe, where it holds the pair, expands each code
+across its candidates on the merged (state, candidate) row axis
+(`cem.MergedRowScore`), one Q call for the whole batch: section 3 holds
+it to the per-state broadcast form it replaced.
 """
 
 import glob
@@ -29,7 +34,9 @@ from tensor2robot_tpu.predictors.checkpoint_predictor import (
     CheckpointPredictor)
 from tensor2robot_tpu.predictors.exported_model_predictor import (
     ExportedModelPredictor)
+from tensor2robot_tpu.replay.bellman import make_bellman_targets_fn
 from tensor2robot_tpu.replay.smoke import TinyQCriticModel
+from tensor2robot_tpu.research.qtopt import cem
 from tensor2robot_tpu.research.qtopt.t2r_models import QTOptGraspingModel
 from tensor2robot_tpu.serving.bucketing import BucketLadder
 from tensor2robot_tpu.serving.policy import CEMFleetPolicy
@@ -447,7 +454,170 @@ def test_the_tiny_critics_artifact_carries_its_pair(tmp_path):
     np.testing.assert_allclose(pair, tiled, rtol=1e-5, atol=1e-6)
 
 
-# --- 3. the counter -----------------------------------------------------------
+# --- 3. the expansion on the merged row axis ----------------------------------
+
+
+@pytest.fixture(scope="module")
+def plain_predictors(tmp_path_factory):
+  """The flagship as initialised: `_seeded`'s shifted statistics drive
+  every Q to 1e11, where a frame's candidates tie in bfloat16 and the
+  search returns the same action whatever it scored."""
+  model = QTOptGraspingModel(image_size=SIZE)
+  variables = jax.device_get(model.init_variables(jax.random.key(0)))
+  return {"exported": _exported(model, variables,
+                                tmp_path_factory.mktemp("plain")),
+          "checkpoint": _checkpoint(model, variables)}
+
+
+def _per_state_recipe(fns, variables, images, precision="f32"):
+  """(states, score) as the recipe built them before ISSUE 39: the same
+  encode (a finite code comes back from `states_of` as it went in), and
+  `make_tiled_q_score_fn` over the code, one state's `broadcast_to`
+  under a vmap over the states."""
+  states, score = cem.make_cem_states_and_score(
+      None, fns, variables, images, precision=precision)
+  codes = states[0] if isinstance(score, cem.MergedRowScore) else states
+  return codes, jax.vmap(cem.make_tiled_q_score_fn(fns[1], variables,
+                                                   precision=precision))
+
+
+def _search(recipe, fns, variables, images, keys, precision="f32"):
+  def run(variables, images, keys):
+    states, score = recipe(fns, variables, images, precision)
+    return cem.fleet_cem_optimize(
+        score, states, keys, 4, precision=precision, num_samples=8,
+        num_elites=2, iterations=2)
+  return jax.device_get(jax.jit(run)(variables, images, keys))
+
+
+def _merged_recipe(fns, variables, images, precision="f32"):
+  states, score = cem.make_cem_states_and_score(
+      None, fns, variables, images, precision=precision)
+  assert isinstance(score, cem.MergedRowScore)
+  return states, score
+
+
+@pytest.mark.parametrize("kind, precision", _TIERS)
+def test_merged_rows_give_the_per_state_forms_answers(plain_predictors, kind,
+                                                      precision):
+  predictor = plain_predictors[kind]
+  _, variables = predictor.device_fn()
+  fns = predictor.factored_device_fns()
+  images = np.stack(_frames(5, seed=9))
+  keys = jax.random.split(jax.random.key(4), 5)
+  merged = _search(_merged_recipe, fns, variables, images, keys, precision)
+  per_state = _search(_per_state_recipe, fns, variables, images, keys,
+                      precision)
+  assert len(np.unique(merged[0], axis=0)) == 5  # scores chose the actions
+  for new, old in zip(merged, per_state):
+    assert np.all(np.isfinite(new))
+    np.testing.assert_array_equal(new, old)
+
+
+@pytest.mark.parametrize("compute, wanted", [
+    ("bfloat16", None), ("float32", jax.lax.Precision.HIGHEST)])
+def test_the_product_is_exact_for_the_codes_dtype(compute, wanted):
+  """A bfloat16 code passes the MXU as it is; a float32 one would be
+  rounded to bfloat16 at the TPU's default precision, so the recipe
+  asks for the highest. Here: what it asks for, and that the expanded
+  rows are the code's own bits."""
+  model = QTOptGraspingModel(image_size=SIZE,
+                             compute_dtype=jnp.dtype(compute))
+  variables = _seeded(model)
+  encode_fn, _ = model.factored_cem_fns()
+  code = encode_fn(variables, {"image": np.stack(_frames(3, seed=1))})
+  assert code.dtype == jnp.dtype(compute)
+  rows = {}
+
+  def capture(_, features):
+    rows["code"] = features["image"]
+    return {"q_predicted": jnp.zeros(features["action"].shape[0])}
+
+  score = cem.MergedRowScore(capture, variables)
+  states = cem.MergedRowScore.states_of(code)
+  jaxpr = jax.make_jaxpr(score)(states, jnp.zeros((3, 4, 4)))
+  dots = [eqn for eqn in jaxpr.jaxpr.eqns
+          if eqn.primitive.name == "dot_general"]
+  assert len(dots) == 1
+  precision = dots[0].params["precision"]
+  assert (precision if precision is None else precision[0]) == wanted
+  score(states, jnp.zeros((3, 4, 4)))
+  np.testing.assert_array_equal(
+      np.asarray(rows["code"], np.float32),
+      np.repeat(np.asarray(code, np.float32), 4, axis=0))
+
+
+@pytest.mark.parametrize("bad", [np.nan, np.inf], ids=["nan", "inf"])
+@pytest.mark.parametrize("kind", ["exported", "checkpoint"])
+def test_a_frame_that_is_not_finite_spoils_its_own_row_alone(
+    plain_predictors, kind, bad):
+  """0 * NaN is NaN: unguarded, the merged product would hand one
+  robot's bad frame to every row of its flush."""
+  policy = CEMFleetPolicy(plain_predictors[kind], ladder=BucketLadder((4,)),
+                          **_CEM)
+  frames = _frames(4, seed=12)
+  seeds = np.array([21, 22, 23, 24], np.uint32)
+  spoiled = list(frames)
+  spoiled[2] = frames[2].copy()
+  spoiled[2][5, 7, 1] = bad
+  sound = policy(frames, seeds, return_scores=True)
+  served = policy(spoiled, seeds, return_scores=True)
+  assert not np.isfinite(served[1][2])
+  for i in (0, 1, 3):
+    alone = policy([frames[i]], seeds[i:i + 1], return_scores=True)
+    for with_bad, without, one in zip(served, sound, alone):
+      np.testing.assert_array_equal(with_bad[i], without[i])
+      np.testing.assert_array_equal(with_bad[i], one[0])
+  assert policy.compile_counts == {4: 1}
+
+
+def test_the_serving_buckets_compile_once_each_and_expand_in_the_conv(
+    predictors):
+  policy = CEMFleetPolicy(predictors["checkpoint"],
+                          ladder=BucketLadder((1, 8, 32)), **_CEM)
+  frames = _frames(32, seed=13)
+  for n in (1, 8, 32, 5, 20, 1):
+    assert policy(frames[:n], np.arange(n, dtype=np.uint32)).shape == (n, 4)
+  assert policy.compile_counts == {1: 1, 8: 1, 32: 1}
+  assert policy.expand_in_conv == {1: True, 8: True, 32: True}
+
+
+@pytest.mark.parametrize("batch", [96, 128, 300])
+@pytest.mark.parametrize("consumer", ["bellman", "acting"])
+def test_training_consumers_read_the_per_state_forms_answers(consumer, batch):
+  """Bellman's target max and Anakin's acting step through the recipe,
+  below, at and above the 128 states one product expands: past it the
+  recipe is the per-state form itself."""
+  model = TinyQCriticModel(image_size=8)
+  variables = jax.device_get(model.init_variables(jax.random.key(1)))
+  fns = model.factored_cem_fns()
+  rng = np.random.default_rng(batch)
+  images = rng.integers(0, 255, (batch, 8, 8, 3), np.uint8)
+  keys = jax.random.split(jax.random.key(2), batch)
+  best, scores = _search(_per_state_recipe, fns, variables, images, keys)
+  def recipe(fns, variables, images, precision):
+    states, score = cem.make_cem_states_and_score(
+        None, fns, variables, images, precision=precision)
+    assert isinstance(score, cem.MergedRowScore) == (batch <= 128)
+    return states, score
+
+  if consumer == "acting":  # anakin.act: the recipe, then the best action
+    new, _ = _search(recipe, fns, variables, images, keys)
+    np.testing.assert_allclose(new, best, rtol=1e-5, atol=1e-6)
+    return
+  rewards = rng.random(batch).astype(np.float32)
+  dones = (rng.random(batch) < 0.3).astype(np.float32)
+  targets, q_next = jax.jit(make_bellman_targets_fn(
+      model, 4, 0.9, 8, 2, 2, True, factored=True))(
+          variables, images, rewards, dones, keys)
+  wanted = 1.0 / (1.0 + np.exp(-scores.astype(np.float64)))
+  np.testing.assert_allclose(q_next, wanted, rtol=1e-5, atol=1e-6)
+  np.testing.assert_allclose(
+      targets, np.clip(rewards + 0.9 * (1.0 - dones) * wanted, 0.0, 1.0),
+      rtol=1e-5, atol=1e-6)
+
+
+# --- 4. the counter -----------------------------------------------------------
 
 
 @pytest.mark.parametrize("offered", [True, False], ids=["pair", "tiled"])
@@ -478,6 +648,7 @@ def test_spans_and_stats_say_whether_the_frame_was_encoded_once(
   compiles = [s for s in spans if s["name"] == "serve/compile"]
   assert len(executes) == snapshot["flushes"] and len(compiles) == 1
   assert {s["encode_once"] for s in executes + compiles} == {int(offered)}
+  assert {s["expand_in_conv"] for s in executes} == {int(offered)}
 
 
 @pytest.mark.parametrize("attrs, share", [

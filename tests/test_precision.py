@@ -138,7 +138,7 @@ class TestPrecisionPolicy:
 
     from tensor2robot_tpu.research.qtopt import cem
     model, variables = tiny_model_and_variables
-    score = cem.make_tiled_q_score_fn(model.predict_fn, variables)
+    score = jax.vmap(cem.make_tiled_q_score_fn(model.predict_fn, variables))
     states = np.zeros((2, 16, 16, 3), np.uint8)
     keys = jax.random.split(jax.random.key(0), 2)
     with pytest.raises(ValueError, match="precision"):

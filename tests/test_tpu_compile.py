@@ -6,6 +6,7 @@ chip run. All such compiles live in this one file: the worker that is
 handed it loads the TPU's library, once."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -164,3 +165,58 @@ def test_hyper_connection_compiles_at_4k_forward_and_backward(one_chip,
   for name in hc.KERNEL_NAMES:
     assert name in text
   assert text.count("tpu_custom_call") >= 4
+
+
+def _written_outside_fusions(text, dims):
+  """Instructions whose result is an array of `dims` in a computation
+  that is no fusion's body (the entry, a loop's body): buffers the
+  program writes, where an instruction inside a fusion names a value
+  that may never leave the core."""
+  fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+  current, found = None, []
+  for line in text.splitlines():
+    header = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+    if header:
+      current = header.group(1)
+    elif current not in fused and re.search(
+        r" = \w+\[%s\]" % re.escape(dims), line):
+      found.append(line.strip()[:120])
+  return found
+
+
+@pytest.mark.parametrize("bucket", [32, 8])
+def test_serving_rung_expands_the_code_inside_the_first_post_conv(
+    one_chip, as_on_tpu, bucket):
+  """The rung's control program over the flagship's pair (f32 tier, CEM
+  64 x 3 x 6). Before ISSUE 39 each iteration wrote the code tiled
+  across its candidates, bf16[bucket,64,59,59,64] with the candidates
+  padded to 128 lanes, and re-laid it into the post convolutions' rows,
+  bf16[bucket*64,59,59,64]: 2.83 GB of temporaries at rung 32. Now the
+  first does not exist, and the second only inside the fusion of the
+  first post convolution."""
+  from tensor2robot_tpu.predictors.checkpoint_predictor import (
+      CheckpointPredictor)
+  from tensor2robot_tpu.research.qtopt.t2r_models import QTOptGraspingModel
+  from tensor2robot_tpu.serving.bucketing import BucketLadder
+  from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+  predictor = CheckpointPredictor(QTOptGraspingModel())
+  predictor.init_randomly()
+  fn, live = predictor.device_fn()
+  policy = CEMFleetPolicy(predictor, ladder=BucketLadder((bucket,)),
+                          num_samples=64, num_elites=6, iterations=3)
+  shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                   sharding=one_chip)
+  compiled = jax.jit(
+      policy._build_control(fn, predictor.factored_device_fns())).lower(
+          jax.tree_util.tree_map(lambda leaf: shape(leaf.shape, leaf.dtype),
+                                 live),
+          shape((bucket, 472, 472, 3), jnp.float32),
+          shape((bucket,), jnp.uint32)).compile()
+  text = compiled.as_text()
+  assert f"[{bucket},64,59,59,64]" not in text
+  rows = f"{bucket * 64},59,59,64"
+  assert f"[{rows}]" in text  # the test sees the rows it says are fused
+  assert _written_outside_fusions(text, rows) == []
+  # The first post convolution's output is a buffer, and is found.
+  assert _written_outside_fusions(text, f"{bucket * 64},30,30,64")
+  assert compiled.memory_analysis().temp_size_in_bytes < 1e9
