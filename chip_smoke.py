@@ -373,6 +373,12 @@ def _serve(devices, export_root: str) -> dict:
       "q_reference_abs_error": round(q_error, 5),
       "fleet_rung_q_abs_error": round(fleet_q_error, 5),
       "fleet_rung_q_spread": round(fleet_q_spread, 5),
+      # Where the flushes' device turns went (ServingStats): the chip
+      # or the wire.
+      **{key: snapshot.get(key) for key in (
+          "turn_wait_p50_ms", "turn_wait_p95_ms", "transfer_wait_p50_ms",
+          "transfer_wait_p95_ms", "program_p50_ms", "program_p95_ms",
+          "transfers_hidden", "program_busy_share")},
   }
 
 

@@ -35,6 +35,20 @@ from tensor2robot_tpu.serving import bucketing
 from tensor2robot_tpu.serving.bucketing import BucketLadder
 
 
+# One hold in this many is split into its two waits (`__call__`): the
+# wait for the inputs wakes the dispatcher once more inside its hold, on
+# a thread that shares the interpreter lock with the other dispatcher's
+# stack and put, and cost 1.4-1.8% of the serving rate when every flush
+# took it (PERF.md, PR 40). Odd, so that two dispatchers that alternate
+# both get their share.
+_SPLIT_EVERY = 7
+
+
+def _end_s(span_record) -> float:
+  """When a closed span ended, on the tracer's clock."""
+  return span_record["ts_s"] + span_record["dur_s"]
+
+
 class _StagingPool:
   """Host arrays a flush's frames are stacked into, kept between calls.
 
@@ -165,10 +179,16 @@ class CEMFleetPolicy:
     # caller at a time.
     self._turn = threading.Lock()
     self._next_seed = 0
+    # The device turns taken, and when the last one's hold ended on
+    # the tracer's clock (both written under the turn lock): the next
+    # program ran no earlier.
+    self._holds = 0
+    self._hold_end_s = None
     # Where each flush's frames are stacked (see _StagingPool), and
-    # whether this thread's last call found its array there: the
-    # replica's stats feed reads it back after its own call
-    # (router.PolicyReplica._flush), beside the other dispatcher's.
+    # whether this thread's last call found its array there and where
+    # it spent its device turn: the replica's stats feed reads them
+    # back after its own call (router.PolicyReplica._flush), beside
+    # the other dispatcher's.
     self._staging = _StagingPool()
     self._last_call = threading.local()
 
@@ -221,7 +241,7 @@ class CEMFleetPolicy:
     # call that raises never gives it back.
     staged, reused = self._staging.take((bucket,) + first.shape,
                                         first.dtype)
-    self._last_call.reused = reused
+    self._last_call.reused, self._last_call.phases = reused, None
     with trace_lib.span("serve/stack", rows=n, bytes=n * first.nbytes,
                         reused=int(reused)):
       if any(frame.dtype != first.dtype for frame in frames):
@@ -267,27 +287,70 @@ class CEMFleetPolicy:
       device_seeds = self._put(padded_seeds)
     # The wait for another caller's program, if one is on the device:
     # the transfer above goes on beside it.
-    with trace_lib.span("serve/turn", bucket=bucket):
+    with trace_lib.span("serve/turn", bucket=bucket) as turn:
       self._turn.acquire()
     try:
+      # Whether that transfer was over when the turn came (hidden under
+      # the other flush's turn): set on the closed record, which the
+      # ring keeps.
+      turn["landed"] = int(device_images.is_ready())
+      split = self._holds % _SPLIT_EVERY == 0
+      self._holds += 1
       # Returns at enqueue.
       with trace_lib.span("serve/execute", bucket=bucket,
                           encode_once=int(self.encode_once[bucket]),
-                          expand_in_conv=int(self.expand_in_conv[bucket])):
+                          expand_in_conv=int(self.expand_in_conv[bucket])
+                          ) as execute:
         actions, scores = compiled(variables, device_images, device_seeds)
       # The wait for that transfer and for the device, then D2H.
       with trace_lib.span("serve/readback") as readback:
-        actions = np.asarray(actions)[:n]
+        if split:
+          # The hold, split on the thread that is blocked in it: what
+          # of this flush's transfer the enqueued program still had to
+          # wait for (the inputs are not donated: they are alive to
+          # wait on), then the program with the actions' D2H; what is
+          # left of the span is the scores' D2H.
+          with trace_lib.span("serve/transfer_wait", bucket=bucket,
+                              bytes=staged.nbytes + padded_seeds.nbytes
+                              ) as transfer_wait:
+            jax.block_until_ready((device_images, device_seeds))
+          # One blocking call waits for the program and copies its
+          # actions out, as in a hold that is not split: a wait of its
+          # own for the program would hand the interpreter lock away
+          # once more between this program's end and the next enqueue.
+          with trace_lib.span("serve/program_wait",
+                              bucket=bucket) as program_wait:
+            actions = np.asarray(actions)[:n]
+          # The program's time on the device (and its actions' way
+          # back), by the spans' own clock reads: it ran from the
+          # latest of its frames having landed, its enqueue and this
+          # policy's previous hold having ended (which the turn lock
+          # puts before the enqueue today).
+          readback["device_ms"] = round(1e3 * (_end_s(program_wait) - max(
+              _end_s(transfer_wait), execute["ts_s"], self._hold_end_s or 0.0
+              )), 3)
+        else:
+          actions = np.asarray(actions)[:n]
         scores = np.asarray(scores)[:n]
+      phases = {"turn_wait_ms": round(1e3 * turn["dur_s"], 3),
+                "landed": turn["landed"]}
+      if self._hold_end_s is not None:  # from hold's end to hold's end
+        phases["period_ms"] = round(
+            1e3 * (_end_s(readback) - self._hold_end_s), 3)
+      self._hold_end_s = _end_s(readback)
+      if split:
+        phases.update(
+            transfer_wait_ms=round(1e3 * transfer_wait["dur_s"], 3),
+            program_ms=readback["device_ms"])
     finally:
       self._turn.release()
     # The program has run, so the transfer it waited for is over.
     self._staging.give(staged)
+    self._last_call.phases = phases
     if self._ledger is not None:
       # Dispatch through completion, on the spans' own clock reads.
       self._ledger.record_dispatch(
-          self._ledger_key(bucket),
-          readback["ts_s"] + readback["dur_s"] - put["ts_s"])
+          self._ledger_key(bucket), _end_s(readback) - put["ts_s"])
     return (actions, scores) if return_scores else actions
 
   @property
@@ -296,6 +359,18 @@ class CEMFleetPolicy:
     an array the pool already held (its `serve/stack` span's
     `reused`)."""
     return getattr(self._last_call, "reused", False)
+
+  @property
+  def last_call_phases(self) -> Optional[dict]:
+    """Where the calling thread's last call spent its device turn, by
+    its spans' clock reads: `turn_wait_ms` and `landed` (the
+    `serve/turn` span's), `period_ms` (from the end of this policy's
+    previous hold to the end of this one; not on its first) and, on the
+    one hold in `_SPLIT_EVERY` that was split, `transfer_wait_ms`
+    (`serve/transfer_wait`) and `program_ms` (the `serve/readback`
+    span's `device_ms`). None after a call that took the host fallback
+    or raised."""
+    return getattr(self._last_call, "phases", None)
 
   @property
   def device_label(self) -> Optional[str]:

@@ -138,16 +138,18 @@ class PolicyReplica:
                                return_scores=True)
       if scores is not None:
         # Served-Q sketch feed (ISSUE 15): free scores off the same
-        # dispatch, whether its program encoded each frame once and
+        # dispatch, whether its program encoded each frame once,
         # whether its frames were stacked into a staging array the
-        # policy had kept; exception-isolated — diagnostics never fail
-        # a flush (the listener contract).
+        # policy had kept and where its device turn went;
+        # exception-isolated — diagnostics never fail a flush (the
+        # listener contract).
         try:
           self.stats.record_q_values(str(self.device), scores)
           if policy.encode_once.get(policy.ladder.bucket_for(len(items))):
             self.stats.record_encode_once_flush()
           if policy.last_call_reused_staging:
             self.stats.record_staged_flush()
+          self.stats.record_flush_phases(**policy.last_call_phases)
         except Exception:
           pass
       if self._episode_recorder is not None:

@@ -156,6 +156,16 @@ class ServingStats:
     self._encode_once_flushes = 0  # dispatched to an encode-once program
     self._staged_flushes = 0  # stacked into a staging array that was kept
     self._queue_depth_sum = 0   # queue depth left behind at flush time
+    # Where the device turns went (record_flush_phases): one histogram
+    # a phase, the flushes whose frames had landed when their turn
+    # came, and for the busy share the sums of the split holds'
+    # program times and of all holds' periods, each with its count.
+    self.turn_wait = LatencyHistogram()
+    self.transfer_wait = LatencyHistogram()
+    self.program = LatencyHistogram()
+    self._transfers_hidden = 0
+    self._program_ms = [0.0, 0]  # sum, holds that were split
+    self._period_ms = [0.0, 0]   # sum, holds after a policy's first
     self._per_class: Dict[str, _ClassStats] = {}
     self._q_sketches: Dict[str, QSketch] = {}
 
@@ -275,6 +285,35 @@ class ServingStats:
     with self._lock:
       self._staged_flushes += 1
 
+  def record_flush_phases(self, turn_wait_ms: float, landed: int,
+                          period_ms: Optional[float] = None,
+                          transfer_wait_ms: Optional[float] = None,
+                          program_ms: Optional[float] = None) -> None:
+    """Where one replica dispatch spent its device turn
+    (`CEMFleetPolicy.last_call_phases`, the flush's own span reads):
+    the wait for the turn, whether the frames had landed when it came,
+    the time since the policy's previous hold ended and, of the one
+    hold in a few that the policy splits, the wait for what of its
+    frames' transfer its enqueued program still had to wait for and
+    the program's time with its actions' way back (the
+    `serve/readback` span's `device_ms`). "Is this replica's limit the
+    chip or the wire": `program_busy_share` near 1 is the chip; a long
+    `transfer_wait_ms` with few `transfers_hidden` is the wire."""
+    with self._lock:
+      self._transfers_hidden += int(landed)
+      for sums, value in ((self._period_ms, period_ms),
+                          (self._program_ms, program_ms)):
+        if value is not None:
+          sums[0] += value
+          sums[1] += 1
+    for name, hist, value in (
+        ("turn_wait_ms", self.turn_wait, turn_wait_ms),
+        ("transfer_wait_ms", self.transfer_wait, transfer_wait_ms),
+        ("program_ms", self.program, program_ms)):
+      if value is not None:
+        hist.record(value)
+        self._registry.histogram(f"serving/{name}").record(value)
+
   def record_latency_ms(self, latency_ms: float,
                         class_name: Optional[str] = None) -> None:
     self.latency.record(latency_ms)
@@ -331,6 +370,21 @@ class ServingStats:
       per_class = {name: self._class_snapshot(cls)
                    for name, cls in sorted(self._per_class.items())}
       shed_total = sum(entry["shed"] for entry in per_class.values())
+      if self._program_ms[1]:  # a flush took the device path
+        out["transfers_hidden"] = self._transfers_hidden
+        # The mean program of the holds that were split over the mean
+        # period of all holds, each replica's holds against its own
+        # periods: of the time between the replicas' first holds and
+        # their last (a replica gone quiet keeps its share).
+        (program_ms, split), (period_ms, periods) = (self._program_ms,
+                                                     self._period_ms)
+        out["program_busy_share"] = round(
+            program_ms / split * periods / period_ms, 4) if periods else None
+        for name, hist in (("turn_wait", self.turn_wait),
+                           ("transfer_wait", self.transfer_wait),
+                           ("program", self.program)):
+          for pct in (50, 95):
+            out[f"{name}_p{pct}_ms"] = hist.percentile(pct)
     out["shed_total"] = shed_total
     for key, value in self.latency.summary().items():
       out["latency_" + key if not key.startswith("count") else

@@ -132,23 +132,54 @@ def _attrs_read_off(span_name):
   return attrs
 
 
+def _set_on_the_record(tree):
+  """{id of a `with *.span(...) as record:` call: the keys its function
+  sets on the record the span yields (`record["key"] = ...`, inside the
+  block or on the closed record after it)}: the ring keeps them beside
+  the attrs the call passes."""
+  found = {}
+  for function in ast.walk(tree):
+    if not isinstance(function, ast.FunctionDef):
+      continue
+    keys = {}  # the record's name: the keys set on it
+    for stmt in ast.walk(function):
+      if not isinstance(stmt, ast.Assign):
+        continue
+      for target in stmt.targets:
+        if (isinstance(target, ast.Subscript)
+            and isinstance(target.value, ast.Name)
+            and isinstance(target.slice, ast.Constant)):
+          keys.setdefault(target.value.id, set()).add(target.slice.value)
+    for node in ast.walk(function):
+      if isinstance(node, ast.With):
+        for item in node.items:
+          if isinstance(item.optional_vars, ast.Name):
+            found[id(item.context_expr)] = keys.get(
+                item.optional_vars.id, set())
+  return found
+
+
 def _span_call_sites(span_name):
   """`*.span("<name>", **attrs)` calls under tensor2robot_tpu/ whose first
-  argument is that literal: [(path, lineno, {keyword names})]."""
+  argument is that literal: [(path, lineno, {keyword names and keys set
+  on the yielded record})]."""
   sites = []
   for path in _package_files():
     with open(path) as f:
       source = f.read()
     if span_name not in source:
       continue
-    for node in ast.walk(ast.parse(source)):
+    tree = ast.parse(source)
+    on_record = _set_on_the_record(tree)
+    for node in ast.walk(tree):
       if not (isinstance(node, ast.Call) and node.args):
         continue
       first = node.args[0]
       if (_called_name(node) == "span" and isinstance(first, ast.Constant)
           and first.value == span_name):
         sites.append((os.path.relpath(path, ROOT), node.lineno,
-                      {kw.arg for kw in node.keywords}))
+                      {kw.arg for kw in node.keywords}
+                      | on_record.get(id(node), set())))
   return sites
 
 
@@ -157,7 +188,8 @@ _SPANS = _span_names_the_benchmark_reads()
 
 def test_the_readers_name_spans_at_all():
   # Guards the parametrisation below: an empty list would pass in silence.
-  assert "serve/flush" in _SPANS and "train/dispatch" in _SPANS, _SPANS
+  assert {"serve/flush", "train/dispatch", "serve/turn",
+          "serve/transfer_wait"} <= set(_SPANS), _SPANS
 
 
 @pytest.mark.parametrize("span_name", _SPANS)
@@ -172,6 +204,8 @@ def test_span_the_benchmark_reads_is_opened_by_the_program(span_name):
     assert {"batch", "queue_wait_ms_sum", "in_flight"} <= wanted, wanted
   if span_name == "serve/execute":  # encode_once_ and expand_in_conv_share
     assert {"encode_once", "expand_in_conv"} <= wanted, wanted
+  if span_name == "serve/turn":  # flush_turn_wait_ and transfer_hidden_share
+    assert {"landed"} <= wanted, wanted
   for path, lineno, keywords in sites:
     assert wanted <= keywords, (
         f"{path}:{lineno} opens {span_name!r} without the attrs "
@@ -204,6 +238,72 @@ def test_expand_in_conv_share_over_a_ring_of_execute_spans(attrs, share):
           declared["workloads"]) == (
               "CEM policy", "program_span", "serve_actions_per_s",
               ["qtopt_serve_closed64"])
+
+
+def _hold_cases():
+  from benchmark.tests import hold_rings
+  return [pytest.param(metric, flushes, share, id=f"{metric}-{i}")
+          for metric, (_, _, cases) in sorted(hold_rings.CASES.items())
+          for i, (flushes, share) in enumerate(cases)]
+
+
+@pytest.mark.parametrize("metric, flushes, share", _hold_cases())
+def test_hold_split_reader_over_a_ring_of_flushes(monkeypatch, metric,
+                                                  flushes, share):
+  """The three readers of the turn's hold (ISSUE 40) over a ring of
+  flushes (`benchmark/tests/hold_rings.py`): the share where the program
+  recorded what they read, of the spans that carry it where only some
+  do (the policy splits one hold in a few: those flushes stand for the
+  others), None where none does (the parent commit's program, which the
+  driver runs under these readers too). The flush's own attrs of the
+  same names are not theirs."""
+  from benchmark import harness
+  from benchmark.tests import hold_rings
+  from tensor2robot_tpu.obs import trace as trace_lib
+  monkeypatch.setattr(trace_lib, "get_tracer",
+                      lambda: hold_rings.ring_of(flushes))
+  read = harness._load_module("layer_metrics", metric).read
+  value = read({"window": {"window_s": hold_rings.WINDOW_S}, "chips": 1,
+                "trace": None})
+  assert value == (None if share is None else pytest.approx(share))
+
+
+@pytest.mark.parametrize("metric", [
+    "flush_transfer_wait_share.serve", "flush_turn_wait_share.serve",
+    "transfer_hidden_share.serve"])
+def test_hold_split_reader_is_declared_and_refuses_a_wrapped_ring(
+    monkeypatch, metric):
+  """Declared on the serving cell at the end of `per_layer`; over a ring
+  that has dropped spans it reads only if the oldest span still held
+  ended before the window began, else nothing of the window is known to
+  be whole."""
+  from benchmark import harness
+  from benchmark.tests import hold_rings
+  from tensor2robot_tpu.obs import trace as trace_lib
+  layer, better, _ = hold_rings.CASES[metric]
+  per_layer = harness.load_cell("qtopt_serve_closed64").spec["per_layer"]
+  assert metric in [m["name"] for m in per_layer[-3:]]
+  (declared,) = [m for m in per_layer if m["name"] == metric]
+  assert declared == {
+      "name": metric, "unit": "%", "better": better, "layer": layer,
+      "source": "program_span", "moves": "serve_actions_per_s",
+      "workloads": ["qtopt_serve_closed64"]}
+  read = harness._load_module("layer_metrics", metric).read
+  run = {"window": {"window_s": hold_rings.WINDOW_S}, "chips": 1,
+         "trace": None}
+  old = {"name": "serve/enqueue", "ts_s": 50.0, "dur_s": 0.001}
+  flushes = [hold_rings.SPLIT] * 2
+  rings = {"whole": hold_rings.ring_of(flushes),
+           "dropped before the window": hold_rings.ring_of(
+               flushes, dropped=7, before=[old]),
+           "dropped inside it": hold_rings.ring_of(flushes, dropped=7)}
+  values = {}
+  for name, ring in rings.items():
+    monkeypatch.setattr(trace_lib, "get_tracer", lambda ring=ring: ring)
+    values[name] = read(run)
+  assert values["whole"] is not None
+  assert values["dropped before the window"] == values["whole"]
+  assert values["dropped inside it"] is None
 
 
 # --- 2. kernel names -----------------------------------------------------------

@@ -907,19 +907,22 @@ class TestCEMFleetPolicy:
     np.testing.assert_allclose(device_out, host_out, atol=1e-4)
 
   def test_flush_phases_nest_under_the_replica_dispatch(
-      self, tiny_predictor):
+      self, tiny_predictor, monkeypatch):
     """One flush through a replica leaves exactly one each of the six
     phase spans, nested under serve/dispatch (itself under the
     batcher's serve/flush), inside it in time, carrying the flush's
-    request_ids; a compile inside a flush has a span of its own and
+    request_ids, and under serve/readback, where the hold is split,
+    its two waits; a compile inside a flush has a span of its own and
     lies outside serve/put."""
     import jax
 
     from tensor2robot_tpu.obs import trace as trace_lib
     from tensor2robot_tpu.obs.registry import MetricRegistry
+    from tensor2robot_tpu.serving import policy as policy_lib
     from tensor2robot_tpu.serving.router import FleetRouter
     from tensor2robot_tpu.serving.stats import ServingStats
 
+    monkeypatch.setattr(policy_lib, "_SPLIT_EVERY", 1)
     router = FleetRouter(
         tiny_predictor, devices=jax.devices()[:1], num_samples=16,
         num_elites=4, iterations=2, seed=0, ladder_sizes=(1, 4),
@@ -940,8 +943,9 @@ class TestCEMFleetPolicy:
       by_name.setdefault(s["name"], []).append(s)
     phases = ("serve/stack", "serve/pad", "serve/put", "serve/turn",
               "serve/execute", "serve/readback")
+    waits = ("serve/transfer_wait", "serve/program_wait")
     assert sorted(by_name) == sorted(
-        phases + ("serve/flush", "serve/dispatch")), sorted(by_name)
+        phases + waits + ("serve/flush", "serve/dispatch")), sorted(by_name)
     assert all(len(rows) == 1 for rows in by_name.values())
     (dispatch,), (flush,) = by_name["serve/dispatch"], by_name["serve/flush"]
     assert dispatch["parent"] == "serve/flush"
@@ -962,6 +966,25 @@ class TestCEMFleetPolicy:
     assert by_name["serve/execute"][0]["bucket"] == 4
     assert by_name["serve/turn"][0]["bucket"] == 4
     assert flush["in_flight"] == 0
+    # The hold, split: first the wait for the flush's own frames, then
+    # the wait for its program, both inside serve/readback on its
+    # thread; what they leave of it is D2H.
+    (readback,), (execute,) = by_name["serve/readback"], by_name["serve/execute"]
+    (transfer_wait,), (program_wait,) = (by_name[name] for name in waits)
+    for wait in (transfer_wait, program_wait):
+      assert wait["parent"] == "serve/readback"
+      assert wait["tid"] == readback["tid"]
+      assert wait["bucket"] == 4
+      assert readback["ts_s"] <= wait["ts_s"]
+      assert end(wait) <= end(readback) + 1e-5
+    assert end(transfer_wait) <= program_wait["ts_s"] + 1e-5
+    assert transfer_wait["dur_s"] + program_wait["dur_s"] <= (
+        readback["dur_s"] + 1e-5)
+    image = np.asarray(tiny_predictor.make_image(0))
+    assert transfer_wait["bytes"] == 4 * image.nbytes + 4 * 4  # + seeds
+    assert by_name["serve/turn"][0]["landed"] in (0, 1)
+    assert 0.0 <= readback["device_ms"] <= 1e3 * (
+        execute["dur_s"] + readback["dur_s"]) + 1e-2
     # Warm-up compiled both rungs, each under its own span, none of
     # them inside a put.
     compiles = [s for s in spans if s["name"] == "serve/compile"
@@ -969,19 +992,24 @@ class TestCEMFleetPolicy:
     assert {s["bucket"] for s in compiles[-2:]} == {1, 4}
     assert all(s.get("parent") != "serve/put" for s in compiles)
 
-  def test_concurrent_calls_take_turns_on_the_device(self, tiny_predictor):
+  def test_concurrent_calls_take_turns_on_the_device(self, tiny_predictor,
+                                                     monkeypatch):
     """Two callers at once (a replica with two flushes open): each gets
     the sequential call's actions and scores for every (image, seed),
     nothing recompiles, and the second's program is not enqueued until
     the first's answer is back — while its stack and put run beside it."""
     from tensor2robot_tpu.obs import trace as trace_lib
+    from tensor2robot_tpu.serving import policy as policy_lib
     from tensor2robot_tpu.serving.policy import CEMFleetPolicy
 
+    monkeypatch.setattr(policy_lib, "_SPLIT_EVERY", 1)
     policy = CEMFleetPolicy(tiny_predictor, action_size=4, num_samples=32,
                             num_elites=4, iterations=2, seed=3)
     batches = [([tiny_predictor.make_image(10 * k + i) for i in range(n)],
                 np.arange(100 * k, 100 * k + n, dtype=np.uint32))
                for k, n in ((1, 4), (2, 3))]  # both bucket 4
+    with trace_lib.get_tracer().span("test/first_call") as first_call:
+      pass
     sequential = [policy(images, seeds, return_scores=True)
                   for images, seeds in batches]
     # The first caller is held inside its device turn until the second
@@ -1032,6 +1060,20 @@ class TestCEMFleetPolicy:
     assert turn1["ts_s"] < end(readback0) <= end(turn1) + 1e-5
     # The host phases did overlap the first caller's device turn.
     assert execute0["ts_s"] < stack1["ts_s"] < end(readback0)
+    # The programs' own intervals on the device, [end - device_ms, end]
+    # with end the end of serve/program_wait, never overlap: the
+    # sequential calls', then the two concurrent ones'.
+    tracer_spans = [s for s in trace_lib.get_tracer().spans()
+                    if s["ts_s"] >= first_call["ts_s"]]
+    program_ends = [end(s) for s in tracer_spans
+                    if s["name"] == "serve/program_wait"]
+    device_ms = [s["device_ms"] for s in tracer_spans
+                 if s["name"] == "serve/readback"]
+    assert len(program_ends) == len(device_ms) == 4
+    intervals = sorted((stop - ms / 1e3, stop)
+                       for stop, ms in zip(program_ends, device_ms))
+    for (_, stop), (start, _) in zip(intervals, intervals[1:]):
+      assert stop <= start + 2e-6, intervals  # the records' rounding
 
   def test_same_answers_with_and_without_a_ledger(self, tiny_predictor):
     """The device path is one path: a ledger only adds the dispatch
@@ -1201,26 +1243,187 @@ class TestCEMFleetPolicy:
     snapshot = stats.snapshot()
     assert snapshot["flushes"] == 5 and snapshot["staged_flushes"] == 3
 
-  def test_a_call_that_raises_drops_its_array(self, tiny_predictor):
-    """A program that fails leaves the pool without that call's array
-    (nobody knows what still reads it), and the next call sound."""
+  # -- the hold, split (ISSUE 40) --------------------------------------------
+
+  _HOLD_KEYS = {"turn_wait_p50_ms", "turn_wait_p95_ms",
+                "transfer_wait_p50_ms", "transfer_wait_p95_ms",
+                "program_p50_ms", "program_p95_ms", "transfers_hidden",
+                "program_busy_share"}
+
+  def _replica(self, predictor, stats):
+    """One replica on the default device, not started: `_flush` is
+    called on the test's thread."""
+    from tensor2robot_tpu.serving.policy import CEMFleetPolicy
+    from tensor2robot_tpu.serving.router import PolicyReplica
+    return PolicyReplica(
+        CEMFleetPolicy(predictor, **self._STAGED), max_batch=4,
+        deadline_ms=50.0, stats=stats, max_queue=None,
+        dispatch_margin_ms=0.0)
+
+  @pytest.mark.parametrize("path", ["device", "host"])
+  def test_snapshot_carries_the_hold_split_of_device_flushes_only(
+      self, tiny_predictor, path, tmp_path):
+    """After a replica's flush `snapshot()` (and `write_to`, and the
+    registry) say where the device turn went; the host fallback, which
+    has no device turn to split, leaves none of it."""
+    from tensor2robot_tpu.obs import trace as trace_lib
+    from tensor2robot_tpu.obs.registry import MetricRegistry
+    from tensor2robot_tpu.serving import policy as policy_lib
+    from tensor2robot_tpu.serving.stats import ServingStats
+    from tensor2robot_tpu.utils.metric_writer import MetricWriter
+
+    class HostOnly:
+      def __init__(self, inner):
+        self._inner = inner
+
+      def device_fn(self):
+        raise NotImplementedError
+
+      def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    registry = MetricRegistry()
+    stats = ServingStats(registry=registry)
+    assert not self._HOLD_KEYS & set(stats.snapshot())
+    replica = self._replica(
+        tiny_predictor if path == "device" else HostOnly(tiny_predictor),
+        stats)
+    with trace_lib.get_tracer().span("test/hold_split") as mark:
+      pass
+    holds = policy_lib._SPLIT_EVERY + 1  # the first and the last are split
+    for r in range(holds):
+      images, seeds = self._round(tiny_predictor, 0, r)
+      assert len(replica._flush(list(zip(images, seeds)))) == len(images)
+    snapshot = stats.snapshot()
+    with MetricWriter(str(tmp_path)) as writer:
+      stats.write_to(writer, step=1)
+    with open(os.path.join(str(tmp_path), "metrics.jsonl")) as f:
+      written = json.loads(f.readline())
+    histograms = {name.rsplit("/", 1)[0] for name in registry.snapshot()
+                  if name.endswith("_ms/count")}
+    if path == "host":
+      assert replica.policy.last_call_phases is None
+      assert not self._HOLD_KEYS & set(snapshot)
+      assert not any("program" in key or "wait" in key for key in written)
+      assert not histograms
+      return
+    assert self._HOLD_KEYS <= set(snapshot)
+    assert {"serving/" + key for key in self._HOLD_KEYS} <= set(written)
+    assert histograms == {"serving/turn_wait_ms",
+                          "serving/transfer_wait_ms", "serving/program_ms"}
+    # (Two split holds stand for eight here, the first of them the
+    # program's first run: an estimate, not bounded by 1.)
+    assert snapshot["program_busy_share"] > 0.0
+    for name in ("turn_wait", "transfer_wait", "program"):
+      assert 0.0 <= snapshot[f"{name}_p50_ms"] <= snapshot[f"{name}_p95_ms"]
+    # The same reads as the ring's. Every turn says whether the frames
+    # had landed; one hold in `_SPLIT_EVERY` is split into its two
+    # waits and carries `device_ms`, the others' serve/readback is
+    # whole.
+    mine = [s for s in trace_lib.get_tracer().spans()
+            if s["ts_s"] >= mark["ts_s"]
+            and s["tid"] == threading.get_ident()]
+    turns = [s for s in mine if s["name"] == "serve/turn"]
+    readbacks = [s for s in mine if s["name"] == "serve/readback"]
+    assert len(turns) == len(readbacks) == holds
+    assert snapshot["transfers_hidden"] == sum(s["landed"] for s in turns)
+    assert ["device_ms" in s for s in readbacks] == (
+        [True] + [False] * (holds - 2) + [True])
+    for wait in ("serve/transfer_wait", "serve/program_wait"):
+      assert [s["ts_s"] >= readbacks[-1]["ts_s"] for s in mine
+              if s["name"] == wait] == [False, True]
+    device_ms = [s["device_ms"] for s in (readbacks[0], readbacks[-1])]
+    assert snapshot["program_p95_ms"] == pytest.approx(max(device_ms),
+                                                       abs=1e-3)
+    # The mean program of the split holds over the mean period of all.
+    end = lambda s: s["ts_s"] + s["dur_s"]
+    period_ms = 1e3 * (end(readbacks[-1]) - end(readbacks[0])) / (holds - 1)
+    assert snapshot["program_busy_share"] == pytest.approx(
+        sum(device_ms) / 2 / period_ms, abs=2e-4)
+    phases = replica.policy.last_call_phases
+    assert phases["program_ms"] == pytest.approx(device_ms[-1], abs=1e-3)
+    assert set(phases) == {"turn_wait_ms", "landed", "period_ms",
+                           "transfer_wait_ms", "program_ms"}
+
+  def test_busy_share_is_each_replicas_holds_against_its_own_periods(self):
+    """Two replicas whose programs each fill half of the same seconds
+    are half busy, not wholly: every hold brings its own policy's
+    period, and the split holds' programs stand for the others'."""
+    from tensor2robot_tpu.obs.registry import MetricRegistry
+    from tensor2robot_tpu.serving.stats import ServingStats
+
+    stats = ServingStats(registry=MetricRegistry())
+    for landed in (1, 0):  # a replica each
+      for k in range(7):  # 100 ms of program in every 200 ms
+        phases = dict(turn_wait_ms=5.0, landed=landed)
+        if k:
+          phases["period_ms"] = 200.0
+        if k % 3 == 0:  # the policy split this hold
+          phases.update(transfer_wait_ms=7.0 * (1 - landed), program_ms=100.0)
+        stats.record_flush_phases(**phases)
+    snapshot = stats.snapshot()
+    assert snapshot["transfers_hidden"] == 7
+    assert snapshot["program_busy_share"] == pytest.approx(0.5, abs=1e-4)
+    assert snapshot["transfer_wait_p95_ms"] == 7.0
+    assert snapshot["program_p50_ms"] == snapshot["turn_wait_p95_ms"] * 20
+    # Before a second hold there is no period to set a program against.
+    first = ServingStats(registry=MetricRegistry())
+    first.record_flush_phases(turn_wait_ms=5.0, landed=0,
+                              transfer_wait_ms=7.0, program_ms=100.0)
+    assert first.snapshot()["program_busy_share"] is None
+
+  def test_a_phase_feed_that_raises_does_not_fail_the_flush(
+      self, tiny_predictor):
+    """The stats feed is diagnostics: a sink that raises on the phases
+    costs the flush nothing, and the feeds before it were taken."""
+    from tensor2robot_tpu.obs.registry import MetricRegistry
+    from tensor2robot_tpu.serving.stats import ServingStats
+
+    class Raising(ServingStats):
+      def record_flush_phases(self, *args, **kwargs):
+        raise RuntimeError("no room for phases")
+
+    stats = Raising(registry=MetricRegistry())
+    sound = self._replica(tiny_predictor,
+                          ServingStats(registry=MetricRegistry()))
+    replica = self._replica(tiny_predictor, stats)
+    images, seeds = self._round(tiny_predictor, 1, 0)
+    items = list(zip(images, seeds))
+    np.testing.assert_array_equal(np.stack(replica._flush(items)),
+                                  np.stack(sound._flush(items)))
+    snapshot = stats.snapshot()
+    assert snapshot["q_sketches"] and not self._HOLD_KEYS & set(snapshot)
+
+  @pytest.mark.parametrize("what", ["the program", "the frames' readiness"])
+  def test_a_call_that_raises_drops_its_array(self, tiny_predictor, what,
+                                              monkeypatch):
+    """A program that fails, or a device array that cannot say whether
+    it has landed once the turn is held, leaves the pool without that
+    call's array (nobody knows what still reads it), the turn free and
+    the next call sound."""
     from tensor2robot_tpu.serving.policy import CEMFleetPolicy
 
     policy = CEMFleetPolicy(tiny_predictor, **self._STAGED)
     key = ((4, 8, 8, 3), np.dtype(np.float32))
     images, seeds = self._round(tiny_predictor, 0, 0)
     want_actions, want_scores = policy(images, seeds, return_scores=True)
-    real = policy._executables[4]
 
     def failing(*args):
       raise RuntimeError("device lost")
 
-    policy._executables[4] = failing
-    with pytest.raises(RuntimeError, match="device lost"):
-      policy(images, seeds)
+    class Lost:
+      is_ready = failing
+
+    with monkeypatch.context() as patched:
+      if what == "the program":
+        patched.setitem(policy._executables, 4, failing)
+      else:
+        patched.setattr(policy, "_put", lambda array: Lost())
+      with pytest.raises(RuntimeError, match="device lost"):
+        policy(images, seeds)
     assert policy._staging.sizes()[key] == 0
     assert not policy._turn.locked()
-    policy._executables[4] = real
+    assert policy.last_call_phases is None
     actions, scores = policy(*self._round(tiny_predictor, 1, 0),
                              return_scores=True)  # other frames between
     assert not policy.last_call_reused_staging
