@@ -38,7 +38,6 @@ from tensor2robot_tpu import modes
 from tensor2robot_tpu.config import configurable
 from tensor2robot_tpu.layers import sequence
 from tensor2robot_tpu.models.abstract_model import AbstractT2RModel, Metrics
-from tensor2robot_tpu.ops import gated_delta_rule
 from tensor2robot_tpu.specs import tensorspec_utils as ts
 
 _CONFIG_FIELDS = frozenset(
@@ -106,15 +105,9 @@ class _Period(nn.Module):
     moe, gdn = [], []
     for i in range(c.full_attention_interval):
       # CSE prevented: a scan of one period is unrolled, and the blocks'
-      # recomputation would then be merged with their first run. Of a
-      # block only the delta rule's chunk inverses are kept from the
-      # first run (67 MB a layer at T = 8,192): ten dependent products
-      # each, more than the rest of the chunk-local part together.
-      x, counters = nn.remat(
-          sequence.DecoderBlock,
-          policy=jax.checkpoint_policies.save_only_these_names(
-              gated_delta_rule.INVERSE_NAME))(
-                  c, True, self.dtype, c.layer_kind(i), name=f"block{i}")(x)
+      # recomputation would then be merged with their first run.
+      x, counters = nn.remat(sequence.DecoderBlock)(
+          c, True, self.dtype, c.layer_kind(i), name=f"block{i}")(x)
       gates = {k: counters.pop(k) for k in list(counters)
                if k.startswith("gdn/")}
       moe.append(counters)

@@ -145,6 +145,52 @@ class TestGatedDeltaRule:
       np.testing.assert_allclose(np.asarray(a, np.float32),
                                  np.asarray(b, np.float32), atol=0.03 * scale)
 
+  # (key heads, value heads, T): each key head serving one value head and
+  # two; one grid step of chunks (one chunk, three) and several (16
+  # chunks, two steps).
+  @pytest.mark.parametrize("key_heads, value_heads, t",
+                           [(2, 2, 64), (1, 2, 192), (2, 2, 1024),
+                            (1, 2, 1024)])
+  def test_prep_programs_match_the_xla_chunk_local_part(self, key_heads,
+                                                        value_heads, t):
+    args = _rule_inputs(t, key_heads=key_heads, value_heads=value_heads)
+    chunk = min(rule_lib.CHUNK, t)
+    xla = lambda *a: rule_lib._prepare(*a, chunk)
+    want, xla_vjp = jax.vjp(xla, *args)
+    got, prep_vjp = jax.vjp(rule_lib._prep_pallas, *args)
+    # The running sums of g are summed in another order: each decay to
+    # a few float32 roundings of G, relative.
+    for g, w in zip(got, want):
+      assert g.shape == w.shape and g.dtype == w.dtype
+      np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-4,
+                                 atol=2e-6 * float(jnp.max(jnp.abs(w))))
+    r = np.random.default_rng(11)
+    cotangents = tuple(jnp.asarray(r.standard_normal(w.shape), w.dtype)
+                       for w in want)
+    for g, w in zip(prep_vjp(cotangents), xla_vjp(cotangents)):
+      assert g.shape == w.shape and g.dtype == w.dtype
+      np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                 atol=2e-5 * float(jnp.max(jnp.abs(w))))
+
+  @pytest.mark.parametrize("implementation, taken",
+                           [("pallas", "prep_programs"), ("xla", "prep_xla"),
+                            ("auto", "prep_xla")])
+  def test_the_counters_read_the_path_each_call_took(self, implementation,
+                                                      taken):
+    """Off a TPU "auto" is XLA's. One count a traced call: a jit traced
+    once and run twice counts once."""
+    from tensor2robot_tpu.obs.registry import get_registry
+    counters = {path: get_registry().counter(f"gated_delta_rule/{path}")
+                for path in ("prep_programs", "prep_xla")}
+    before = {path: c.value for path, c in counters.items()}
+    run = jax.jit(functools.partial(rule_lib.gated_delta_rule,
+                                    implementation=implementation))
+    args = _rule_inputs(64)
+    run(*args)
+    run(*args)
+    assert {path: c.value - before[path] for path, c in counters.items()} == {
+        path: int(path == taken) for path in counters}
+
   def test_without_decay_and_full_writes_it_is_the_plain_delta_rule(self):
     q, k, v, _, _ = _rule_inputs(128)
     zeros, ones = jnp.zeros(v.shape[:3]), jnp.ones(v.shape[:3])

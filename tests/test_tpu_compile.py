@@ -113,8 +113,9 @@ def test_gated_delta_rule_compiles_at_8k_forward_and_backward(one_chip,
                                                               as_on_tpu):
   """The delta net's shapes: 16 q/k heads and 32 value heads of 128,
   T = 8,192 in chunks of 64, bf16 operands with float32 decays: both
-  walks contract over the chunk's rows (a transposed left operand),
-  which interpret mode takes whatever Mosaic makes of it."""
+  walks contract over the chunk's rows (a transposed left operand), and
+  the prep programs transpose 64x64 float32 products at float32's
+  precision, which interpret mode takes whatever Mosaic makes of them."""
   import importlib
   rule = importlib.import_module("tensor2robot_tpu.ops.gated_delta_rule")
   shape = lambda heads, dtype=jnp.bfloat16, width=(128,): (
@@ -129,9 +130,13 @@ def test_gated_delta_rule_compiles_at_8k_forward_and_backward(one_chip,
   compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
       shape(16), shape(16), shape(32), gate, gate).compile()
   text = compiled.as_text()
+  # The two walks and the two prep programs, each an instruction of its
+  # own ("gated_delta_rule_prep" is also the start of the backward's
+  # name, so the instruction's whole name is matched).
+  assert len(rule.KERNEL_NAMES) == 4
   for name in rule.KERNEL_NAMES:
-    assert name in text
-  assert text.count("tpu_custom_call") >= 2
+    assert re.search(rf"{name}_*\.\d+ = ", text), name
+  assert text.count("tpu_custom_call") >= 4
 
 
 def test_hyper_connection_compiles_at_4k_forward_and_backward(one_chip,
