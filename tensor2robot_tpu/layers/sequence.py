@@ -1,15 +1,18 @@
-"""Decoder blocks of a sparse-expert sequence model: RMSNorm (plain or
-zero-centred), rotary positions (interleaved over the whole width, plain
-or under YaRN, or half-split over the head's first part), three token
-mixers (multi-head latent attention, gated grouped-query attention, the
-gated delta net; training forms), gated MLP, the expert layer (routing
-and grouping in `parallel/expert_parallel`), the residual rule (plain,
-or manifold-constrained hyper-connections over `hc_mult` streams) and a
+"""Decoder blocks of a sequence model: RMSNorm (plain or zero-centred),
+rotary positions (interleaved over the whole width, plain or under
+YaRN, or half-split over the head's first part), five token mixers
+(multi-head latent attention, gated grouped-query attention, the gated
+delta net, block-sparse attention, Lightning attention; training
+forms), gated MLP, the expert layer (routing and grouping in
+`parallel/expert_parallel`), the residual rule (plain, scaled, or
+manifold-constrained hyper-connections over `hc_mult` streams) and a
 chunked next-token loss.
 
 Layer equations: DeepSeek-V2/V3's, which the JoyAI-LLM-Flash and
 Xing4.0 configs follow, Qwen3-Next's (gated attention and Gated Delta
-Networks, arXiv:2412.06464) and mHC's (arXiv:2512.24880); see
+Networks, arXiv:2412.06464), mHC's (arXiv:2512.24880) and
+MiniCPM-SALA's (InfLLM-v2's sparse attention, arXiv:2506.07900, beside
+Lightning attention, arXiv:2401.04658, under muP scalings); see
 `SequenceConfig`'s fields and each module.
 Activations run in `dtype` (bfloat16), parameters are float32; norms,
 rotary angles, the router, the softmax statistics, the delta net's
@@ -33,14 +36,18 @@ from tensor2robot_tpu.ops.flash_attention import flash_attention
 from tensor2robot_tpu.ops.gated_delta_rule import gated_delta_rule
 from tensor2robot_tpu.ops.hyper_connection import (
     MapConfig, hyper_connection_post, hyper_connection_pre)
+from tensor2robot_tpu.ops.lightning_attention import (
+    lightning_attention, log_decays)
+from tensor2robot_tpu.ops.sparse_attention import (
+    block_sparse_attention, select_blocks)
 from tensor2robot_tpu.parallel import expert_parallel
 
 
 @dataclasses.dataclass(frozen=True)
 class SequenceConfig:
   """Sizes under their names in the published `config.json`; the chip's
-  share (`experts_held`, `first_expert`, the vocabulary slice) beside
-  them."""
+  share (`experts_held`, `first_expert`, `first_head`, the vocabulary
+  slice) beside them."""
   vocab_size: int = 129280
   hidden_size: int = 2048
   num_attention_heads: int = 32
@@ -94,11 +101,51 @@ class SequenceConfig:
   hc_eps: float = 1e-6
   mhc_h_res_clamp_min: float = -30.0
   mhc_h_res_clamp_max: float = 30.0
+  # Set: each layer's mixer by name, "minicpm4" (block-sparse attention)
+  # or "lightning-attn" (Lightning attention), the first
+  # `num_hidden_layers` of them held; every FFN then the dense gated MLP
+  # (MiniCPM-SALA). None: the layouts above.
+  mixer_types: Optional[Any] = None
+  # The sparse layer: q and k normed per head (one weight for all heads),
+  # rotary or not, the output times sigmoid(x . W_g).
+  qk_norm: bool = False
+  attn_use_rope: bool = True
+  attn_use_output_gate: bool = False
+  # InfLLM-v2's selection, as MiniCPM4's published `sparse_config`
+  # (block_size, kernel_size, kernel_stride, init_blocks, window_size,
+  # topk, dense_len); given as a dict, kept as its sorted items.
+  sparse_config: Optional[Any] = None
+  # The Lightning layer: `lightning_nh` heads of `lightning_head_dim`
+  # held (q, k, v alike), q and k normed per head, rotary over the whole
+  # head (half-split) where `lightning_use_rope`, q over sqrt(width); the
+  # output normed per head (`use_output_norm`) and times sigmoid(x . W_g)
+  # (`use_output_gate`).
+  lightning_nh: int = 0
+  lightning_nkv: int = 0
+  lightning_head_dim: int = 0
+  lightning_use_rope: bool = False
+  use_output_gate: bool = False
+  use_output_norm: bool = False
+  # muP: the embedding times `scale_emb`, each residual branch times
+  # scale_depth / sqrt(the published layer count) (0: unscaled), the
+  # final hidden over hidden_size / dim_model_base (0: not divided).
+  scale_emb: float = 1.0
+  scale_depth: float = 0.0
+  dim_model_base: int = 0
+  # The share of a tensor-parallel deployment: the first query head (and
+  # Lightning head) held; the published head and layer counts the
+  # decays and the residual scale read (0: the held ones).
+  first_head: int = 0
+  published_lightning_nh: int = 0
+  published_num_hidden_layers: int = 0
 
   def __post_init__(self):
-    if isinstance(self.rope_scaling, dict):
-      object.__setattr__(self, "rope_scaling",
-                         tuple(sorted(self.rope_scaling.items())))
+    for name in ("rope_scaling", "sparse_config"):
+      if isinstance(getattr(self, name), dict):
+        object.__setattr__(self, name,
+                           tuple(sorted(getattr(self, name).items())))
+    if isinstance(self.mixer_types, list):
+      object.__setattr__(self, "mixer_types", tuple(self.mixer_types))
 
   @property
   def num_expert_layers(self) -> int:
@@ -108,8 +155,20 @@ class SequenceConfig:
   def hybrid(self) -> bool:
     return self.full_attention_interval > 0
 
+  @property
+  def residual_scale(self) -> float:
+    """scale_depth / sqrt(the published layer count), or 0: unscaled."""
+    if not self.scale_depth:
+      return 0.0
+    layers = self.published_num_hidden_layers or self.num_hidden_layers
+    return self.scale_depth / math.sqrt(layers)
+
   def layer_kind(self, layer: int) -> str:
-    """"mla", or in the hybrid layout "full" | "linear"."""
+    """"mla", in the hybrid layout "full" | "linear", under `mixer_types`
+    "sparse" | "lightning"."""
+    if self.mixer_types:
+      return {"minicpm4": "sparse",
+              "lightning-attn": "lightning"}[self.mixer_types[layer]]
     if not self.hybrid:
       return "mla"
     return ("full" if (layer + 1) % self.full_attention_interval == 0
@@ -360,6 +419,118 @@ class GatedDeltaNet(nn.Module):
                "gdn/beta_mean": jnp.mean(beta)}
 
 
+def _head_norm(x, eps: float):
+  """x · rsqrt(mean(x²) + eps) over the last axis, float32."""
+  x = x.astype(jnp.float32)
+  return x * jax.lax.rsqrt(jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+                           + eps)
+
+
+class SparseAttention(nn.Module):
+  """Block-sparse grouped-query attention (MiniCPM4's InfLLM-v2, as
+  MiniCPM-SALA's "minicpm4" layers run it), training form:
+  `num_attention_heads` query heads on `num_key_value_heads` key/value
+  heads of `head_dim`, q and k normed per head (`qk_norm`, one weight for
+  all heads), rotary over the whole head (half-split) only where
+  `attn_use_rope`; each group selects its key blocks (`select_blocks`,
+  under `sala/select`, no gradient) and its queries attend to those
+  alone (`block_sparse_attention`, under `sala/attend`), scale
+  1/sqrt(head_dim); the output times sigmoid(x · W_g) where
+  `attn_use_output_gate`. Returns (y, {"sala/selected_mass",
+  "sala/visited_blocks", "sala/selected_blocks"}: the share of the
+  selection scores on the selected blocks, and the (token, block) pairs
+  the kernels visited and the tokens selected)."""
+  config: SequenceConfig
+  dtype: Any = jnp.bfloat16
+
+  @nn.compact
+  def __call__(self, x):
+    c = self.config
+    b, t, _ = x.shape
+    heads, kv, hd = c.num_attention_heads, c.num_key_value_heads, c.head_dim
+    sizes = dict(c.sparse_config)
+    norm = lambda name: RMSNorm(c.rms_norm_eps, jnp.float32, name=name)
+    turn = lambda a: (rotary_half_split(a, c.rope_theta, hd)
+                      if c.attn_use_rope else a).astype(self.dtype)
+    with jax.named_scope("sala/proj"):
+      q = _dense(heads * hd, self.dtype, "q_proj")(x).reshape(b, t, heads, hd)
+      k = _dense(kv * hd, self.dtype, "k_proj")(x).reshape(b, t, kv, hd)
+      v = _dense(kv * hd, self.dtype, "v_proj")(x).reshape(b, t, kv, hd)
+      if c.qk_norm:
+        q, k = norm("q_norm")(q), norm("k_norm")(k)
+      q, k = turn(q), turn(k)
+    with jax.named_scope("sala/select"):
+      selected, mass = select_blocks(
+          q, k, block=sizes["block_size"], kernel=sizes["kernel_size"],
+          stride=sizes["kernel_stride"], top_k=sizes["topk"],
+          init_blocks=sizes["init_blocks"], window=sizes["window_size"],
+          dense_len=sizes["dense_len"])
+    with jax.named_scope("sala/attend"):
+      out, visits = block_sparse_attention(
+          q, k, v, selected, block=sizes["block_size"],
+          scale=1.0 / math.sqrt(hd))
+    with jax.named_scope("sala/out"):
+      if c.attn_use_output_gate:
+        gate = _dense(heads * hd, self.dtype, "o_gate")(x)
+        out = (out.reshape(b, t, heads * hd).astype(jnp.float32)
+               * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype)
+      y = _dense(c.hidden_size, self.dtype, "o_proj")(
+          out.reshape(b, t, heads * hd))
+    return y, {"sala/selected_mass": mass,
+               "sala/visited_blocks": visits["visited"],
+               "sala/selected_blocks": visits["selected"]}
+
+
+class LightningAttention(nn.Module):
+  """Lightning attention (MiniCPM-SALA's "lightning-attn" layers),
+  training form: `lightning_nh` heads of `lightning_head_dim` for q, k
+  and v, q and k normed per head (`qk_norm`, one weight for all heads),
+  rotary over the whole head (half-split) where `lightning_use_rope`, q
+  over sqrt(width); the decayed linear recurrence with each head's fixed
+  decay (`ops/lightning_attention`, heads `first_head` on of the
+  published count, at this block's `layer` of the published layers),
+  under `lightning/scan`; the output normed per head with a weight per
+  channel (`use_output_norm`) and times sigmoid(x · W_g)
+  (`use_output_gate`). Returns (y, {"lightning/decay_mean": the mean of
+  the held heads' decays})."""
+  config: SequenceConfig
+  dtype: Any = jnp.bfloat16
+  layer: int = 0
+
+  @nn.compact
+  def __call__(self, x):
+    c = self.config
+    b, t, _ = x.shape
+    heads, hd = c.lightning_nh, c.lightning_head_dim
+    norm = lambda name: RMSNorm(c.rms_norm_eps, jnp.float32, name=name)
+    turn = lambda a: (rotary_half_split(a, c.rope_theta, hd)
+                      if c.lightning_use_rope else a)
+    with jax.named_scope("lightning/proj"):
+      q, k, v = (_dense(heads * hd, self.dtype, name)(x).reshape(
+          b, t, heads, hd) for name in ("q_proj", "k_proj", "v_proj"))
+      if c.qk_norm:
+        q, k = norm("q_norm")(q), norm("k_norm")(k)
+      q = (turn(q).astype(jnp.float32) / math.sqrt(hd)).astype(self.dtype)
+      k = turn(k).astype(self.dtype)
+    with jax.named_scope("lightning/scan"):
+      log_decay = log_decays(
+          c.published_lightning_nh or heads, c.first_head, heads, self.layer,
+          c.published_num_hidden_layers or c.num_hidden_layers)
+      out = lightning_attention(q, k, v, log_decay)
+    with jax.named_scope("lightning/out"):
+      out = out.reshape(b, t, heads, hd)
+      if c.use_output_norm:
+        scale = self.param("o_norm", nn.initializers.ones, (heads * hd,),
+                           jnp.float32)
+        out = _head_norm(out, c.rms_norm_eps) * scale.reshape(heads, hd)
+      out = out.reshape(b, t, heads * hd).astype(jnp.float32)
+      if c.use_output_gate:
+        out = out * jax.nn.sigmoid(
+            _dense(heads * hd, self.dtype, "o_gate")(x).astype(jnp.float32))
+      y = _dense(c.hidden_size, self.dtype, "o_proj")(out.astype(self.dtype))
+    return y, {"lightning/decay_mean": jnp.mean(jnp.exp(log_decay))}
+
+
 class GatedMLP(nn.Module):
   """down(silu(gate x) ⊙ up x), no biases."""
   width: int
@@ -475,16 +646,21 @@ MHC_COUNTERS = ("mhc/res_diag_mean", "mhc/pre_mean", "mhc/post_mean",
 class DecoderBlock(nn.Module):
   """h = x + Mixer(norm(x)); y = h + FFN(norm(h)); the mixer by the
   layer's `kind` ("mla", "full": gated attention, "linear": the gated
-  delta net), FFN the dense gated MLP or the expert layer. With
-  `hc_mult` streams x is (B, T, n·D) and each of the two sublayers
-  reads, writes and mixes it through its own `HyperConnection`. Returns
-  (y, the expert layer's counters, with a delta net's `gdn/*` and the
+  delta net, "sparse": block-sparse attention, "lightning": Lightning
+  attention at the published `layer`), FFN the dense gated MLP or the
+  expert layer; under `scale_depth` each branch is scaled by the
+  config's `residual_scale`. With `hc_mult` streams x is (B, T, n·D)
+  and each of the two sublayers reads, writes and mixes it through its
+  own `HyperConnection`. Returns (y, the expert layer's counters, with a
+  delta net's `gdn/*`, a sparse layer's `sala/*`, a Lightning layer's
+  `lightning/*` and the
   hyper-connections' `mhc/*` (each (2,), by sublayer) beside them; None
   where the block has none)."""
   config: SequenceConfig
   experts: bool
   dtype: Any = jnp.bfloat16
   kind: str = "mla"
+  layer: int = 0
 
   @nn.compact
   def __call__(self, x, _=None):
@@ -495,6 +671,9 @@ class DecoderBlock(nn.Module):
 
     def sublayer(x, name, inner):
       """`inner` under the block's residual rule."""
+      if c.residual_scale:
+        branch = inner(norm(name + "_norm")(x)).astype(jnp.float32)
+        return x + (branch * c.residual_scale).astype(x.dtype)
       if not c.hc_mult:
         return x + inner(norm(name + "_norm")(x))
       hc = HyperConnection(c, self.dtype, name=name + "_hc")
@@ -503,6 +682,15 @@ class DecoderBlock(nn.Module):
       return hc.post(x, inner(norm(name + "_norm")(u)), h)
 
     def mixer(inner):
+      if self.kind == "sparse":
+        mixed, visits = SparseAttention(c, self.dtype, name="attn")(inner)
+        extra.update(visits)
+        return mixed
+      if self.kind == "lightning":
+        mixed, decays = LightningAttention(c, self.dtype, self.layer,
+                                           name="attn")(inner)
+        extra.update(decays)
+        return mixed
       if self.kind == "linear":
         mixed, gates = GatedDeltaNet(c, self.dtype, name="attn")(inner)
         extra.update(gates)

@@ -194,7 +194,8 @@ def _walk_xla(qg, kg, w, u, m, a):
 def _fwd_kernel(qg_ref, kg_ref, w_ref, u_ref, m_ref, a_ref, o_ref, *rest,
                 chunk: int, block_chunks: int):
   """One head's next `block_chunks` chunks from the state in scratch;
-  with a states output, each chunk's starting state is kept."""
+  with a states output, each chunk's starting state is kept. Without
+  `w_ref` (Lightning attention's walk) v' = u: nothing is corrected."""
   h_ref, state_ref = rest if len(rest) == 2 else (None, rest[0])
 
   @pl.when(pl.program_id(1) == 0)
@@ -208,9 +209,12 @@ def _fwd_kernel(qg_ref, kg_ref, w_ref, u_ref, m_ref, a_ref, o_ref, *rest,
     held = state.astype(dtype)
     if h_ref is not None:
       h_ref[0, j] = held
-    fresh = u_ref[0, rows, :].astype(jnp.float32) - _dot(
-        w_ref[0, rows, :], held)
-    fresh_in = fresh.astype(dtype)
+    if w_ref is None:
+      fresh_in = u_ref[0, rows, :]
+    else:
+      fresh = u_ref[0, rows, :].astype(jnp.float32) - _dot(
+          w_ref[0, rows, :], held)
+      fresh_in = fresh.astype(dtype)
     o = _dot(qg_ref[0, rows, :], held) + _dot(m_ref[0, rows, :], fresh_in)
     o_ref[0, rows, :] = o.astype(o_ref.dtype)
     state = a_ref[0, j:j + 1, :] * state + _dot(
@@ -226,7 +230,8 @@ def _bwd_kernel(qg_ref, kg_ref, w_ref, u_ref, m_ref, a_ref, h_ref, do_ref,
   w S, and dO given:
     dv' = M^T dO + kg dS;   du = dv';   dw = -dv' S^T
     dqg = dO S^T;  dM = dO v'^T;  dkg = v' dS^T;  da = <dS, S>
-    dS <- a dS + qg^T dO - w^T dv'"""
+    dS <- a dS + qg^T dO - w^T dv'
+  Without `w_ref` (and `dw_ref`) v' = u, and the w terms are not made."""
 
   @pl.when(pl.program_id(1) == 0)
   def _():
@@ -237,22 +242,28 @@ def _bwd_kernel(qg_ref, kg_ref, w_ref, u_ref, m_ref, a_ref, h_ref, do_ref,
   for j in reversed(range(block_chunks)):
     rows = slice(j * chunk, (j + 1) * chunk)
     held, ds_in = h_ref[0, j], ds.astype(dtype)
-    qg, kg, w = qg_ref[0, rows, :], kg_ref[0, rows, :], w_ref[0, rows, :]
+    qg, kg = qg_ref[0, rows, :], kg_ref[0, rows, :]
+    w = None if w_ref is None else w_ref[0, rows, :]
     do = do_ref[0, rows, :]
-    fresh = (u_ref[0, rows, :].astype(jnp.float32)
-             - _dot(w, held)).astype(dtype)
+    if w is None:
+      fresh = u_ref[0, rows, :]
+    else:
+      fresh = (u_ref[0, rows, :].astype(jnp.float32)
+               - _dot(w, held)).astype(dtype)
     dfresh = _dot(m_ref[0, rows, :], do, _TN) + _dot(kg, ds_in)
     dfresh_in = dfresh.astype(dtype)
     du_ref[0, rows, :] = dfresh.astype(du_ref.dtype)
-    dw_ref[0, rows, :] = (-_dot(dfresh_in, held, _NT)).astype(dw_ref.dtype)
+    if w is not None:
+      dw_ref[0, rows, :] = (-_dot(dfresh_in, held, _NT)).astype(dw_ref.dtype)
     dqg_ref[0, rows, :] = _dot(do, held, _NT).astype(dqg_ref.dtype)
     dm_ref[0, rows, :] = _dot(do, fresh, _NT).astype(dm_ref.dtype)
     dkg_ref[0, rows, :] = _dot(fresh, ds_in, _NT).astype(dkg_ref.dtype)
     # Summed over Dk here, over the lanes (Dv) by the caller.
     da_ref[0, j:j + 1, :] = jnp.sum(ds * held.astype(jnp.float32), axis=0,
                                     keepdims=True)
-    ds = (a_ref[0, j:j + 1, :] * ds + _dot(qg, do, _TN)
-          - _dot(w, dfresh_in, _TN))
+    ds = a_ref[0, j:j + 1, :] * ds + _dot(qg, do, _TN)
+    if w is not None:
+      ds = ds - _dot(w, dfresh_in, _TN)
   ds_ref[...] = ds
 
 

@@ -1,13 +1,18 @@
-"""SequenceMoEModel: a decoder-only sparse-expert language model on the
-`AbstractT2RModel` contract, trained through `Trainer.train_steps`.
+"""SequenceMoEModel: a decoder-only language model, sparse-expert or
+dense, on the `AbstractT2RModel` contract, trained through
+`Trainer.train_steps`.
 
-Two layouts, by the configuration's sizes under their published names:
+Three layouts, by the configuration's sizes under their published names:
 JoyAI-LLM-Flash's (DeepSeek-V3: MLA attention, `first_k_dense_replace`
 dense blocks, then equal expert blocks of routed top-k experts plus a
 shared expert, a depth-1 multi-token prediction module), and, where
 `full_attention_interval` is set, Qwen3-Next's hybrid (periods of that
 many expert blocks, gated delta nets and then one gated grouped-query
-attention block; no dense lead). Where `hc_mult` is set the residual is
+attention block; no dense lead), and, where `mixer_types` is set,
+MiniCPM-SALA's (each layer's mixer by name, block-sparse or Lightning
+attention, every FFN the dense gated MLP, no experts; the embedding,
+residual branches and final hidden under muP's scales). Where `hc_mult`
+is set the residual is
 that many streams wide (Xing4.0's manifold-constrained
 hyper-connections): the embedding fans out to the streams, the blocks
 carry them side by side, (B, T, n·D), and the final norm reads their
@@ -21,7 +26,8 @@ slice.
 Features: `tokens`, int32 (B, T); no labels, no `batch_stats`. The
 module computes in `compute_dtype` with float32 parameters, recomputes
 each block on the backward pass and scans the equal expert blocks (or
-the equal periods), so the program holds one of them.
+the equal periods), so the program holds one of them; under
+`mixer_types` the blocks differ and are unrolled.
 """
 
 from __future__ import annotations
@@ -130,7 +136,11 @@ class _SequenceModule(nn.Module):
                      param_dtype=jnp.float32, name="embed")
     head = self.param("head", nn.initializers.lecun_normal(),
                       (c.hidden_size, c.vocab_size), jnp.float32)
-    x = _fan_out(embed(tokens), c)
+    embedded = embed(tokens)
+    if c.scale_emb != 1.0:
+      embedded = (embedded.astype(jnp.float32) * c.scale_emb).astype(
+          self.dtype)
+    x = _fan_out(embedded, c)
     mhc = []  # each block's `mhc/*` counters, in layer order
     for i in range(c.first_k_dense_replace):
       x, counters = nn.remat(sequence.DecoderBlock)(
@@ -138,7 +148,22 @@ class _SequenceModule(nn.Module):
       if c.hc_mult:
         mhc.append(jax.tree_util.tree_map(lambda a: a[None], counters))
     outputs = {}
-    if c.hybrid:
+    counters = None
+    if c.mixer_types:
+      by_kind = {"sala": [], "lightning": []}
+      for i in range(c.num_hidden_layers):
+        x, extra = nn.remat(sequence.DecoderBlock)(
+            c, False, self.dtype, c.layer_kind(i), i, name=f"block{i}")(x)
+        for kind, rows in by_kind.items():
+          named, _ = _split_counters(extra or {}, kind + "/")
+          if named:
+            rows.append(named)
+      for kind, rows in by_kind.items():
+        if rows:
+          # (layers of that kind,) each, in layer order.
+          outputs[kind + "_counters"] = jax.tree_util.tree_map(
+              lambda *rows: jnp.stack(rows), *rows)
+    elif c.hybrid:
       stack = nn.scan(
           _Period, variable_axes={"params": 0}, split_rngs={"params": True},
           length=c.num_hidden_layers // c.full_attention_interval)
@@ -157,8 +182,12 @@ class _SequenceModule(nn.Module):
       maps, counters = _split_counters(counters, "mhc/")
       mhc.append(maps)
     x = _collapse(x, c)
-    final = sequence.RMSNorm(c.rms_norm_eps, self.dtype,
-                             c.zero_centered_norm, name="final_norm")
+    norm = sequence.RMSNorm(c.rms_norm_eps, self.dtype,
+                            c.zero_centered_norm, name="final_norm")
+    final = norm
+    if c.dim_model_base:
+      # Over a power of two at the published sizes: exact in bf16.
+      final = lambda h: norm(h) / (c.hidden_size / c.dim_model_base)
     if mode == modes.PREDICT:
       with jax.named_scope("lm_head"):
         return {"logits": jnp.dot(final(x), head.astype(self.dtype),
@@ -176,7 +205,8 @@ class _SequenceModule(nn.Module):
         mhc.append(jax.tree_util.tree_map(lambda a: a[None], maps))
       counters = jax.tree_util.tree_map(
           lambda a, b: jnp.concatenate([a, b[None]]), counters, extra)
-    outputs["moe_counters"] = counters
+    if counters is not None:
+      outputs["moe_counters"] = counters
     if mhc:
       outputs["mhc_counters"] = jax.tree_util.tree_map(
           lambda *rows: jnp.concatenate(rows), *mhc)
@@ -230,15 +260,16 @@ class SequenceMoEModel(AbstractT2RModel):
     if "token_loss_mtp" in outputs:
       metrics["loss_mtp"] = mean_over(outputs["token_loss_mtp"], t - 2)
       loss = loss + self._config.mtp_loss_weight * metrics["loss_mtp"]
-    counters = outputs["moe_counters"]
-    per_expert = counters["expert_tokens"]          # (layers, held)
-    metrics.update({
-        "moe/expert_tokens": per_expert,
-        "moe/held_assignments": jnp.sum(counters["held_assignments"]),
-        "moe/total_assignments": jnp.sum(counters["total_assignments"]),
-        "moe/max_expert_tokens": jnp.max(per_expert),
-        "moe/min_expert_tokens": jnp.min(per_expert),
-    })
+    if "moe_counters" in outputs:
+      counters = outputs["moe_counters"]
+      per_expert = counters["expert_tokens"]          # (layers, held)
+      metrics.update({
+          "moe/expert_tokens": per_expert,
+          "moe/held_assignments": jnp.sum(counters["held_assignments"]),
+          "moe/total_assignments": jnp.sum(counters["total_assignments"]),
+          "moe/max_expert_tokens": jnp.max(per_expert),
+          "moe/min_expert_tokens": jnp.min(per_expert),
+      })
     # (linear layers,): the mean of exp(g) and of β over tokens and heads;
     # a decay stuck at 0 or 1 is a layer that forgets everything or
     # nothing.
@@ -247,4 +278,10 @@ class SequenceMoEModel(AbstractT2RModel):
     # is a plain residual, 1/n full mixing), the mean H_pre and H_post,
     # and the largest |row sum − 1| Sinkhorn's last iteration left.
     metrics.update(outputs.get("mhc_counters", {}))
+    # (sparse layers,): the share of the selection scores on the selected
+    # blocks (mean over tokens), and the (token, block) pairs the
+    # attention kernels visited and the tokens selected.
+    metrics.update(outputs.get("sala_counters", {}))
+    # (Lightning layers,): the mean of the decays each applied.
+    metrics.update(outputs.get("lightning_counters", {}))
     return loss, metrics

@@ -273,16 +273,15 @@ def test_hold_split_reader_over_a_ring_of_flushes(monkeypatch, metric,
     "transfer_hidden_share.serve"])
 def test_hold_split_reader_is_declared_and_refuses_a_wrapped_ring(
     monkeypatch, metric):
-  """Declared on the serving cell at the end of `per_layer`; over a ring
-  that has dropped spans it reads only if the oldest span still held
-  ended before the window began, else nothing of the window is known to
-  be whole."""
+  """Declared on the serving cell; over a ring that has dropped spans it
+  reads only if the oldest span still held ended before the window
+  began, else nothing of the window is known to be whole."""
   from benchmark import harness
   from benchmark.tests import hold_rings
   from tensor2robot_tpu.obs import trace as trace_lib
   layer, better, _ = hold_rings.CASES[metric]
   per_layer = harness.load_cell("qtopt_serve_closed64").spec["per_layer"]
-  assert metric in [m["name"] for m in per_layer[-3:]]
+  assert metric in [m["name"] for m in per_layer]
   (declared,) = [m for m in per_layer if m["name"] == metric]
   assert declared == {
       "name": metric, "unit": "%", "better": better, "layer": layer,
@@ -310,14 +309,18 @@ def test_hold_split_reader_is_declared_and_refuses_a_wrapped_ring(
 
 
 @pytest.mark.parametrize("kernel", ["flash_attention", "gated_delta_rule",
-                                    "hyper_connection"])
+                                    "hyper_connection", "sparse_attention",
+                                    "lightning_attention"])
 def test_kernel_names_are_the_pallas_calls_names(kernel):
   """`mla_attention_roofline.train` finds the flash kernel's device events
   by `ops/flash_attention.KERNEL_NAMES`, `gated_delta_rule_roofline.train`
   and `linear_attention_time_share.train` the delta rule's by
   `ops/gated_delta_rule.KERNEL_NAMES`, `hyper_connection_roofline.train`
-  the stream passes' by `ops/hyper_connection.KERNEL_NAMES`; a program
-  named otherwise, or one more the tuple lacks, makes them read None."""
+  the stream passes' by `ops/hyper_connection.KERNEL_NAMES`,
+  `sparse_attention_roofline.train` and `lightning_attention_roofline.train`
+  theirs by `ops/sparse_attention.KERNEL_NAMES` and
+  `ops/lightning_attention.KERNEL_NAMES`; a program named otherwise, or
+  one more the tuple lacks, makes them read None."""
   # `ops/__init__.py` re-exports the function under the module's name.
   module = importlib.import_module(f"tensor2robot_tpu.ops.{kernel}")
   tree = _parse(os.path.join(PACKAGE, "ops", f"{kernel}.py"))
@@ -341,10 +344,13 @@ def test_the_drivers_read_the_kernels_by_the_program_s_names():
   """The drivers' trace readers import the tuples, they do not spell the
   names: `train_resident_tokens` the flash kernel's,
   `train_resident_hybrid` the delta rule's, `train_resident_mhc` the
-  hyper-connections'."""
+  hyper-connections', `train_resident_sala` the block-sparse kernel's and
+  Lightning's."""
   for driver, module in (("train_resident_tokens", "flash_attention"),
                          ("train_resident_hybrid", "gated_delta_rule"),
-                         ("train_resident_mhc", "hyper_connection")):
+                         ("train_resident_mhc", "hyper_connection"),
+                         ("train_resident_sala", "sparse_attention"),
+                         ("train_resident_sala", "lightning_attention")):
     imports = _program_imports(
         os.path.join(BENCHMARK, "drivers", f"{driver}.py"))
     assert (f"tensor2robot_tpu.ops.{module}", "KERNEL_NAMES") in imports

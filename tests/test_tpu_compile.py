@@ -172,6 +172,57 @@ def test_hyper_connection_compiles_at_4k_forward_and_backward(one_chip,
   assert text.count("tpu_custom_call") >= 4
 
 
+def test_block_sparse_attention_compiles_at_32k_forward_and_backward(
+    one_chip, as_on_tpu):
+  """The sparse layer's share: 16 query heads on one key/value head of
+  128, T = 32,768 in blocks of 64, bf16, the selection made in XLA beside
+  it: the key/value head's whole K and V rows (8 MiB each) single-buffered
+  in VMEM, dK and dV's float32 rows (16 MiB each) beside them through
+  the backward's sequential grid, the visit plan's rows in scalar
+  memory."""
+  from tensor2robot_tpu.ops import sparse_attention as sparse
+  shape = lambda heads: jax.ShapeDtypeStruct((1, 32768, heads, 128),
+                                             jnp.bfloat16, sharding=one_chip)
+
+  def loss(q, k, v):
+    selected, mass = sparse.select_blocks(
+        q, k, block=64, kernel=32, stride=16, top_k=64, init_blocks=1,
+        window=2048, dense_len=8192)
+    out, visits = sparse.block_sparse_attention(
+        q, k, v, selected, block=64, scale=128 ** -0.5,
+        implementation="pallas")
+    return jnp.sum(out.astype(jnp.float32)) + mass + visits["visited"]
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+      shape(16), shape(1), shape(1)).compile()
+  text = compiled.as_text()
+  for name in sparse.KERNEL_NAMES:
+    assert re.search(rf"{name}\.\d+ = ", text), name
+  assert text.count("tpu_custom_call") >= 2
+
+
+def test_lightning_attention_compiles_at_32k_forward_and_backward(
+    one_chip, as_on_tpu):
+  """A Lightning layer's share: 16 heads of 128, T = 32,768 in chunks of
+  64, bf16: the delta rule's walk without the w operand."""
+  from tensor2robot_tpu.ops import lightning_attention as lightning
+  shape = jax.ShapeDtypeStruct((1, 32768, 16, 128), jnp.bfloat16,
+                               sharding=one_chip)
+
+  def loss(q, k, v):
+    out = lightning.lightning_attention(
+        q, k, v, lightning.log_decays(32, 0, 16, 1, 32), "pallas")
+    return jnp.sum(out.astype(jnp.float32))
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+      shape, shape, shape).compile()
+  text = compiled.as_text()
+  for name in lightning.KERNEL_NAMES:
+    assert re.search(rf"{name}\.\d+ = ", text), name
+  # The delta rule's own programs are not among them.
+  assert not re.search(r"gated_delta_rule_\w*\.\d+ = ", text)
+
+
 def _written_outside_fusions(text, dims):
   """Instructions whose result is an array of `dims` in a computation
   that is no fusion's body (the entry, a loop's body): buffers the
