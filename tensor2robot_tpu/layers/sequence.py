@@ -437,9 +437,10 @@ class SparseAttention(nn.Module):
   alone (`block_sparse_attention`, under `sala/attend`), scale
   1/sqrt(head_dim); the output times sigmoid(x · W_g) where
   `attn_use_output_gate`. Returns (y, {"sala/selected_mass",
-  "sala/visited_blocks", "sala/selected_blocks"}: the share of the
-  selection scores on the selected blocks, and the (token, block) pairs
-  the kernels visited and the tokens selected)."""
+  "sala/visited_blocks", "sala/selected_blocks", "sala/visit_slots"}:
+  the share of the selection scores on the selected blocks, the (token,
+  block) pairs the kernels visited and the tokens selected, and the
+  slots the kernels' loops stepped through, padding included)."""
   config: SequenceConfig
   dtype: Any = jnp.bfloat16
 
@@ -478,7 +479,8 @@ class SparseAttention(nn.Module):
           out.reshape(b, t, heads * hd))
     return y, {"sala/selected_mass": mass,
                "sala/visited_blocks": visits["visited"],
-               "sala/selected_blocks": visits["selected"]}
+               "sala/selected_blocks": visits["selected"],
+               "sala/visit_slots": visits["slots"]}
 
 
 class LightningAttention(nn.Module):

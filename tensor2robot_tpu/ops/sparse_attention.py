@@ -23,14 +23,20 @@ the tile's tokens' blocks, whose indices come in scalar memory, in
 ascending order; inside, a bit per token says which of them selected
 the block, and the causal mask cuts the diagonal. The key/value head's
 whole K and V rows sit in VMEM (single-buffered: their block never
-moves), so a visit is a slice, not a copy. The backward is one program:
-per tile it makes p again from the forward's logsumexp, writes dq, and
-adds the tile's dK and dV into the head's whole float32 rows, which stay
-in VMEM across the (sequential) grid.
+moves), so a visit is a slice, not a copy. A loop iteration takes
+`VISIT_KEYS` keys: that many / `block` of the union's blocks, their rows
+stacked into one operand, so that the score tile fills its lanes and the
+chain of products, max, exponentials and sums is paid once for all of
+them; the last iteration's slots past the union are blocks no token
+selected, masked. The backward is one program: per tile it makes p
+again from the forward's logsumexp, writes dq, and adds the tile's dK
+and dV into the head's whole float32 rows, which stay in VMEM across
+the (sequential) grid.
 
 The union is the cost of the tile: a block one token of the tile chose
-is computed for all of them (masked). `plan` counts both: the (token,
-block) pairs the tokens selected and those the kernels visit.
+is computed for all of them (masked). `plan` counts the (token, block)
+pairs the tokens selected, those of the unions, and the slots the loops
+step through (the unions rounded up to whole iterations).
 
 `implementation="xla"` (and "auto" off a TPU or under
 `dispatch.xla_only()`) computes the same attention as a dense masked
@@ -61,6 +67,14 @@ KERNEL_NAMES = ("sparse_attention_fwd", "sparse_attention_bwd")
 # The bit that says which of them selected a block is one bit of an
 # int32, so at most 31.
 TILE_TOKENS = 8
+# Keys a loop iteration of the kernels takes: max(1, VISIT_KEYS // block)
+# of a tile's blocks at once (8 at blocks of 64), at most all of them.
+# At T = 32,768, blocks of 64, 16 heads of 128 on one key/value head, on
+# one v5e, ms a forward / backward call by keys an iteration: 64: 318 /
+# 371, 128: 172 / 235, 256: 93 / 153, 512: 49 / 90; the padding of the
+# last iteration rounds 217 blocks a tile up by 1.6% at 512, and twice
+# that a width wider.
+VISIT_KEYS = 512
 # Query tokens a step of the selection takes: their (group, tokens,
 # compressed keys) float32 scores are its largest temporary.
 _SELECT_TOKENS = 1024
@@ -174,14 +188,23 @@ def select_blocks(q, k, *, block: int, kernel: int, stride: int, top_k: int,
 # --- the visit plan ---------------------------------------------------------------
 
 
-def plan(selected, tokens: int = TILE_TOKENS):
-  """What the kernels visit, from `selected` (G, T, NB) bool.
+def visit_blocks(block: int, nb: int) -> int:
+  """Blocks a loop iteration takes, of `nb` blocks of `block` keys."""
+  return max(1, min(VISIT_KEYS // block, nb))
 
-  Returns (blocks (G·tiles, 1, NB) int32: each tile's union of its tokens'
-  blocks in ascending order, then the rest; bits (G·tiles, 1, NB) int32:
-  bit i set where the tile's token i selected that block; counts
-  (G·tiles,) int32: the union's size; the (token, block) pairs visited
-  and selected, float32)."""
+
+def plan(selected, tokens: int = TILE_TOKENS, per_visit: int = 1):
+  """What the kernels visit, from `selected` (G, T, NB) bool, `per_visit`
+  blocks an iteration.
+
+  Returns (blocks (G·tiles, 1, NB') int32: each tile's union of its
+  tokens' blocks in ascending order, then the rest, then block 0 up to
+  NB', the next multiple of `per_visit`; bits (G·tiles, 1, NB') int32:
+  bit i set where the tile's token i selected that block, 0 past the
+  union; counts (G·tiles,) int32: the union's size; float32 counts of
+  (token, block) pairs: those visited (the unions'), those selected, and
+  the slots the loops step through (each union rounded up to a multiple
+  of `per_visit`))."""
   g, t, nb = selected.shape
   tiles = t // tokens
   per_token = selected.reshape(g * tiles, tokens, nb)
@@ -189,14 +212,17 @@ def plan(selected, tokens: int = TILE_TOKENS):
   bits = jnp.sum(per_token.astype(jnp.int32)
                  << jnp.arange(tokens, dtype=jnp.int32)[:, None], axis=1)
   order = jnp.argsort(jnp.where(union, 0, 1), axis=-1, stable=True)
+  bits = jnp.take_along_axis(bits, order, -1)
+  pad = [(0, 0), (0, -nb % per_visit)]
   counts = jnp.sum(union, axis=-1, dtype=jnp.int32)
   visited = jnp.sum(counts, dtype=jnp.float32) * tokens
   chosen = jnp.sum(selected, dtype=jnp.float32)
-  # (G·tiles, 1, NB): a block of one row in scalar memory is its whole
+  steps = (counts + per_visit - 1) // per_visit
+  slots = jnp.sum(steps, dtype=jnp.float32) * per_visit * tokens
+  # (G·tiles, 1, NB'): a block of one row in scalar memory is its whole
   # last two dimensions.
-  return (order.astype(jnp.int32)[:, None],
-          jnp.take_along_axis(bits, order, -1)[:, None], counts, visited,
-          chosen)
+  return (jnp.pad(order.astype(jnp.int32), pad)[:, None],
+          jnp.pad(bits, pad)[:, None], counts, visited, chosen, slots)
 
 
 # --- layouts -------------------------------------------------------------------
@@ -250,29 +276,48 @@ def _eye(rows: int):
           == jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1))
 
 
-def _visible(blocks_ref, bits_ref, j, bit, position, block: int):
-  """(the visited block's first key, (rows, block) bool: which of its
-  keys each row's token selected and sees)."""
-  start = pl.multiple_of(blocks_ref[0, 0, j] * block, block)
-  key = start + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-  return start, ((bits_ref[0, 0, j] & bit) != 0) & (key <= position)
+def _visible(blocks_ref, bits_ref, i, bit, position, block: int, n: int):
+  """(the first key of each of iteration i's n blocks, (rows, n·block)
+  bool: which of their keys each row's token selected and sees)."""
+  col = jax.lax.broadcasted_iota(jnp.int32, (1, n * block), 1)
+  starts, key, bits = [], col, jnp.zeros_like(col)
+  for u in range(n):
+    start = pl.multiple_of(blocks_ref[0, 0, i * n + u] * block, block)
+    mine = (col >= u * block) & (col < (u + 1) * block)
+    key = jnp.where(mine, col + (start - u * block), key)
+    bits = jnp.where(mine, bits_ref[0, 0, i * n + u], bits)
+    starts.append(start)
+  return starts, ((bits & bit) != 0) & (key <= position)
+
+
+def _stacked(ref, starts, block: int):
+  """The blocks' rows of a head's whole (1, T, D) rows, one under the
+  next: (n·block, D), each piece at a sublane-aligned offset."""
+  return jnp.concatenate([ref[0, pl.ds(start, block), :] for start in starts],
+                         axis=0)
+
+
+def _steps(counts_ref, index, n: int):
+  """Iterations of n blocks that cover a tile's union."""
+  return (counts_ref[index] + n - 1) // n
 
 
 def _fwd_kernel(counts_ref, blocks_ref, bits_ref, q_ref, k_ref, v_ref,
                 o_ref, lse_ref, *, scale: float, block: int, tokens: int,
-                group: int, tiles: int):
-  """One tile of queries over its union of blocks, online softmax."""
+                group: int, tiles: int, per_visit: int):
+  """One tile of queries over its union of blocks, online softmax,
+  `per_visit` blocks an update."""
   g, tile = pl.program_id(0), pl.program_id(1)
   q = q_ref[0, 0]                                          # (rows, D)
   rows = q.shape[0]
   bit, place = _row_tokens(rows, tokens, group)
   position = tile * tokens + place
 
-  def body(j, carry):
+  def body(i, carry):
     m, l, acc = carry
-    start, keep = _visible(blocks_ref, bits_ref, j, bit, position, block)
-    k = k_ref[0, pl.ds(start, block), :]
-    v = v_ref[0, pl.ds(start, block), :]
+    starts, keep = _visible(blocks_ref, bits_ref, i, bit, position, block,
+                            per_visit)
+    k, v = _stacked(k_ref, starts, block), _stacked(v_ref, starts, block)
     s = jnp.where(keep, _dot(q, k, _NT) * scale, _MASKED)
     m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
     p = jnp.where(keep, jnp.exp(s - m_new), 0.0)
@@ -281,7 +326,7 @@ def _fwd_kernel(counts_ref, blocks_ref, bits_ref, q_ref, k_ref, v_ref,
             alpha * acc + _dot(p.astype(v.dtype), v))
 
   m, l, acc = jax.lax.fori_loop(
-      0, counts_ref[g * tiles + tile], body,
+      0, _steps(counts_ref, g * tiles + tile, per_visit), body,
       (jnp.full((rows, 1), _MASKED, jnp.float32),
        jnp.zeros((rows, 1), jnp.float32),
        jnp.zeros((rows, v_ref.shape[-1]), jnp.float32)))
@@ -293,11 +338,13 @@ def _fwd_kernel(counts_ref, blocks_ref, bits_ref, q_ref, k_ref, v_ref,
 def _bwd_kernel(counts_ref, blocks_ref, bits_ref, q_ref, k_ref, v_ref,
                 o_ref, do_ref, lse_ref, dq_ref, dk_ref, dv_ref, *,
                 scale: float, block: int, tokens: int, group: int,
-                tiles: int):
+                tiles: int, per_visit: int):
   """One tile's dq, and its part of the head's dK and dV, which the
   program keeps in VMEM across the tiles:
     p = exp(s - lse);  dV += p^T dO;  dP = dO V^T;  dS = p (dP - delta)
-    dq = scale dS K;   dK += scale dS^T q;  delta = rowsum(dO . O)"""
+    dq = scale dS K;   dK += scale dS^T q;  delta = rowsum(dO . O)
+  `per_visit` blocks an iteration: dV's and dK's parts are made for all
+  of them at once and added block by block."""
   g, tile = pl.program_id(0), pl.program_id(1)
 
   @pl.when(tile == 0)
@@ -313,19 +360,23 @@ def _bwd_kernel(counts_ref, blocks_ref, bits_ref, q_ref, k_ref, v_ref,
   delta = jnp.sum(do.astype(jnp.float32) * o_ref[0, 0].astype(jnp.float32),
                   axis=1, keepdims=True)
 
-  def body(j, dq):
-    start, keep = _visible(blocks_ref, bits_ref, j, bit, position, block)
-    k = k_ref[0, pl.ds(start, block), :]
-    v = v_ref[0, pl.ds(start, block), :]
+  def body(i, dq):
+    starts, keep = _visible(blocks_ref, bits_ref, i, bit, position, block,
+                            per_visit)
+    k, v = _stacked(k_ref, starts, block), _stacked(v_ref, starts, block)
     s = _dot(q, k, _NT) * scale
     p = jnp.where(keep, jnp.exp(s - lse), 0.0)
-    dv_ref[0, pl.ds(start, block), :] += _dot(p.astype(dtype), do, _TN)
+    dv = _dot(p.astype(dtype), do, _TN)                      # (n·block, D)
     ds = (p * (_dot(do, v, _NT) - delta)).astype(dtype)
-    dk_ref[0, pl.ds(start, block), :] += _dot(ds, q, _TN) * scale
+    dk = _dot(ds, q, _TN) * scale
+    for u, start in enumerate(starts):
+      rows_u = slice(u * block, (u + 1) * block)
+      dv_ref[0, pl.ds(start, block), :] += dv[rows_u]
+      dk_ref[0, pl.ds(start, block), :] += dk[rows_u]
     return dq + _dot(ds, k)
 
-  dq = jax.lax.fori_loop(0, counts_ref[g * tiles + tile], body,
-                         jnp.zeros(q.shape, jnp.float32))
+  dq = jax.lax.fori_loop(0, _steps(counts_ref, g * tiles + tile, per_visit),
+                         body, jnp.zeros(q.shape, jnp.float32))
   dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
 
 
@@ -355,15 +406,17 @@ def _params(whole_bytes: int, semantics):
       vmem_limit_bytes=int(whole_bytes + 16 * 1024 * 1024))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block", "tokens"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "block", "tokens", "per_visit"))
 def _pallas_forward(q_tiles, keys, values, blocks, bits, counts, *,
-                    scale: float, block: int, tokens: int):
+                    scale: float, block: int, tokens: int, per_visit: int):
   heads, tiles, rows, d = q_tiles.shape
   grid = _grid(q_tiles, keys, (blocks,))
   whole_bytes = 2 * keys.size // heads * keys.dtype.itemsize
   o, lse = pl.pallas_call(
       functools.partial(_fwd_kernel, scale=scale, block=block, tokens=tokens,
-                        group=rows // tokens, tiles=tiles),
+                        group=rows // tokens, tiles=tiles,
+                        per_visit=per_visit),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=1, grid=grid["grid"],
           in_specs=[grid["smem"](), grid["smem"](), grid["tile"],
@@ -378,9 +431,11 @@ def _pallas_forward(q_tiles, keys, values, blocks, bits, counts, *,
   return o, lse
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block", "tokens"))
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "block", "tokens", "per_visit"))
 def _pallas_backward(q_tiles, keys, values, o, lse, do, blocks, bits, counts,
-                     *, scale: float, block: int, tokens: int):
+                     *, scale: float, block: int, tokens: int,
+                     per_visit: int):
   heads, tiles, rows, d = q_tiles.shape
   grid = _grid(q_tiles, keys, (blocks,))
   row_bytes = keys.size // heads
@@ -389,7 +444,8 @@ def _pallas_backward(q_tiles, keys, values, o, lse, do, blocks, bits, counts,
   f32_rows = jax.ShapeDtypeStruct(keys.shape, jnp.float32)
   dq, dk, dv = pl.pallas_call(
       functools.partial(_bwd_kernel, scale=scale, block=block, tokens=tokens,
-                        group=rows // tokens, tiles=tiles),
+                        group=rows // tokens, tiles=tiles,
+                        per_visit=per_visit),
       grid_spec=pltpu.PrefetchScalarGridSpec(
           num_scalar_prefetch=1, grid=grid["grid"],
           in_specs=[grid["smem"](), grid["smem"](), grid["tile"],
@@ -406,26 +462,28 @@ def _pallas_backward(q_tiles, keys, values, o, lse, do, blocks, bits, counts,
   return dq, dk.astype(keys.dtype), dv.astype(values.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8, 9))
 def _attend_pallas(q_tiles, keys, values, blocks, bits, counts, scale, block,
-                   tokens):
+                   tokens, per_visit):
   o, _ = _pallas_forward(q_tiles, keys, values, blocks, bits, counts,
-                         scale=scale, block=block, tokens=tokens)
+                         scale=scale, block=block, tokens=tokens,
+                         per_visit=per_visit)
   return o
 
 
 def _attend_fwd(q_tiles, keys, values, blocks, bits, counts, scale, block,
-                tokens):
+                tokens, per_visit):
   o, lse = _pallas_forward(q_tiles, keys, values, blocks, bits, counts,
-                           scale=scale, block=block, tokens=tokens)
+                           scale=scale, block=block, tokens=tokens,
+                           per_visit=per_visit)
   return o, (q_tiles, keys, values, o, lse, blocks, bits, counts)
 
 
-def _attend_bwd(scale, block, tokens, residuals, do):
+def _attend_bwd(scale, block, tokens, per_visit, residuals, do):
   q_tiles, keys, values, o, lse, blocks, bits, counts = residuals
   dq, dk, dv = _pallas_backward(q_tiles, keys, values, o, lse, do, blocks,
                                 bits, counts, scale=scale, block=block,
-                                tokens=tokens)
+                                tokens=tokens, per_visit=per_visit)
   return dq, dk, dv, None, None, None
 
 
@@ -451,7 +509,8 @@ def masked_attention(q, k, v, selected, *, block: int, scale: float):
 
 
 def block_sparse_attention(q, k, v, selected, *, block: int, scale: float,
-                           implementation: str = "auto"):
+                           implementation: str = "auto",
+                           _per_visit: int | None = None):
   """Attention over the selected key blocks.
 
   Args:
@@ -461,11 +520,14 @@ def block_sparse_attention(q, k, v, selected, *, block: int, scale: float,
       query selects the block of the sequence's first key.
     block: keys a block; scale: of the scores.
     implementation: "pallas", "xla", or "auto" (pallas on a TPU).
+    _per_visit: blocks a loop iteration of the kernels takes, where a
+      test sets it; else `visit_blocks(block, NB)`.
 
   Returns:
     (o (B, T, Hq, D) in v's dtype, {"visited": the (token, block) pairs
-    the kernels visit, "selected": those the tokens selected}, float32
-    sums over the batch and groups).
+    of the tiles' unions, "selected": those the tokens selected, "slots":
+    those the kernels' loops step through, the last iteration's padding
+    included}, float32 sums over the batch and groups).
   """
   if implementation not in ("auto", "pallas", "xla"):
     raise ValueError(
@@ -478,14 +540,15 @@ def block_sparse_attention(q, k, v, selected, *, block: int, scale: float,
     raise ValueError(f"T must be a multiple of the block ({block}) and of "
                      f"{tokens}, the heads of the key/value heads; got T={t}, "
                      f"heads {hq}/{hkv}")
-  blocks, bits, counts, visited, chosen = plan(
-      selected.reshape(b * hkv, t, t // block), tokens)
+  per_visit = _per_visit or visit_blocks(block, t // block)
+  blocks, bits, counts, visited, chosen, slots = plan(
+      selected.reshape(b * hkv, t, t // block), tokens, per_visit)
   use_xla = implementation == "xla" or (implementation == "auto" and (
       dispatch.use_xla_only() or jax.default_backend() != "tpu"))
-  visits = {"visited": visited, "selected": chosen}
+  visits = {"visited": visited, "selected": chosen, "slots": slots}
   if use_xla:
     return masked_attention(q, k, v, selected, block=block,
                             scale=scale), visits
   o = _attend_pallas(_tiles(q, hkv, tokens), _rows(k), _rows(v), blocks,
-                     bits, counts, scale, block, tokens)
+                     bits, counts, scale, block, tokens, per_visit)
   return _untiles(o, b, t, hkv, hq // hkv), visits

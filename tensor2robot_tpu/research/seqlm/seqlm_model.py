@@ -279,8 +279,9 @@ class SequenceMoEModel(AbstractT2RModel):
     # and the largest |row sum − 1| Sinkhorn's last iteration left.
     metrics.update(outputs.get("mhc_counters", {}))
     # (sparse layers,): the share of the selection scores on the selected
-    # blocks (mean over tokens), and the (token, block) pairs the
-    # attention kernels visited and the tokens selected.
+    # blocks (mean over tokens), the (token, block) pairs the attention
+    # kernels visited and the tokens selected, and the slots the kernels'
+    # loops stepped through.
     metrics.update(outputs.get("sala_counters", {}))
     # (Lightning layers,): the mean of the decays each applied.
     metrics.update(outputs.get("lightning_counters", {}))

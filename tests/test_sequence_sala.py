@@ -135,16 +135,29 @@ class TestSelection:
 
 class TestBlockSparseKernel:
 
+  @pytest.mark.parametrize("per_visit, paired", [
+      (1, False),   # a block an iteration
+      (2, True),    # blocks selected in pairs: every union a multiple of 2
+      (3, False),   # a padded last iteration; rows of 32 padded to 33
+  ])
   @pytest.mark.parametrize("heads, kv, batch", [(4, 2, 1), (8, 1, 2)])
-  def test_kernel_matches_the_masked_softmax(self, heads, kv, batch):
+  def test_kernel_matches_the_masked_softmax(self, heads, kv, batch,
+                                             per_visit, paired):
     """Forward and every gradient; tiles of 8 tokens whose unions the
-    bits cut back to each token's own blocks."""
+    bits cut back to each token's own blocks, `per_visit` blocks a loop
+    iteration."""
     q, k = _qk(heads=heads, kv=kv, batch=batch, width=128, seed=1)
     v = jnp.asarray(np.random.default_rng(2).standard_normal(k.shape),
                     jnp.float32)
     selected, _ = sparse_lib.select_blocks(q, k, **_SELECT)
+    if paired:   # each block with its pair (a later block's keys are masked)
+      pairs = jnp.any(selected.reshape(selected.shape[:-1] + (16, 2)), -1)
+      selected = jnp.repeat(pairs, 2, axis=-1)
+    counts = sparse_lib.plan(selected.reshape(-1, 256, 32))[2]
+    assert bool(jnp.any(counts % per_visit)) == (per_visit == 3)
     run = lambda impl: (lambda q, k, v: sparse_lib.block_sparse_attention(
-        q, k, v, selected, block=8, scale=0.1, implementation=impl)[0])
+        q, k, v, selected, block=8, scale=0.1, implementation=impl,
+        _per_visit=per_visit)[0])
     want = run("xla")(q, k, v)
     np.testing.assert_allclose(np.asarray(run("pallas")(q, k, v)),
                                np.asarray(want), atol=1e-5)
@@ -154,27 +167,33 @@ class TestBlockSparseKernel:
       np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                  atol=1e-5 * float(jnp.max(jnp.abs(w))))
 
-  def test_a_token_sees_only_its_own_blocks(self):
+  @pytest.mark.parametrize("per_visit", [1, 3])
+  def test_a_token_sees_only_its_own_blocks(self, per_visit):
     """A key outside every block the token selected moves nothing of its
-    output, though its tile visits that block for another token."""
+    output, though its tile visits that block for another token; nor do
+    the slots that pad a tile's union to whole iterations."""
     q, k = _qk(heads=2, kv=1, width=128)
     v = jnp.zeros(k.shape, jnp.float32)
     selected = jnp.zeros((1, 1, 256, 32), bool).at[..., 0].set(True)
     selected = selected.at[0, 0, 100, 5].set(True)   # token 100 alone
     v = v.at[0, 45, 0].set(1.0)                        # a key in block 5
     out, visits = sparse_lib.block_sparse_attention(
-        q, k, v, selected, block=8, scale=0.1, implementation="pallas")
+        q, k, v, selected, block=8, scale=0.1, implementation="pallas",
+        _per_visit=per_visit)
     moved = np.flatnonzero(np.abs(np.asarray(out[0, :, :, :])).sum((1, 2)))
     np.testing.assert_array_equal(moved, [100])
     # 32 tiles visit block 0; the tile of token 100 visits block 5 too.
     assert float(visits["visited"]) == (32 + 1) * 8
     assert float(visits["selected"]) == 256 + 1
+    # Unions of 1 and 2 blocks, each a whole iteration at 3.
+    slots = {1: 32 + 1, 3: 32 * 3}[per_visit] * 8
+    assert float(visits["slots"]) == slots
 
   def test_the_plan_lists_each_tile_s_union_in_order(self):
     selected = np.zeros((1, 16, 6), bool)
     selected[0, 0, [0, 3]] = selected[0, 5, [0, 1]] = True
     selected[0, 8:, 0] = selected[0, 9, 4] = True
-    blocks, bits, counts, visited, chosen = sparse_lib.plan(
+    blocks, bits, counts, visited, chosen, slots = sparse_lib.plan(
         jnp.asarray(selected), tokens=8)
     assert list(np.asarray(counts)) == [3, 2]
     assert list(np.asarray(blocks)[0, 0, :3]) == [0, 1, 3]
@@ -182,6 +201,26 @@ class TestBlockSparseKernel:
     assert list(np.asarray(blocks)[1, 0, :2]) == [0, 4]
     assert list(np.asarray(bits)[1, 0, :2]) == [255, 1 << 1]
     assert float(visited) == 5 * 8 and float(chosen) == selected.sum()
+    assert float(slots) == float(visited)
+
+  def test_the_plan_pads_its_rows_to_whole_iterations(self):
+    """6 blocks at 4 an iteration: rows of 8, the last two block 0 with
+    no bit; unions of 3 and 2 blocks step through 4 slots each."""
+    selected = np.zeros((1, 16, 6), bool)
+    selected[0, 0, [0, 3]] = selected[0, 5, [0, 1]] = True
+    selected[0, 8:, 0] = selected[0, 9, 4] = True
+    one = sparse_lib.plan(jnp.asarray(selected), tokens=8)
+    four = sparse_lib.plan(jnp.asarray(selected), tokens=8, per_visit=4)
+    for padded, unpadded in zip(four[:2], one[:2]):
+      assert padded.shape == (2, 1, 8)
+      np.testing.assert_array_equal(np.asarray(padded)[..., :6],
+                                    np.asarray(unpadded))
+      assert not np.any(np.asarray(padded)[..., 6:])
+    np.testing.assert_array_equal(np.asarray(four[2]), np.asarray(one[2]))
+    assert float(four[3]) == 5 * 8 and float(four[5]) == 2 * 4 * 8
+    assert sparse_lib.visit_blocks(64, 512) == 8
+    assert sparse_lib.visit_blocks(8, 6) == 6
+    assert sparse_lib.visit_blocks(512, 64) == 1
 
 
 # --- Lightning attention -------------------------------------------------------
@@ -433,7 +472,8 @@ class TestModel:
       assert numbers[name] < 1e-3, (name, numbers)
     assert np.asarray(metrics["sala/visited_blocks"]).shape == (1,)
     assert (float(metrics["sala/selected_blocks"][0])
-            < float(metrics["sala/visited_blocks"][0]))
+            < float(metrics["sala/visited_blocks"][0])
+            <= float(metrics["sala/visit_slots"][0]))
 
 
 def test_the_configuration_builds_through_the_trainer_s_cfg():

@@ -179,7 +179,11 @@ def test_block_sparse_attention_compiles_at_32k_forward_and_backward(
   it: the key/value head's whole K and V rows (8 MiB each) single-buffered
   in VMEM, dK and dV's float32 rows (16 MiB each) beside them through
   the backward's sequential grid, the visit plan's rows in scalar
-  memory."""
+  memory. A loop iteration takes `visit_blocks(64, 512)` = 8 blocks: K
+  and V's 512 rows stacked from eight sublane-aligned slices, a (128,
+  512) float32 score tile, and in the backward (512, 128) float32 parts
+  of dK and dV added into their eight blocks' rows, all inside the same
+  16 MiB beside the whole rows."""
   from tensor2robot_tpu.ops import sparse_attention as sparse
   shape = lambda heads: jax.ShapeDtypeStruct((1, 32768, heads, 128),
                                              jnp.bfloat16, sharding=one_chip)
