@@ -1,18 +1,19 @@
 """Decoder blocks of a sequence model: RMSNorm (plain or zero-centred),
 rotary positions (interleaved over the whole width, plain or under
-YaRN, or half-split over the head's first part), five token mixers
-(multi-head latent attention, gated grouped-query attention, the gated
-delta net, block-sparse attention, Lightning attention; training
-forms), gated MLP, the expert layer (routing and grouping in
-`parallel/expert_parallel`), the residual rule (plain, scaled, or
-manifold-constrained hyper-connections over `hc_mult` streams) and a
-chunked next-token loss.
+YaRN, or half-split over the head's first part), six token mixers
+(multi-head latent attention, grouped-query attention with or without
+its output gate, the gated delta net, block-sparse attention, Lightning
+attention, the gated short convolution; training forms), gated MLP, the
+expert layer (routing and grouping in `parallel/expert_parallel`), the
+residual rule (plain, scaled, or manifold-constrained hyper-connections
+over `hc_mult` streams) and a chunked next-token loss.
 
 Layer equations: DeepSeek-V2/V3's, which the JoyAI-LLM-Flash and
 Xing4.0 configs follow, Qwen3-Next's (gated attention and Gated Delta
 Networks, arXiv:2412.06464), mHC's (arXiv:2512.24880) and
 MiniCPM-SALA's (InfLLM-v2's sparse attention, arXiv:2506.07900, beside
-Lightning attention, arXiv:2401.04658, under muP scalings); see
+Lightning attention, arXiv:2401.04658, under muP scalings) and LFM2's
+(gated short convolutions beside plain grouped-query attention); see
 `SequenceConfig`'s fields and each module.
 Activations run in `dtype` (bfloat16), parameters are float32; norms,
 rotary angles, the router, the softmax statistics, the delta net's
@@ -38,6 +39,7 @@ from tensor2robot_tpu.ops.hyper_connection import (
     MapConfig, hyper_connection_post, hyper_connection_pre)
 from tensor2robot_tpu.ops.lightning_attention import (
     lightning_attention, log_decays)
+from tensor2robot_tpu.ops.short_conv import causal_conv, gated_short_conv
 from tensor2robot_tpu.ops.sparse_attention import (
     block_sparse_attention, select_blocks)
 from tensor2robot_tpu.parallel import expert_parallel
@@ -138,14 +140,32 @@ class SequenceConfig:
   first_head: int = 0
   published_lightning_nh: int = 0
   published_num_hidden_layers: int = 0
+  # Set: each published layer's mixer by name, "conv" (the gated short
+  # convolution over `conv_L_cache` taps) or "full_attention" (grouped-
+  # query attention without an output gate), the `num_hidden_layers`
+  # from `first_layer` held; a layer before `first_k_dense_replace`
+  # (the published `num_dense_layers`) has the dense gated MLP, the
+  # others the expert layer (LFM2). None: the layouts above.
+  layer_types: Optional[Any] = None
+  first_layer: int = 0
+  conv_L_cache: int = 0
+  conv_bias: bool = False
+  # The head is the embedding's transpose: no `head` parameter.
+  tie_word_embeddings: bool = False
+  # Added to the sum of the chosen sigmoid scores before they divide it.
+  router_norm_eps: float = 1e-20
 
   def __post_init__(self):
     for name in ("rope_scaling", "sparse_config"):
       if isinstance(getattr(self, name), dict):
         object.__setattr__(self, name,
                            tuple(sorted(getattr(self, name).items())))
-    if isinstance(self.mixer_types, list):
-      object.__setattr__(self, "mixer_types", tuple(self.mixer_types))
+    for name in ("mixer_types", "layer_types"):
+      if isinstance(getattr(self, name), list):
+        object.__setattr__(self, name, tuple(getattr(self, name)))
+    if self.conv_bias:
+      raise NotImplementedError("conv_bias: the short convolution and its "
+                                "projections are built without biases")
 
   @property
   def num_expert_layers(self) -> int:
@@ -165,7 +185,11 @@ class SequenceConfig:
 
   def layer_kind(self, layer: int) -> str:
     """"mla", in the hybrid layout "full" | "linear", under `mixer_types`
-    "sparse" | "lightning"."""
+    "sparse" | "lightning", under `layer_types` "conv" | "attention" (of
+    the published layer `layer`)."""
+    if self.layer_types:
+      return {"conv": "conv",
+              "full_attention": "attention"}[self.layer_types[layer]]
     if self.mixer_types:
       return {"minicpm4": "sparse",
               "lightning-attn": "lightning"}[self.mixer_types[layer]]
@@ -311,14 +335,16 @@ class MLAttention(nn.Module):
 
 
 class GatedAttention(nn.Module):
-  """Gated grouped-query attention, training form: `num_attention_heads`
+  """Grouped-query attention, training form: `num_attention_heads`
   query heads on `num_key_value_heads` key/value heads of `head_dim`, q
-  and k normed per head (one weight for all heads), rotary
-  over the head's first `partial_rotary_factor`, causal; each head's
-  output times sigmoid(gate_h), the gate the other half of q's
-  projection."""
+  and k normed per head (one weight for all heads; zero-centred where
+  the config's norms are), rotary over the head's first
+  `partial_rotary_factor`, causal; with `output_gate` each head's output
+  times sigmoid(gate_h), the gate the other half of q's projection
+  (Qwen3-Next), without it q's projection is q alone (LFM2)."""
   config: SequenceConfig
   dtype: Any = jnp.bfloat16
+  output_gate: bool = True
 
   @nn.compact
   def __call__(self, x):
@@ -331,8 +357,9 @@ class GatedAttention(nn.Module):
     turn = lambda a: rotary_half_split(
         a, c.rope_theta, int(c.partial_rotary_factor * hd)).astype(self.dtype)
     with jax.named_scope("gqa"):
-      q_gate = _dense(heads * 2 * hd, self.dtype, "q_proj")(x).reshape(
-          b, t, heads, 2 * hd)
+      width = 2 * hd if self.output_gate else hd
+      q_gate = _dense(heads * width, self.dtype, "q_proj")(x).reshape(
+          b, t, heads, width)
       q, gate = q_gate[..., :hd], q_gate[..., hd:]
       k = _dense(kv * hd, self.dtype, "k_proj")(x).reshape(b, t, kv, hd)
       v = _dense(kv * hd, self.dtype, "v_proj")(x).reshape(b, t, kv, hd)
@@ -343,20 +370,48 @@ class GatedAttention(nn.Module):
           turn(norm("q_norm")(q)), turn(norm("k_norm")(k)), v, causal=True,
           scale=1.0 / math.sqrt(hd),
           implementation="pallas" if on_tpu else "auto")
-      out = (out.astype(jnp.float32)
-             * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype)
+      if self.output_gate:
+        out = (out.astype(jnp.float32)
+               * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(self.dtype)
       return _dense(c.hidden_size, self.dtype, "o_proj")(
           out.reshape(b, t, heads * hd))
 
 
-def causal_conv(x, kernel):
-  """Depthwise convolution over time of (B, T, C) with (taps, C): y_t =
-  Σ_j kernel[j] · x[t − (taps − 1) + j]; nothing ahead of t is read, and
-  before the start there are zeros. Float32 sums."""
-  taps, t = kernel.shape[0], x.shape[1]
-  padded = jnp.pad(x, ((0, 0), (taps - 1, 0), (0, 0)))
-  return sum(kernel[j] * padded[:, j:j + t].astype(jnp.float32)
-             for j in range(taps))
+# The scope the short convolution's work runs under, its parts as
+# `in_proj`, `op` and `out_proj` inside it: the benchmark times it by
+# this name.
+SHORT_CONV_SCOPE = "short_conv"
+
+
+class ShortConv(nn.Module):
+  """LFM2's gated short convolution, training form: [B | C | x~] = x
+  W_in (`in_proj`, 3 · hidden wide), then `ops/short_conv`'s C ⊙ conv(B
+  ⊙ x~) over `conv_L_cache` taps (`conv`, (taps, hidden), depthwise and
+  causal), then W_out (`out_proj`); under the scopes `short_conv/in_proj`,
+  `short_conv/op` and `short_conv/out_proj`. Returns (y, {"conv/u_rms",
+  "conv/y_rms"}: the root mean square of B ⊙ x~ and of C ⊙ z over tokens
+  and channels)."""
+  config: SequenceConfig
+  dtype: Any = jnp.bfloat16
+
+  @nn.compact
+  def __call__(self, x):
+    c = self.config
+    d = c.hidden_size
+    with jax.named_scope(SHORT_CONV_SCOPE):
+      with jax.named_scope("in_proj"):
+        bcx = _dense(3 * d, self.dtype, "in_proj")(x)
+      taps = self.param("conv", nn.initializers.variance_scaling(
+          1.0, "fan_in", "normal", in_axis=0, out_axis=1),
+                        (c.conv_L_cache, d), jnp.float32)
+      with jax.named_scope("op"):
+        y, u_square = gated_short_conv(bcx, taps)
+      counters = jax.lax.stop_gradient({
+          "conv/u_rms": jnp.sqrt(u_square),
+          "conv/y_rms": jnp.sqrt(jnp.mean(jnp.square(
+              y.astype(jnp.float32))))})
+      with jax.named_scope("out_proj"):
+        return _dense(d, self.dtype, "out_proj")(y), counters
 
 
 def _l2_normalized(x, eps: float = 1e-6):
@@ -572,7 +627,8 @@ class ExpertLayer(nn.Module):
     y, counters = expert_parallel.moe_share(
         x.reshape(b * t, d), params, first_expert=c.first_expert,
         top_k=c.num_experts_per_tok, scale=c.routed_scaling_factor,
-        compute_dtype=self.dtype, scoring=c.scoring_func)
+        compute_dtype=self.dtype, scoring=c.scoring_func,
+        norm_eps=c.router_norm_eps)
     y = y.reshape(b, t, d)
     if c.shared_expert_intermediate_size:
       with jax.named_scope("moe/shared"):
@@ -647,17 +703,18 @@ MHC_COUNTERS = ("mhc/res_diag_mean", "mhc/pre_mean", "mhc/post_mean",
 
 class DecoderBlock(nn.Module):
   """h = x + Mixer(norm(x)); y = h + FFN(norm(h)); the mixer by the
-  layer's `kind` ("mla", "full": gated attention, "linear": the gated
-  delta net, "sparse": block-sparse attention, "lightning": Lightning
-  attention at the published `layer`), FFN the dense gated MLP or the
-  expert layer; under `scale_depth` each branch is scaled by the
-  config's `residual_scale`. With `hc_mult` streams x is (B, T, n·D)
-  and each of the two sublayers reads, writes and mixes it through its
-  own `HyperConnection`. Returns (y, the expert layer's counters, with a
-  delta net's `gdn/*`, a sparse layer's `sala/*`, a Lightning layer's
-  `lightning/*` and the
-  hyper-connections' `mhc/*` (each (2,), by sublayer) beside them; None
-  where the block has none)."""
+  layer's `kind` ("mla", "full": gated attention, "attention": grouped-
+  query attention without the gate, "linear": the gated delta net,
+  "sparse": block-sparse attention, "lightning": Lightning attention at
+  the published `layer`, "conv": the gated short convolution), FFN the
+  dense gated MLP or the expert layer; under `scale_depth` each branch
+  is scaled by the config's `residual_scale`. With `hc_mult` streams x
+  is (B, T, n·D) and each of the two sublayers reads, writes and mixes
+  it through its own `HyperConnection`. Returns (y, the expert layer's
+  counters, with a delta net's `gdn/*`, a sparse layer's `sala/*`, a
+  Lightning layer's `lightning/*`, a short convolution's `conv/*` and
+  the hyper-connections' `mhc/*` (each (2,), by sublayer) beside them;
+  None where the block has none)."""
   config: SequenceConfig
   experts: bool
   dtype: Any = jnp.bfloat16
@@ -697,8 +754,13 @@ class DecoderBlock(nn.Module):
         mixed, gates = GatedDeltaNet(c, self.dtype, name="attn")(inner)
         extra.update(gates)
         return mixed
-      if self.kind == "full":
-        return GatedAttention(c, self.dtype, name="attn")(inner)
+      if self.kind == "conv":
+        mixed, rms = ShortConv(c, self.dtype, name="attn")(inner)
+        extra.update(rms)
+        return mixed
+      if self.kind in ("full", "attention"):
+        return GatedAttention(c, self.dtype, self.kind == "full",
+                              name="attn")(inner)
       return MLAttention(c, self.dtype, name="attn")(inner)
 
     def feed_forward(inner):

@@ -74,15 +74,17 @@ SCORINGS = ("sigmoid", "softmax")
 
 def route(tokens: jnp.ndarray, router: jnp.ndarray,
           bias: Optional[jnp.ndarray], top_k: int, scale: float = 1.0,
-          scoring: str = "sigmoid") -> Tuple[jnp.ndarray, jnp.ndarray]:
+          scoring: str = "sigmoid", norm_eps: float = 1e-20
+          ) -> Tuple[jnp.ndarray, jnp.ndarray]:
   """(N, D) tokens → ((N, k) expert ids, (N, k) float32 weights).
 
   `scoring` "sigmoid": each expert's own sigmoid, the choice by score +
   `bias`; "softmax": a softmax over all E experts, the choice by score,
   `bias` not read. Either way the weights are the chosen k scores over
-  their sum, times `scale`. Float32 throughout, the product at full
-  precision: a choice that flipped on rounding would move a token's
-  whole result."""
+  their sum (under sigmoid scoring the sum plus `norm_eps`: LFM2's 1e-6,
+  DeepSeek-V3's 1e-20), times `scale`. Float32 throughout, the product
+  at full precision: a choice that flipped on rounding would move a
+  token's whole result."""
   if scoring not in SCORINGS:
     raise ValueError(f"scoring must be one of {SCORINGS}; got {scoring!r}")
   logits = jnp.dot(
@@ -95,7 +97,7 @@ def route(tokens: jnp.ndarray, router: jnp.ndarray,
   _, index = jax.lax.top_k(
       scores + jax.lax.stop_gradient(bias.astype(jnp.float32)), top_k)
   chosen = jnp.take_along_axis(scores, index, axis=-1)
-  weight = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+  weight = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + norm_eps)
   return index, weight * scale
 
 
@@ -164,15 +166,16 @@ def _counters(sizes, held, top_k: int) -> Dict[str, jnp.ndarray]:
 
 def moe_share(tokens: jnp.ndarray, params: MoEParams, first_expert: int,
               top_k: int, scale: float = 1.0, compute_dtype=None,
-              scoring: str = "sigmoid"):
+              scoring: str = "sigmoid", norm_eps: float = 1e-20):
   """One holder's part of the layer: Σ over top-k ∩ held of w_i E_i(x).
 
   Args:
     tokens: (N, D).
     params: router over all E experts, H held experts' weights; the
       held experts are `first_expert .. first_expert + H - 1`.
-    top_k, scale, scoring: experts per token; factor on the normalized
-      weights; how `route` scores.
+    top_k, scale, scoring, norm_eps: experts per token; factor on the
+      normalized weights; how `route` scores and what it adds to the
+      chosen scores' sum.
     compute_dtype: dtype of the expert products' operands (float32
       accumulation); default the tokens'.
 
@@ -185,7 +188,7 @@ def moe_share(tokens: jnp.ndarray, params: MoEParams, first_expert: int,
   compute_dtype = compute_dtype or tokens.dtype
   with jax.named_scope("moe/route"):
     index, weight = route(tokens, params.router, params.bias, top_k, scale,
-                          scoring)
+                          scoring, norm_eps)
     local = index - first_expert
     held = (local >= 0) & (local < num_held)                   # (N, k)
   with jax.named_scope("moe/dispatch"):

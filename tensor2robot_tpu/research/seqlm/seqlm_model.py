@@ -11,8 +11,13 @@ many expert blocks, gated delta nets and then one gated grouped-query
 attention block; no dense lead), and, where `mixer_types` is set,
 MiniCPM-SALA's (each layer's mixer by name, block-sparse or Lightning
 attention, every FFN the dense gated MLP, no experts; the embedding,
-residual branches and final hidden under muP's scales). Where `hc_mult`
-is set the residual is
+residual branches and final hidden under muP's scales), and, where
+`layer_types` is set, LFM2's (each published layer's mixer by name,
+gated short convolution or plain grouped-query attention, the held
+layers from `first_layer`; those before `first_k_dense_replace` with the
+dense gated MLP, the rest with the expert layer; no shared expert, no
+MTP). Where `tie_word_embeddings` is set the head is the embedding's
+transpose. Where `hc_mult` is set the residual is
 that many streams wide (Xing4.0's manifold-constrained
 hyper-connections): the embedding fans out to the streams, the blocks
 carry them side by side, (B, T, n·D), and the final norm reads their
@@ -27,7 +32,7 @@ Features: `tokens`, int32 (B, T); no labels, no `batch_stats`. The
 module computes in `compute_dtype` with float32 parameters, recomputes
 each block on the backward pass and scans the equal expert blocks (or
 the equal periods), so the program holds one of them; under
-`mixer_types` the blocks differ and are unrolled.
+`mixer_types` and `layer_types` the blocks differ and are unrolled.
 """
 
 from __future__ import annotations
@@ -134,50 +139,65 @@ class _SequenceModule(nn.Module):
     tokens = features["tokens"]
     embed = nn.Embed(c.vocab_size, c.hidden_size, dtype=self.dtype,
                      param_dtype=jnp.float32, name="embed")
-    head = self.param("head", nn.initializers.lecun_normal(),
-                      (c.hidden_size, c.vocab_size), jnp.float32)
+    if not c.tie_word_embeddings:
+      head = self.param("head", nn.initializers.lecun_normal(),
+                        (c.hidden_size, c.vocab_size), jnp.float32)
     embedded = embed(tokens)
+    if c.tie_word_embeddings:
+      head = embed.embedding.T
     if c.scale_emb != 1.0:
       embedded = (embedded.astype(jnp.float32) * c.scale_emb).astype(
           self.dtype)
     x = _fan_out(embedded, c)
+    stack = lambda rows: jax.tree_util.tree_map(
+        lambda *leaves: jnp.stack(leaves), *rows)
     mhc = []  # each block's `mhc/*` counters, in layer order
-    for i in range(c.first_k_dense_replace):
+    # Under `layer_types` each held block's layer sets its FFN (below).
+    for i in range(0 if c.layer_types else c.first_k_dense_replace):
       x, counters = nn.remat(sequence.DecoderBlock)(
           c, False, self.dtype, name=f"dense_block{i}")(x)
       if c.hc_mult:
         mhc.append(jax.tree_util.tree_map(lambda a: a[None], counters))
     outputs = {}
     counters = None
-    if c.mixer_types:
-      by_kind = {"sala": [], "lightning": []}
+    if c.mixer_types or c.layer_types:
+      # Unrolled (the blocks differ); each block's counters by the kind of
+      # its mixer, the rest its expert layer's.
+      by_kind = {"sala": [], "lightning": [], "conv": []}
+      moe = []
       for i in range(c.num_hidden_layers):
+        layer = c.first_layer + i
         x, extra = nn.remat(sequence.DecoderBlock)(
-            c, False, self.dtype, c.layer_kind(i), i, name=f"block{i}")(x)
+            c, bool(c.layer_types) and layer >= c.first_k_dense_replace,
+            self.dtype, c.layer_kind(layer), layer, name=f"block{i}")(x)
+        extra = extra or {}
         for kind, rows in by_kind.items():
-          named, _ = _split_counters(extra or {}, kind + "/")
+          named, extra = _split_counters(extra, kind + "/")
           if named:
             rows.append(named)
+        if extra:
+          moe.append(extra)
       for kind, rows in by_kind.items():
         if rows:
           # (layers of that kind,) each, in layer order.
-          outputs[kind + "_counters"] = jax.tree_util.tree_map(
-              lambda *rows: jnp.stack(rows), *rows)
+          outputs[kind + "_counters"] = stack(rows)
+      if moe:
+        counters = stack(moe)
     elif c.hybrid:
-      stack = nn.scan(
+      periods = nn.scan(
           _Period, variable_axes={"params": 0}, split_rngs={"params": True},
           length=c.num_hidden_layers // c.full_attention_interval)
-      x, counters = stack(c, self.dtype, name="periods")(x, None)
+      x, counters = periods(c, self.dtype, name="periods")(x, None)
       # (periods, blocks a period, ...) -> (layers, ...), in layer order.
       counters = jax.tree_util.tree_map(
           lambda a: a.reshape((-1,) + a.shape[2:]), counters)
       outputs["gdn_counters"], counters = counters["gdn"], counters["moe"]
     else:
-      stack = nn.scan(
+      blocks = nn.scan(
           nn.remat(sequence.DecoderBlock, prevent_cse=False),
           variable_axes={"params": 0}, split_rngs={"params": True},
           length=c.num_expert_layers)
-      x, counters = stack(c, True, self.dtype, name="expert_blocks")(x, None)
+      x, counters = blocks(c, True, self.dtype, name="expert_blocks")(x, None)
     if c.hc_mult:
       maps, counters = _split_counters(counters, "mhc/")
       mhc.append(maps)
@@ -285,4 +305,7 @@ class SequenceMoEModel(AbstractT2RModel):
     metrics.update(outputs.get("sala_counters", {}))
     # (Lightning layers,): the mean of the decays each applied.
     metrics.update(outputs.get("lightning_counters", {}))
+    # (short-convolution layers,): the root mean square of B ⊙ x~ and of
+    # C ⊙ z, the gated input and output of the convolution.
+    metrics.update(outputs.get("conv_counters", {}))
     return loss, metrics

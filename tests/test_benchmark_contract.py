@@ -356,6 +356,20 @@ def test_the_drivers_read_the_kernels_by_the_program_s_names():
     assert (f"tensor2robot_tpu.ops.{module}", "KERNEL_NAMES") in imports
 
 
+def test_the_conv_driver_times_the_mixer_by_the_program_s_scope():
+  """`train_resident_conv` joins the trace's operations to the compiled
+  step's by the scope `layers/sequence.SHORT_CONV_SCOPE`, imported, not
+  spelled: the scope `ShortConv` opens, so a rename cannot make
+  `short_conv_time_share.train` and `short_conv_roofline.train` read
+  nothing."""
+  imports = _program_imports(
+      os.path.join(BENCHMARK, "drivers", "train_resident_conv.py"))
+  assert ("tensor2robot_tpu.layers.sequence", "SHORT_CONV_SCOPE") in imports
+  from tensor2robot_tpu.layers import sequence
+  assert "jax.named_scope(SHORT_CONV_SCOPE)" in inspect.getsource(
+      sequence.ShortConv)
+
+
 @pytest.mark.parametrize("metric", sorted(
     os.path.basename(p)[:-3]
     for p in glob.glob(os.path.join(BENCHMARK, "layer_metrics", "*.py"))))

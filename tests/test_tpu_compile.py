@@ -109,6 +109,47 @@ def test_flash_attention_compiles_at_8k_grouped_256_wide(one_chip, as_on_tpu):
   assert text.count("tpu_custom_call") >= 3
 
 
+def test_flash_attention_compiles_at_8k_grouped_64_wide(one_chip, as_on_tpu):
+  """LFM2's attention shapes: 32 query heads on 8 key/value heads of 64,
+  T = 8,192, bf16: a width that fills half the MXU's depth and is padded
+  to 128 lanes in VMEM."""
+  from tensor2robot_tpu.ops.flash_attention import flash_attention
+  shape = lambda heads: jax.ShapeDtypeStruct(
+      (1, 8192, heads, 64), jnp.bfloat16, sharding=one_chip)
+
+  def loss(q, k, v):
+    out = flash_attention(q, k, v, causal=True, implementation="pallas")
+    return jnp.sum(out.astype(jnp.float32))
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+      shape(32), shape(8), shape(8)).compile()
+  text = compiled.as_text()
+  for name in ("flash_attention_fwd", "flash_attention_dq",
+               "flash_attention_dkv"):
+    assert name in text
+  assert text.count("tpu_custom_call") >= 3
+
+
+def test_short_conv_compiles_at_8k_forward_and_backward(one_chip):
+  """The gated short convolution at LFM2's widths: [B | C | x~] of 3 x
+  2,048 channels over T = 8,192, bf16, 3 float32 taps; XLA's passes, no
+  kernel; the gradients leave in the operands' dtypes."""
+  from tensor2robot_tpu.ops.short_conv import gated_short_conv
+  bcx = jax.ShapeDtypeStruct((1, 8192, 3 * 2048), jnp.bfloat16,
+                             sharding=one_chip)
+  taps = jax.ShapeDtypeStruct((3, 2048), jnp.float32, sharding=one_chip)
+
+  def loss(bcx, taps):
+    return jnp.sum(gated_short_conv(bcx, taps)[0].astype(jnp.float32))
+
+  compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+      bcx, taps).compile()
+  assert "tpu_custom_call" not in compiled.as_text()
+  # bcx's gradient in bf16 (and the taps', padded to the tiling).
+  assert 8192 * 3 * 2048 * 2 < compiled.memory_analysis(
+      ).output_size_in_bytes < 8192 * 3 * 2048 * 4
+
+
 def test_gated_delta_rule_compiles_at_8k_forward_and_backward(one_chip,
                                                               as_on_tpu):
   """The delta net's shapes: 16 q/k heads and 32 value heads of 128,
