@@ -31,6 +31,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from tensor2robot_tpu.ops import dispatch
 from tensor2robot_tpu.ops.flash_attention import flash_attention
@@ -768,12 +769,28 @@ class DecoderBlock(nn.Module):
         return GatedMLP(c.intermediate_size, self.dtype, name="mlp")(inner)
       y, counters = ExpertLayer(c, self.dtype, name="moe")(inner)
       extra.update(counters)
+      if c.hc_mult:
+        # The stream write's backward reads y: kept, so that the block's
+        # recomputation walks none of the layer's held rows forward again.
+        y = checkpoint_name(y, KEPT)
       return y
 
     x = sublayer(sublayer(x, "attn", mixer), "ffn", feed_forward)
     if maps:
       extra.update(zip(MHC_COUNTERS, jnp.stack(maps).T))
     return x, extra or None
+
+
+# What a block names so its recomputation keeps (`recomputed_block`).
+KEPT = "decoder_block_kept"
+
+
+def recomputed_block(**kwargs):
+  """`DecoderBlock` recomputed on the backward pass, all but what it
+  names `KEPT`."""
+  return nn.remat(
+      DecoderBlock,
+      policy=jax.checkpoint_policies.save_only_these_names(KEPT), **kwargs)
 
 
 _LOSS_CHUNKS = 8

@@ -98,7 +98,7 @@ class _MTPModule(nn.Module):
         axis=-1)
     h = nn.Dense(c.hidden_size, use_bias=False, dtype=self.dtype,
                  param_dtype=jnp.float32, name="eh_proj")(joined)
-    h, counters = nn.remat(sequence.DecoderBlock)(
+    h, counters = sequence.recomputed_block()(
         c, True, self.dtype, name="block")(_fan_out(h, c))
     return norm("final_norm")(_collapse(h, c)), counters
 
@@ -117,7 +117,7 @@ class _Period(nn.Module):
     for i in range(c.full_attention_interval):
       # CSE prevented: a scan of one period is unrolled, and the blocks'
       # recomputation would then be merged with their first run.
-      x, counters = nn.remat(sequence.DecoderBlock)(
+      x, counters = sequence.recomputed_block()(
           c, True, self.dtype, c.layer_kind(i), name=f"block{i}")(x)
       gates = {k: counters.pop(k) for k in list(counters)
                if k.startswith("gdn/")}
@@ -154,7 +154,7 @@ class _SequenceModule(nn.Module):
     mhc = []  # each block's `mhc/*` counters, in layer order
     # Under `layer_types` each held block's layer sets its FFN (below).
     for i in range(0 if c.layer_types else c.first_k_dense_replace):
-      x, counters = nn.remat(sequence.DecoderBlock)(
+      x, counters = sequence.recomputed_block()(
           c, False, self.dtype, name=f"dense_block{i}")(x)
       if c.hc_mult:
         mhc.append(jax.tree_util.tree_map(lambda a: a[None], counters))
@@ -167,7 +167,7 @@ class _SequenceModule(nn.Module):
       moe = []
       for i in range(c.num_hidden_layers):
         layer = c.first_layer + i
-        x, extra = nn.remat(sequence.DecoderBlock)(
+        x, extra = sequence.recomputed_block()(
             c, bool(c.layer_types) and layer >= c.first_k_dense_replace,
             self.dtype, c.layer_kind(layer), layer, name=f"block{i}")(x)
         extra = extra or {}
@@ -194,7 +194,7 @@ class _SequenceModule(nn.Module):
       outputs["gdn_counters"], counters = counters["gdn"], counters["moe"]
     else:
       blocks = nn.scan(
-          nn.remat(sequence.DecoderBlock, prevent_cse=False),
+          sequence.recomputed_block(prevent_cse=False),
           variable_axes={"params": 0}, split_rngs={"params": True},
           length=c.num_expert_layers)
       x, counters = blocks(c, True, self.dtype, name="expert_blocks")(x, None)
@@ -287,6 +287,9 @@ class SequenceMoEModel(AbstractT2RModel):
           "moe/expert_tokens": per_expert,
           "moe/held_assignments": jnp.sum(counters["held_assignments"]),
           "moe/total_assignments": jnp.sum(counters["total_assignments"]),
+          # The sorted rows the chunked passes stepped through: over
+          # held_assignments, the padding of the last chunks.
+          "moe/visited_rows": jnp.sum(counters["visited_rows"]),
           "moe/max_expert_tokens": jnp.max(per_expert),
           "moe/min_expert_tokens": jnp.min(per_expert),
       })

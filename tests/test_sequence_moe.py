@@ -321,3 +321,9 @@ class TestModel:
     assert int(metrics["moe/min_expert_tokens"]) == per_expert.min()
     assert (0 < per_expert.sum()
             < int(metrics["moe/total_assignments"]) == 3 * 2 * 32 * 3)
+    # Each layer steps through whole chunks of its sorted rows, the last
+    # one holding its last held row.
+    chunk = expert_parallel.chunk_rows(2 * 32 * 3)
+    visited = int(metrics["moe/visited_rows"])
+    assert visited % chunk == 0
+    assert per_expert.sum() <= visited < per_expert.sum() + 3 * chunk

@@ -321,3 +321,38 @@ def test_serving_rung_expands_the_code_inside_the_first_post_conv(
   # The first post convolution's output is a buffer, and is found.
   assert _written_outside_fusions(text, f"{bucket * 64},30,30,64")
   assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_chunked_expert_layer_compiles_at_joyai_shape(one_chip, as_on_tpu):
+  """One holder's share of the expert layer at JoyAI's shape (8,192
+  tokens of 2,048, 16 held of 256 experts 768 wide, top-8: N·k = 65,536
+  sorted rows), forward and backward as a checkpointed block runs them:
+  the walks over the held chunks as loops, their grouped products the
+  ragged-dot kernel, and no (N, k, D) buffer. The all-rows layer held
+  1.04 GB of temporaries here."""
+  from tensor2robot_tpu.parallel import expert_parallel as ep
+  n, d, e, h, f, k = 8192, 2048, 256, 16, 768, 8
+  shape = lambda dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+      dims, dtype, sharding=one_chip)
+  params = ep.MoEParams(router=shape((d, e)), bias=shape((e,)),
+                        gate=shape((h, d, f)), up=shape((h, d, f)),
+                        down=shape((h, f, d)))
+
+  def loss(tokens, params, cotangent):
+    y, counters = jax.checkpoint(lambda x, p: ep.moe_share(
+        x, p, 0, k, 2.5, jnp.bfloat16))(tokens, params)
+    return jnp.sum(y.astype(jnp.float32) * cotangent), counters
+
+  compiled = jax.jit(jax.value_and_grad(
+      loss, argnums=(0, 1), has_aux=True)).lower(
+          shape((n, d), jnp.bfloat16), params, shape((n, d))).compile()
+  text = compiled.as_text()
+  temp = compiled.memory_analysis().temp_size_in_bytes
+  print(f"chunked expert layer at JoyAI's shape: {temp / 1e9:.3f} GB of "
+        "temporaries (the all-rows layer: 1.04 GB)")
+  # The forward walk and the backward's two; the recomputed forward's is
+  # dead.
+  assert text.count(" while(") == 3
+  assert "ragged-dot" in text
+  assert _written_outside_fusions(text, f"{n},{k},{d}") == []
+  assert temp < 1.04e9
